@@ -6,7 +6,8 @@ scan that validates framing + both CRCs and returns every record's
 python-level reads and two python/c-extension crc calls per record.
 Dense feature columns batch-decode straight into numpy arrays.
 
-Follows the shm.py pattern: lazy g++ build cached next to the package,
+Follows the shm.py pattern: lazy g++ build cached next to the package
+under a source-hash name (_native.py),
 ``available()`` False (and the pure-python tfrecord.py codec takes over)
 wherever the toolchain is missing. tfrecord.py remains the canonical,
 oracle-tested implementation; tests assert byte-exact agreement.
@@ -14,37 +15,17 @@ oracle-tested implementation; tests assert byte-exact agreement.
 
 import ctypes
 import logging
-import os
-import subprocess
 import threading
 
 import numpy as np
 
+from tensorflowonspark_tpu import _native
+
 logger = logging.getLogger(__name__)
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                    "native", "tfrecord_codec.cpp")
-_SO = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                   "_libtfrecord.so")
 _lib = None
 _lib_lock = threading.Lock()
 _u64p = ctypes.POINTER(ctypes.c_uint64)
-
-
-def _build():
-    # per-pid temp: concurrent executor processes all lazily build; a
-    # shared .tmp would tear and the mtime guard would then pin the torn
-    # .so forever. os.replace of complete files is atomic either way.
-    tmp = "{}.{}.tmp".format(_SO, os.getpid())
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True)
-        os.replace(tmp, _SO)
-    finally:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
 
 
 def _load():
@@ -52,11 +33,7 @@ def _load():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC) and
-                os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            _build()
-        lib = ctypes.CDLL(_SO)
+        lib = _native.load("tfrecord_codec.cpp", "tfrecord")
         lib.tfrec_crc32c.restype = ctypes.c_uint32
         lib.tfrec_crc32c.argtypes = (ctypes.c_char_p, ctypes.c_uint64)
         lib.tfrec_masked_crc32c.restype = ctypes.c_uint32

@@ -25,7 +25,7 @@ import string
 import threading
 import time
 
-from tensorflowonspark_tpu import node, reservation
+from tensorflowonspark_tpu import device_info, node, reservation
 
 logger = logging.getLogger(__name__)
 
@@ -386,6 +386,7 @@ def run(sc, map_fun, tf_args, num_executors, num_ps=0, tensorboard=False,
 
         cluster_info = server.await_reservations(timeout=reservation_timeout,
                                                  status=_status)
+        device_info.check_one_owner_per_chip(cluster_info)
     except BaseException:
         # Don't leak the barrier: executors still blocked in
         # await_reservations see the server vanish and fail their node

@@ -12,27 +12,6 @@ from tensorflowonspark_tpu.device_info import is_tpu_available  # noqa: F401
 is_gpu_available = is_tpu_available
 
 
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` across the import-path move.
-
-    Newer jax exposes ``jax.shard_map`` (kwarg ``check_vma``); older
-    releases only have ``jax.experimental.shard_map.shard_map`` (same
-    surface, the kwarg was still called ``check_rep``). The parallel
-    modules route through this shim so the framework runs on both sides
-    of the rename without scattering version probes.
-    """
-    import jax
-
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, **kwargs)
-
-
 def export_saved_model(export_dir, apply_fn, variables, is_chief,
                        signature=None):
     """Chief-only export (reference: ``compat.export_saved_model(model,

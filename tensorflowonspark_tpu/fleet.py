@@ -830,9 +830,12 @@ class ServingNode(object):
         self.server = None
 
     def start(self):
+        from tensorflowonspark_tpu import util
         from tensorflowonspark_tpu.serving import DecodeEngine, \
             ModelServer
 
+        # before the builder: it may already compile (model.init)
+        util.enable_compile_cache()
         spec = self.spec
         builder = spec.get("builder")
         if builder is not None:

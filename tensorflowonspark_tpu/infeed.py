@@ -45,7 +45,7 @@ def prefetch(batch_iter, size=2, device_put=None, timers=None):
     ``jax.device_put`` can ZERO-COPY alias an aligned numpy array on
     the CPU backend, so feeding DataFeed batches through this plain
     prefetch on CPU can alias staged arrays to memory the feed will
-    overwrite. Use :func:`sharded_batches` (its per-shard puts copy —
+    overwrite. Use :func:`sharded_batches` (it copies before the put —
     the canonical consumption everywhere in this framework), pass a
     copying ``device_put``, or set ``TFOS_FEED_STAGING=0`` on the feed.
 
@@ -109,24 +109,27 @@ def sharded_batches(batch_iter, mesh, axis="data", size=2, timers=None):
 
     Each array's leading dim is split across ``axis`` (must divide it);
     everything arrives as committed global arrays, so a pjit-ed step with
-    matching in_shardings runs without any implicit resharding. A SPLIT
-    axis's per-shard ``device_put`` copies out of the host batch (each
-    shard is a slice), so DataFeed's reusable staging buffers are safe
-    to hand straight in here; a 1-device axis's "shard" is the whole
-    array, which ``jax.device_put`` can ZERO-COPY alias on the CPU
-    backend (measured) — there the copy is forced explicitly, or
-    prefetched-but-unconsumed batches would be silently overwritten by
-    the feed's next gather. ``timers`` forwards to :func:`prefetch`.
+    matching in_shardings runs without any implicit resharding. Every
+    numpy batch is COPIED before the put, which is what makes
+    DataFeed's reusable staging buffers safe to hand straight in here:
+    ``jax.device_put`` gives a numpy source to the runtime without a
+    copy of its own (jax 0.9 ignores ``may_alias`` for numpy inputs) —
+    the CPU backend then ALIASES each contiguous shard slice for the
+    array's lifetime, whole array or split alike (measured: a 4-way
+    split on CPU served batches 3,4,5,6,7,7,7,7 of 0..7), and an
+    accelerator may still be reading the host buffer after the call
+    returns — so prefetched-but-unconsumed batches would be silently
+    overwritten by the feed's next gather. ``timers`` forwards to
+    :func:`prefetch`.
     """
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
     sharding = NamedSharding(mesh, PartitionSpec(axis))
-    n_shards = int(mesh.shape[axis])
 
     def put(x):
-        if n_shards == 1 and isinstance(x, np.ndarray):
+        if isinstance(x, np.ndarray):
             x = np.array(x, copy=True)
         return jax.device_put(x, sharding)
 

@@ -48,13 +48,12 @@ def moe_ffn(x, router_w, w_in, w_out, mesh, expert_axis="expert",
 
     Returns ([tokens, hidden], aux_loss).
     """
-    from tensorflowonspark_tpu.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     num_experts = w_in.shape[0]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(expert_axis), P(expert_axis)),
         out_specs=(P(), P()),
         check_vma=False)

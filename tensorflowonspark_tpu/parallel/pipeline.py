@@ -28,7 +28,6 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh,
 
     Returns [M, mb, ...]: outputs of the last stage, replicated.
     """
-    from tensorflowonspark_tpu.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     num_stages = mesh.shape[stage_axis]
@@ -37,7 +36,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh,
     params_spec = jax.tree.map(lambda _: P(stage_axis), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(params_spec, P()), out_specs=P(),
         check_vma=False)
     def _run(params, xs):

@@ -143,7 +143,6 @@ def ring_flash_attention(q, k, v, mesh, seq_axis="seq", causal=False,
     ~2x the average work); zigzag makes each step cost ~one half-block
     pair everywhere, recovering the factor-2.
     """
-    from tensorflowonspark_tpu.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from tensorflowonspark_tpu.ops.flash_attention import (
@@ -167,7 +166,7 @@ def ring_flash_attention(q, k, v, mesh, seq_axis="seq", causal=False,
                                    interpret=interpret)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False)
     def _ring(q_blk, k_blk, v_blk):
         rank = jax.lax.axis_index(seq_axis)
@@ -282,14 +281,13 @@ def ring_attention(q, k, v, mesh, seq_axis="seq", causal=False, scale=None):
     riding ICI neighbor links.
     """
     from jax.sharding import PartitionSpec as P
-    from tensorflowonspark_tpu.compat import shard_map
 
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     axis_size = mesh.shape[seq_axis]
     spec = P(None, seq_axis, None, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False)
     def _ring(q_blk, k_blk, v_blk):
         rank = jax.lax.axis_index(seq_axis)

@@ -3915,6 +3915,9 @@ class ModelServer(object):
         """Start serving in a daemon thread; returns (host, port)."""
         from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+        from tensorflowonspark_tpu import util
+
+        util.enable_compile_cache()  # this process compiles what it serves
         server = self
 
         class Handler(BaseHTTPRequestHandler):

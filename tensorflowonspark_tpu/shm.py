@@ -14,23 +14,22 @@ broker is local and the ring builds — see node.py); semantics
 identical to the queue path.
 
 The .so builds on first use with the toolchain baked into the image
-(g++); the build is cached next to this file. Everything degrades
-gracefully: ``available()`` is False where g++ or POSIX shm is missing.
+(g++); the build is cached next to this file under a name that carries
+the source's hash (_native.py). ``available()`` is False where g++ or
+POSIX shm is missing, and node.py then feeds through the queue
+transport; a caller that needs the ring asserts ``available()`` itself.
 """
 
 import ctypes
 import logging
 import os
 import pickle
-import subprocess
 import threading
+
+from tensorflowonspark_tpu import _native
 
 logger = logging.getLogger(__name__)
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                    "native", "shm_ring.cpp")
-_SO = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                   "_libshmring.so")
 _lib = None
 _lib_lock = threading.Lock()
 
@@ -40,33 +39,13 @@ _from_memory.argtypes = (ctypes.c_void_p, ctypes.c_ssize_t, ctypes.c_int)
 _PyBUF_READ = 0x100
 
 
-def _build():
-    # per-pid temp: concurrent executor processes all lazily build; a
-    # shared .tmp would tear and the mtime guard would then pin the torn
-    # .so forever. os.replace of complete files is atomic either way.
-    tmp = "{}.{}.tmp".format(_SO, os.getpid())
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
-           _SRC, "-lrt", "-pthread"]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True)
-        os.replace(tmp, _SO)
-    finally:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-
-
 def _load():
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC) and
-                os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            _build()
-        lib = ctypes.CDLL(_SO)
+        lib = _native.load("shm_ring.cpp", "shmring",
+                           link_flags=("-lrt", "-pthread"))
         lib.shmring_create.restype = ctypes.c_void_p
         lib.shmring_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
         lib.shmring_open.restype = ctypes.c_void_p

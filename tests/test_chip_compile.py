@@ -1,0 +1,229 @@
+"""Compile the main path for the chip, without the chip.
+
+Rehearsal 3 of the on-chip-measurement guide kept as tests: the TPU's
+compiler is installed here and compiles for a v5e that is DESCRIBED,
+not attached — so what it refuses (a block shape off the tiling, a
+relayout Mosaic lacks, a program that does not fit the device) fails
+tier-1 at no chip time. Interpret mode cannot see any of that: both
+kernels passed every interpret-mode test while neither lowered.
+
+Nothing runs here, so these say nothing about results or times. The
+topology is described inside a module-scoped fixture (only the worker
+that is handed this file loads libtpu; every other file of the suite
+stays clear of it), the persistent compilation cache is off around the
+compiles (an entry written for an absent chip cannot be read back),
+and everything compiles in the test's own process. All of it is in
+this one file on purpose.
+"""
+
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+fa = importlib.import_module("tensorflowonspark_tpu.ops.flash_attention")
+pa = importlib.import_module("tensorflowonspark_tpu.ops.paged_attention")
+
+#: chip_smoke.py's phase-2/3 widths (GPT-2 small: 12 heads of 64; the
+#: engine's 16-token KV blocks over a 1024-token context, 8 slots)
+HEADS, HEAD_DIM, SEQ, KV_BLOCK, SLOTS = 12, 64, 1024, 16, 8
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip("no v5e:2x2 topology can be described here: "
+                    "{}".format(e))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled):
+    return "tpu_custom_call" in compiled.as_text()
+
+
+FLASH_SHAPES = {
+    # (batch, seq, heads, head_dim, dtype, causal, key_mask)
+    "gpt2_bf16_causal": (8, SEQ, HEADS, HEAD_DIM, jnp.bfloat16, True, False),
+    "gpt2_f32_key_mask": (8, SEQ, HEADS, HEAD_DIM, jnp.float32, False, True),
+    # examples/longcontext's regime: K/V must stream, not sit whole in VMEM
+    "long_bf16_causal": (1, 8192, 8, 128, jnp.bfloat16, True, False),
+}
+
+
+def _flash_case(name, one_chip):
+    b, s, n, d, dtype, causal, masked = FLASH_SHAPES[name]
+    qkv = jax.ShapeDtypeStruct((b, s, n, d), dtype, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((b, s), jnp.bool_, sharding=one_chip) \
+        if masked else None
+
+    def flash(q, k, v, key_mask=None):
+        return fa.flash_attention(q, k, v, causal=causal, key_mask=key_mask,
+                                  force_pallas=True, interpret=False)
+
+    return flash, (qkv, qkv, qkv) + ((mask,) if masked else ())
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_SHAPES))
+def test_flash_forward_compiles_for_v5e(one_chip, name):
+    flash, args = _flash_case(name, one_chip)
+    assert _has_kernel(_compile(flash, *args))
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_SHAPES))
+def test_flash_backward_compiles_for_v5e(one_chip, name):
+    flash, args = _flash_case(name, one_chip)
+
+    def loss(q, k, v, *mask):
+        return jnp.sum(flash(q, k, v, *mask).astype(jnp.float32) ** 2)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    # forward (residuals), dQ, dK/dV
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+PAGED_SHAPES = {
+    # (rows, s_q, heads, head_dim, kv_block, table_width, q dtype, int8)
+    "decode_f32": (SLOTS, 1, HEADS, HEAD_DIM, KV_BLOCK, 64, jnp.float32,
+                   False),
+    "decode_bf16": (SLOTS, 1, HEADS, HEAD_DIM, KV_BLOCK, 64, jnp.bfloat16,
+                    False),
+    "decode_int8_pool": (SLOTS, 1, HEADS, HEAD_DIM, KV_BLOCK, 64,
+                         jnp.float32, True),
+    "prefill_128_f32": (1, 128, HEADS, HEAD_DIM, KV_BLOCK, 64, jnp.float32,
+                        False),
+    "prefill_1024_bf16": (1, SEQ, HEADS, HEAD_DIM, KV_BLOCK, 64,
+                          jnp.bfloat16, False),
+    "prefill_128_int8_pool": (1, 128, HEADS, HEAD_DIM, KV_BLOCK, 64,
+                              jnp.bfloat16, True),
+    # tests/test_paged_attention.py's own widths (its interpret-mode
+    # cases, now also put to the real lowering): 4 heads of 16, blocks
+    # of 8 tokens, an odd prefill length
+    "test_widths_f32": (3, 1, 4, 16, 8, 4, jnp.float32, False),
+    "test_widths_int8_pool": (3, 17, 4, 16, 8, 4, jnp.float32, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PAGED_SHAPES))
+def test_paged_attention_compiles_for_v5e(one_chip, name):
+    rows, s_q, n, d, bs, mb, dtype, quant = PAGED_SHAPES[name]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((rows * mb + 1, bs, n, d), jnp.int8 if quant else dtype)
+    scale = sds((rows * mb + 1, bs, n), jnp.float32) if quant else None
+
+    def paged(q, k, v, table, pos, ks, vs):
+        return pa.paged_attention(q, k, v, table, pos, impl="pallas",
+                                  interpret=False, k_scale=ks, v_scale=vs)
+
+    compiled = _compile(paged, sds((rows, s_q, n, d), dtype), pool, pool,
+                        sds((rows, mb), jnp.int32),
+                        sds((rows, s_q), jnp.int32), scale, scale)
+    assert _has_kernel(compiled)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sharding), tree)
+
+
+def _resnet50_step(mesh, batch=256, image=224):
+    """(jitted train step, its abstract arguments) of chip_smoke.py's
+    phase 1 on ``mesh``, from shapes alone."""
+    import optax
+
+    from tensorflowonspark_tpu import training
+    from tensorflowonspark_tpu.models.resnet import ResNet50
+
+    trainer = training.Trainer(ResNet50(), optax.sgd(0.1, momentum=0.9),
+                               mesh)
+    state = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0),
+        np.zeros((batch, image, image, 3), np.float32)))
+    trainer._build_step()
+    return trainer._jit_step, (_on(trainer.replicated, state), {
+        "x": jax.ShapeDtypeStruct((batch, image, image, 3), jnp.uint8,
+                                  sharding=trainer.batch_sharding),
+        "y": jax.ShapeDtypeStruct((batch,), jnp.int64,
+                                  sharding=trainer.batch_sharding)})
+
+
+@pytest.mark.slow  # ~45 s each here; the kernels above are the guard
+@pytest.mark.parametrize("chips", [1, 4])
+def test_resnet50_train_step_compiles_for_v5e(topo, chips):
+    """Phase 1 (one chip) and ``--multichip`` (a four-chip data mesh:
+    the batch split, the gradients all-reduced) fit the device. Run
+    before a four-chip call: ``pytest tests/test_chip_compile.py -m
+    slow``."""
+    from jax.sharding import Mesh
+
+    step, args = _resnet50_step(
+        Mesh(np.array(topo.devices[:chips]), ("data",)))
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < V5E_HBM_BYTES
+    assert ("all-reduce" in compiled.as_text()) == (chips > 1)
+
+
+def test_gpt2_small_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The engine's one decode program (phase 2): 8 slots over the
+    paged pool through the Pallas kernel. ``impl=None`` asks
+    ``jax.default_backend()``, which is the CPU here, so THIS test
+    steers the model's attention call to the kernel."""
+    from chip_smoke import GPT2_SMALL
+    from tensorflowonspark_tpu import generation
+    from tensorflowonspark_tpu.models.decoder import DecoderLM
+
+    paged_attention = pa.paged_attention
+    monkeypatch.setattr(
+        pa, "paged_attention",
+        lambda *a, impl=None, **kw: paged_attention(
+            *a, impl="pallas", interpret=False, **kw))
+    blocks = SLOTS * SEQ // KV_BLOCK
+    model = DecoderLM(decode=True, kv_block_size=KV_BLOCK,
+                      kv_blocks=blocks + 1, **GPT2_SMALL)
+    variables = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((SLOTS, SEQ), jnp.int32)))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = _compile(
+        lambda *a: generation.paged_decode_step(model, *a),
+        _on(one_chip, variables["params"]), _on(one_chip, variables["cache"]),
+        sds((SLOTS,), jnp.int32), sds((SLOTS,), jnp.int32),
+        sds((SLOTS, SEQ // KV_BLOCK), jnp.int32))
+    # one kernel call per layer
+    assert compiled.as_text().count("tpu_custom_call") \
+        >= GPT2_SMALL["num_layers"]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < V5E_HBM_BYTES

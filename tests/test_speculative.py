@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from spec_weights import zero_residual_tail
 from tensorflowonspark_tpu import chaos, generation, paging, serving
 from tensorflowonspark_tpu.models.decoder import DecoderLM
 
@@ -150,10 +151,8 @@ def test_full_acceptance_on_zero_residual_tail(lm):
     1.0, every round emits k tokens, and the output is still
     bitwise-solo (the full-accept path's pin; the bench leg's
     draft-friendly device justified here)."""
-    from bench import _zero_residual_tail
-
     dec, params = lm
-    params = _zero_residual_tail(params, 1, L)
+    params = zero_residual_tail(params, 1, L)
     rng = np.random.RandomState(9)
     reqs = [(rng.randint(0, V, size=6).tolist(), 12) for _ in range(2)]
     want = [_solo(dec, params, p, mn) for p, mn in reqs]
@@ -177,8 +176,11 @@ def test_spec_counter_arithmetic_and_live_rate(lm):
     remaining) — a request near its length cap must not inflate the
     published acceptance rate with positions it could never emit),
     accepted <= proposed, and the BEAT-riding acceptance rate is
-    exactly accepted/proposed."""
+    exactly accepted/proposed. Full-acceptance weights make every
+    window deterministic: after the prefill's token a request has 8
+    left, emitted as windows of 3, 3 and a CLAMPED 2."""
     dec, params = lm
+    params = zero_residual_tail(params, 1, L)
     k = 3
     with serving.DecodeEngine(dec, params, slots=2,
                               speculate_k=k) as eng:
@@ -194,6 +196,7 @@ def test_spec_counter_arithmetic_and_live_rate(lm):
     # max_new=9 with k=3: the last window of a request that decodes
     # to its cap is CLAMPED below k, so the strict inequality is
     # actually exercised here, not vacuously true
+    assert (rounds, proposed) == (2 * 3, 2 * (3 + 3 + 2))
     assert proposed < k * rounds
     assert 0 <= accepted <= proposed
     assert load["spec_acceptance_rate"] == round(accepted / proposed, 4)
@@ -318,9 +321,7 @@ def test_estimate_admission_scales_with_acceptance(lm):
     per-token service BELOW the raw round EWMA (the plain formula
     would overcharge every token at the heavier round cost)."""
     dec, params = lm
-    from bench import _zero_residual_tail
-
-    params = _zero_residual_tail(params, 1, L)  # acceptance 1.0
+    params = zero_residual_tail(params, 1, L)  # acceptance 1.0
     with serving.DecodeEngine(dec, params, slots=2,
                               speculate_k=4) as eng:
         eng.submit(list(range(1, 6)), 12).result(300)

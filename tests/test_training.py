@@ -88,27 +88,30 @@ def test_sharded_batches_layout(jax):
     assert x.addressable_shards[0].data.shape == (2, 4)
 
 
-def test_sharded_batches_single_device_copies_reused_buffers(jax):
-    """A 1-device mesh's 'shard' is the whole array, which CPU
-    jax.device_put can zero-copy ALIAS — sharded_batches must force the
-    copy there, or DataFeed's reused staging buffers would overwrite
-    prefetched-but-unconsumed batches (silent corruption)."""
+@pytest.mark.parametrize("n_devices,shape", [(1, (4, 8)), (4, (4, 8)),
+                                             (4, (64, 8192))])
+def test_sharded_batches_copies_reused_buffers(jax, n_devices, shape):
+    """CPU jax.device_put can zero-copy ALIAS a numpy source — the
+    whole array on a 1-device mesh, and each contiguous shard slice of
+    a split one (the large, page-aligned case) — so sharded_batches
+    must copy first, or DataFeed's reused staging buffers would
+    overwrite prefetched-but-unconsumed batches (silent corruption)."""
     from jax.sharding import Mesh
 
     from tensorflowonspark_tpu import infeed
 
-    buf = np.zeros((4, 8), np.float32)
+    buf = np.zeros(shape, np.float32)
 
     def reusing_gen():
-        for i in range(3):
+        for i in range(6):
             buf[:] = i  # ONE buffer reused, like the feed's staging
             yield {"x": buf}
 
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    mesh = Mesh(np.array(jax.devices()[:n_devices]), ("data",))
     out = list(infeed.sharded_batches(reusing_gen(), mesh))
     for i, b in enumerate(out):
         np.testing.assert_array_equal(
-            np.asarray(b["x"]), np.full((4, 8), i, np.float32))
+            np.asarray(b["x"]), np.full(shape, i, np.float32))
 
 
 def test_lenet_dp_training_converges(jax):

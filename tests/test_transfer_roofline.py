@@ -48,7 +48,7 @@ def test_offline_merge_reports_missing_fed(tmp_path):
     wire_p = tmp_path / "roofline.json"
     wire_p.write_text(json.dumps({"h2d_ceiling_MBps": 10.0}))
     bench_p = tmp_path / "bench.json"
-    bench_p.write_text(json.dumps({"value": 0.0, "error": "tunnel down"}))
+    bench_p.write_text(json.dumps({"value": 0.0, "error": "no device"}))
     rec = _run("--from", str(wire_p), "--fed-json", str(bench_p))
     assert "fed_json_error" in rec
     assert "fed_frac_of_wire" not in rec

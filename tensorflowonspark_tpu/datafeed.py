@@ -174,7 +174,7 @@ class DataFeed(object):
         # tracing): how long the consumer sat blocked on the queue, plus
         # the per-stage breakdown (ring wait / decode / gather; the
         # prefetcher adds device_put into the same instance).
-        self.timers = tracing.StageTimers()
+        self.timers = tracing.StageTimers("feed")
         self._wait_s = 0.0  # cumulative blocked-on-transport seconds
         # Observability plane (PR 5): the feed counters (records /
         # chunks / batches / staging) and stage timers live in ONE
@@ -457,40 +457,49 @@ class DataFeed(object):
         aborts — otherwise a feeder that died mid-shutdown would park
         this consumer on an empty feed until the shutdown timeout.
         """
-        import queue as _queue
         if self._backlog:
             # items decoded ahead of time from a coalesced multi-frame
             return self._backlog.pop(0)
-        idle_terminating = 0
-        # One wait sample per DELIVERED item, spanning however many empty
+        # One wait span per DELIVERED item, spanning however many empty
         # 5s polls preceded it — so timers.per_ms() reads as per-item
         # wait, not a per-poll mean diluted (or inflated) by idle polls.
-        t_wait = time.monotonic()
+        while True:
+            if self._ring is not None:
+                with self.timers.timed("ring_wait"):
+                    view, release = self._await()
+                with self.timers.timed("decode"):
+                    items = self._decode_message(view, release)
+                if items:  # empty multi-frame: nothing to deliver
+                    self._backlog.extend(items[1:])
+                    return items[0]
+            else:
+                with self.timers.timed("queue_wait"):
+                    (item,) = self._await()
+                if isinstance(item, frames_lib.FrameList):
+                    # tail coalescing: one queue item carrying
+                    # several feed items ([final chunk, EndPartition]
+                    # today). _item_done fires the single task_done
+                    # on the LAST piece.
+                    pieces = list(item)
+                    self._unpacked = len(pieces)
+                    self._backlog.extend(pieces[1:])
+                    return pieces[0]
+                return item
+
+    def _await(self):
+        """Poll the transport until it delivers: ``(view, release)`` of
+        a ring message, ``(item,)`` of the queue. Each poll is a bounded
+        wait and the node's state is checked between polls."""
+        import queue as _queue
+        idle_terminating = 0
         while True:
             if self._ring is not None:
                 view, release = self._ring.read_view(timeout=5.0)
                 if view is not None:
-                    self.timers.add("ring_wait", time.monotonic() - t_wait)
-                    items = self._decode_message(view, release)
-                    if items:  # empty multi-frame: nothing to deliver
-                        self._backlog.extend(items[1:])
-                        return items[0]
-                    t_wait = time.monotonic()
+                    return view, release
             else:
                 try:
-                    item = self._queue_in.get(block=True, timeout=5.0)
-                    self.timers.add("queue_wait",
-                                    time.monotonic() - t_wait)
-                    if isinstance(item, frames_lib.FrameList):
-                        # tail coalescing: one queue item carrying
-                        # several feed items ([final chunk, EndPartition]
-                        # today). _item_done fires the single task_done
-                        # on the LAST piece.
-                        pieces = list(item)
-                        self._unpacked = len(pieces)
-                        self._backlog.extend(pieces[1:])
-                        return pieces[0]
-                    return item
+                    return (self._queue_in.get(block=True, timeout=5.0),)
                 except _queue.Empty:
                     pass
             state = self.mgr.get("state")
@@ -517,7 +526,6 @@ class DataFeed(object):
         consumed segments unpinned (``_unpin_segments`` in next_batch),
         i.e. with no slots held by this consumer.
         """
-        t0 = time.monotonic()
         try:
             obj = frames_lib.decode(view)
         except BaseException:
@@ -540,7 +548,6 @@ class DataFeed(object):
                     o.materialize()
             release()
             items = objs
-        self.timers.add("decode", time.monotonic() - t0)
         return items
 
     def _item_done(self):

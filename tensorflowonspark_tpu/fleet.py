@@ -1277,7 +1277,7 @@ class FleetRouter(object):
                                     cooldown=cooldown,
                                     max_cooldown=max_cooldown)
         self.counters = tracing.Counters()
-        self.timers = tracing.StageTimers()
+        self.timers = tracing.StageTimers("fleet")
         self.metrics = tracing.MetricsRegistry()
         self.metrics.add_counters("tfos_fleet", self.counters)
         self.metrics.add_timers("tfos_fleet_stage", self.timers)

@@ -321,26 +321,29 @@ def decode_step(model, params, cache, tokens, idx, temperature=0.0,
 @functools.lru_cache(maxsize=32)
 def slot_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     """(jitted prefill_into_slot, jitted decode_step) for one model +
-    sampling config, cache-donating, reused across engines.
+    sampling config, cache-donating, reused across engines. Every
+    engine program is the jit of a NAMED function: a trace's host
+    spans then read ``PjitFunction(slot_prefill)`` and the device's
+    ``XLA Modules`` line ``jit_slot_prefill``, where a lambda leaves
+    every program ``<lambda>``.
 
     Compile-count contract (asserted in tests): the decode fn compiles
     ONCE per (slots, total_len) cache shape; the prefill fn once per
     bucket length. ``fn._cache_size()`` exposes the live program count —
     serving.DecodeEngine surfaces both via ``compile_stats()``.
     """
-    prefill = jax.jit(
-        lambda params, cache, slot, tokens, true_len, key:
-        prefill_into_slot(model, params, cache, slot, tokens, true_len,
-                          temperature=temperature, top_k=top_k,
-                          top_p=top_p, rng=key),
-        donate_argnums=(1,))
-    decode = jax.jit(
-        lambda params, cache, tokens, idx, key:
-        decode_step(model, params, cache, tokens, idx,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    rng=key),
-        donate_argnums=(1,))
-    return prefill, decode
+    def slot_prefill(params, cache, slot, tokens, true_len, key):
+        return prefill_into_slot(
+            model, params, cache, slot, tokens, true_len,
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+
+    def slot_decode_step(params, cache, tokens, idx, key):
+        return decode_step(
+            model, params, cache, tokens, idx, temperature=temperature,
+            top_k=top_k, top_p=top_p, rng=key)
+
+    return (jax.jit(slot_prefill, donate_argnums=(1,)),
+            jax.jit(slot_decode_step, donate_argnums=(1,)))
 
 
 # -- paged-KV slot primitives (PR 8) -----------------------------------
@@ -443,6 +446,12 @@ def paged_decode_step(model, params, cache, tokens, idx, tables,
     return upd["cache"], picked
 
 
+# the jitted program of paged_step_fns carries the name
+# ``paged_decode_step`` too (it is what a trace shows), which hides
+# this one inside that function: the alias is how its wrapper gets here
+_paged_decode_step = paged_decode_step
+
+
 @functools.lru_cache(maxsize=32)
 def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     """(jitted paged prefill, jitted paged decode) for one paged model
@@ -451,19 +460,19 @@ def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     one prefill program per TAIL bucket (``start``/``tail_len`` are
     traced scalars, so a warm prefix and a cold prompt of equal tail
     bucket share a program)."""
-    prefill = jax.jit(
-        lambda params, cache, table_row, tokens, tail_len, start, key:
-        paged_prefill_into_slot(model, params, cache, table_row, tokens,
-                                tail_len, start, temperature=temperature,
-                                top_k=top_k, top_p=top_p, rng=key),
-        donate_argnums=(1,))
-    decode = jax.jit(
-        lambda params, cache, tokens, idx, tables, key:
-        paged_decode_step(model, params, cache, tokens, idx, tables,
-                          temperature=temperature, top_k=top_k,
-                          top_p=top_p, rng=key),
-        donate_argnums=(1,))
-    return prefill, decode
+    def paged_prefill(params, cache, table_row, tokens, tail_len, start,
+                      key):
+        return paged_prefill_into_slot(
+            model, params, cache, table_row, tokens, tail_len, start,
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+
+    def paged_decode_step(params, cache, tokens, idx, tables, key):
+        return _paged_decode_step(
+            model, params, cache, tokens, idx, tables,
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+
+    return (jax.jit(paged_prefill, donate_argnums=(1,)),
+            jax.jit(paged_decode_step, donate_argnums=(1,)))
 
 
 # -- KV block-row shipping primitives (PR 17) ---------------------------
@@ -682,14 +691,14 @@ def speculative_step_fns(model, draft_model, k, temperature=0.0,
     key) -> (cache', draft_cache', drafts, targets)``."""
     import jax
 
-    return jax.jit(
-        lambda params, draft_params, cache, draft_cache, last, idx, \
-        tables, key:
-        paged_spec_round(model, draft_model, params, draft_params,
-                         cache, draft_cache, last, idx, tables,
-                         int(k), temperature=temperature, top_k=top_k,
-                         top_p=top_p, rng=key),
-        donate_argnums=(2, 3))
+    def spec_round(params, draft_params, cache, draft_cache, last, idx,
+                   tables, key):
+        return paged_spec_round(
+            model, draft_model, params, draft_params, cache, draft_cache,
+            last, idx, tables, int(k), temperature=temperature,
+            top_k=top_k, top_p=top_p, rng=key)
+
+    return jax.jit(spec_round, donate_argnums=(2, 3))
 
 
 @functools.lru_cache(maxsize=32)
@@ -705,17 +714,17 @@ def speculative_probe_fns(model, draft_model, k, temperature=0.0,
     them."""
     import jax
 
-    propose = jax.jit(
-        lambda params, cache, last, idx, tables, key:
-        paged_propose_tokens(draft_model, params, cache, last, idx,
-                             tables, int(k), temperature=temperature,
-                             top_k=top_k, top_p=top_p, rng=key))
-    verify = jax.jit(
-        lambda params, cache, tokens, idx, tables, key:
-        paged_verify_step(model, params, cache, tokens, idx, tables,
-                          temperature=temperature, top_k=top_k,
-                          top_p=top_p, rng=key))
-    return propose, verify
+    def spec_propose(params, cache, last, idx, tables, key):
+        return paged_propose_tokens(
+            draft_model, params, cache, last, idx, tables, int(k),
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+
+    def spec_verify(params, cache, tokens, idx, tables, key):
+        return paged_verify_step(
+            model, params, cache, tokens, idx, tables,
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+
+    return jax.jit(spec_propose), jax.jit(spec_verify)
 
 
 def default_buckets(total_len, lo=8):
@@ -743,13 +752,15 @@ def bucket_for(length, buckets):
 def _jitted_generate(model, max_new_tokens, temperature, top_k, top_p,
                      eos_token, pad_token):
     # flax Modules are frozen dataclasses (hashable), so the option
-    # tuple keys a REUSED jitted fn — a fresh jax.jit(lambda) per call
-    # would recompile every time
-    return jax.jit(
-        lambda params, tokens, key: generate(
+    # tuple keys a REUSED jitted fn — a fresh jax.jit per call would
+    # recompile every time
+    def generate_fixed(params, tokens, key):
+        return generate(
             model, params, tokens, max_new_tokens, temperature, key,
             top_k=top_k, top_p=top_p, eos_token=eos_token,
-            pad_token=pad_token))
+            pad_token=pad_token)
+
+    return jax.jit(generate_fixed)
 
 
 def generate_jit(model, params, prompt, max_new_tokens, temperature=0.0,

@@ -108,7 +108,7 @@ class GoodputLedger(object):
         self._clock = clock
         self._lock = threading.Lock()
         #: badput accumulators (stage-labeled timer families)
-        self.timers = tracing.StageTimers()
+        self.timers = tracing.StageTimers("badput")
         #: productive seconds + steps, ratio / step-EWMA gauges
         self.counters = tracing.Counters()
         self._stack = []            # open (category, entered_at)

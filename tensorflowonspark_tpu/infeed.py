@@ -20,7 +20,8 @@ Two layers:
 
 import queue as _queue
 import threading
-import time
+
+from tensorflowonspark_tpu import tracing
 
 _END = object()
 
@@ -57,6 +58,8 @@ def prefetch(batch_iter, size=2, device_put=None, timers=None):
     import jax
 
     put = device_put or jax.device_put
+    if timers is None:
+        timers = tracing.StageTimers()  # read by nobody
     buf = _queue.Queue(maxsize=size)
     stop = threading.Event()
 
@@ -73,10 +76,9 @@ def prefetch(batch_iter, size=2, device_put=None, timers=None):
     def _stage():
         try:
             for batch in batch_iter:
-                t0 = time.monotonic()
-                staged = jax.tree.map(put, batch)
-                if timers is not None:
-                    timers.add("device_put", time.monotonic() - t0)
+                # one span per batch, on the staging thread
+                with timers.timed("device_put"):
+                    staged = jax.tree.map(put, batch)
                 if stop.is_set() or not _put(staged):
                     return
             _put(_END)

@@ -349,6 +349,7 @@ def _flash_fwd(q, k, v, bias, causal, scale, block_q, block_k, interpret):
         ],
         compiler_params=grid_params(),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*inputs)
     return _unfold(out, b, s_q, n, d), lse[..., 0]
 
@@ -413,6 +414,7 @@ def _flash_bwd(q, k, v, bias, out, lse, g, causal, scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=grid_params(),
         interpret=interpret,
+        name="flash_attention_dq",
     )(*inputs)
 
     # dK/dV: grid (bh, kv-tile, q-tile) — Q innermost
@@ -431,6 +433,7 @@ def _flash_bwd(q, k, v, bias, out, lse, g, causal, scale, block_q,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=grid_params(),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(*inputs)
 
     return (_unfold(dq, b, s_q, n, d), _unfold(dk, b, s_k, n, d),

@@ -361,6 +361,7 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
         out_shape=jax.ShapeDtypeStruct((b, n, s_q + pad, d), q.dtype),
         compiler_params=grid_params(),
         interpret=interpret,
+        name="paged_attention",
     )(*inputs)
     return jnp.transpose(out, (0, 2, 1, 3))[:, :s_q]
 

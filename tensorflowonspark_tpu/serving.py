@@ -685,7 +685,8 @@ class DecodeEngine(object):
         generation.check_sampling_config(temperature, top_k, top_p, rng)
         self.counters = counters if counters is not None \
             else tracing.Counters()
-        self.timers = timers if timers is not None else tracing.StageTimers()
+        self.timers = timers if timers is not None \
+            else tracing.StageTimers("engine")
         #: the engine's observability plane (PR 5): one MetricsRegistry
         #: carrying its counters, stage timers, and latency histograms
         #: — ModelServer's GET /metrics renders it, bench.py and
@@ -1687,6 +1688,22 @@ class DecodeEngine(object):
         return [s for s in range(self.slots)
                 if self._slot_req[s] is not None]
 
+    def _idle_locked(self):
+        """Nothing queued, nothing in a slot, not stopping: the loop
+        parks (caller holds ``_cv``)."""
+        return not (self._stopping or self._queue or self._kv_jobs
+                    or self._active_slots())
+
+    def _step_feed(self, jnp):
+        """A step program's per-step inputs put on the device — each
+        slot's last token and cursor, the block tables of a paged
+        engine, the key: what ``step_upload`` times."""
+        feed = [jnp.asarray(self._last), jnp.asarray(self._idx)]
+        if self._paged:
+            feed.append(jnp.asarray(self._tables))
+        feed.append(self._next_key())
+        return feed
+
     def _ewma(self, prev, sample):
         return sample if prev is None \
             else self._ewma_alpha * sample \
@@ -1871,6 +1888,11 @@ class DecodeEngine(object):
                                    backlogged=backlogged)
             self._qos_admitted[winner] = \
                 self._qos_admitted.get(winner, 0) + 1
+        if buckets:
+            # the scan leaves a head waiting: for blocks the pool
+            # cannot supply, else for a slot
+            self.counters.inc("admit_scans_blocked_blocks" if block_starved
+                              else "admit_scans_blocked_slots")
         victims = []
         # class preemption rides PR 8's paged preemption machinery
         # (continuation re-prefill of prompt + emitted tokens); a
@@ -1903,10 +1925,12 @@ class DecodeEngine(object):
         try:
             while True:
                 with self._cv:
-                    while (not self._stopping and not self._queue
-                           and not self._kv_jobs
-                           and not self._active_slots()):
-                        self._cv.wait()
+                    if self._idle_locked():
+                        # idle for want of work: its own stage, so
+                        # nobody reads a quiet engine as scheduler cost
+                        with self.timers.timed("park"):
+                            while self._idle_locked():
+                                self._cv.wait()
                     if self._stopping:
                         self._fail_outstanding(
                             RuntimeError("engine stopped"))
@@ -1935,22 +1959,27 @@ class DecodeEngine(object):
                 # every other scheduling decision lands on
                 for s in victims:
                     if self._slot_req[s] is not None:
-                        self._preempt(s)
+                        with self.timers.timed("preempt"):
+                            self._preempt(s)
                 for job in kv_jobs:
-                    self._run_kv_job(job)
+                    with self.timers.timed("kv_job"):
+                        self._run_kv_job(job)
                 # prefill OUTSIDE the lock: submit() must never block on
                 # device work
                 for s, handle in admits:
-                    self._admit(s, handle)
+                    with self.timers.timed("admit"):
+                        self._admit(s, handle)
                 # step-boundary eviction: cancelled / past-deadline
                 # requests free their slots BEFORE the step computes
                 # for them, so the next admission scan can reuse them
-                self._evict_expired(time.monotonic())
+                with self.timers.timed("evict"):
+                    self._evict_expired(time.monotonic())
                 if self._paged:
                     # lazy block growth (and, under exhaustion,
                     # youngest-first preemption) for every slot whose
                     # NEXT write crosses a block boundary
-                    self._grow_active_blocks()
+                    with self.timers.timed("grow_blocks"):
+                        self._grow_active_blocks()
                 active = self._active_slots()
                 self.counters.gauge("slot_occupancy", len(active))
                 if not active:
@@ -1965,20 +1994,18 @@ class DecodeEngine(object):
                 if self._spec_k:
                     drafts, targets = self._spec_round(jnp)
                 else:
+                    # upload, the jitted call until it returns (the
+                    # host path of a dispatch: the device works on
+                    # after it), the blocking read of the tokens
                     with self.timers.timed("decode_step"):
-                        if self._paged:
+                        with self.timers.timed("step_upload"):
+                            feed = self._step_feed(jnp)
+                        with self.timers.timed("step_dispatch"):
                             self._cache, toks = self._decode_fn(
-                                self.params, self._cache,
-                                jnp.asarray(self._last),
-                                jnp.asarray(self._idx),
-                                jnp.asarray(self._tables),
-                                self._next_key())
-                        else:
-                            self._cache, toks = self._decode_fn(
-                                self.params, self._cache,
-                                jnp.asarray(self._last),
-                                jnp.asarray(self._idx), self._next_key())
-                        toks = np.asarray(toks)  # the per-step host sync
+                                self.params, self._cache, *feed)
+                        with self.timers.timed("step_sync"):
+                            # the per-step host sync
+                            toks = np.asarray(toks)
                 t1 = time.monotonic()
                 self._step_ewma = self._ewma(self._step_ewma, t1 - t0)
                 self._hist_step.observe(t1 - t0)
@@ -1988,6 +2015,13 @@ class DecodeEngine(object):
                                  active=len(active), step=steps)
                 steps += 1
                 self.counters.inc("decode_steps")
+                if self._paged:
+                    # blocks held by in-flight sequences, summed over
+                    # the steps: kv_block_steps / decode_steps is the
+                    # mean occupancy of the pool the steps saw
+                    self.counters.inc(
+                        "kv_block_steps",
+                        self._pool.num_blocks - self._pool.allocatable())
                 with self.timers.timed("host_schedule"):
                     if self._spec_k:
                         delivered = self._spec_deliver(active, drafts,
@@ -2066,14 +2100,16 @@ class DecodeEngine(object):
         comes from :meth:`measure_spec`'s standalone probes — per-op
         timing is invisible inside one program."""
         with self.timers.timed("spec_round"):
-            self._cache, self._draft_cache, drafts, targets = \
-                self._round_fn(
-                    self.params, self._draft_params, self._cache,
-                    self._draft_cache, jnp.asarray(self._last),
-                    jnp.asarray(self._idx), jnp.asarray(self._tables),
-                    self._next_key())
-            drafts = np.asarray(drafts)   # the per-round host sync
-            targets = np.asarray(targets)
+            with self.timers.timed("step_upload"):
+                feed = self._step_feed(jnp)
+            with self.timers.timed("step_dispatch"):
+                self._cache, self._draft_cache, drafts, targets = \
+                    self._round_fn(
+                        self.params, self._draft_params, self._cache,
+                        self._draft_cache, *feed)
+            with self.timers.timed("step_sync"):
+                drafts = np.asarray(drafts)   # the per-round host sync
+                targets = np.asarray(targets)
         return drafts, targets
 
     def measure_spec(self, reps=3, depth=None):
@@ -2637,6 +2673,9 @@ class DecodeEngine(object):
         if handle._decode_t0 is None:
             # queue-wait metrics describe FIRST admissions only; a
             # preemption re-entry is a continuation, not a queue wait
+            # (the stage is a sample, not a span: the interval began
+            # on the submitting thread)
+            self.timers.add("queue_wait", t0 - handle.submitted)
             self._hist_qwait.observe(t0 - handle.submitted)
             self._hist_qwait_class.get(
                 handle.priority,
@@ -2720,6 +2759,7 @@ class DecodeEngine(object):
         # the loop's failure path finds the handle in _slot_req instead
         # of stranding its client on a timeout)
         t0 = time.monotonic()
+        self.timers.add("queue_wait", t0 - handle.submitted)
         self._hist_qwait.observe(t0 - handle.submitted)
         self._hist_qwait_class.get(
             handle.priority,

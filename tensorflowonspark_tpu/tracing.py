@@ -6,8 +6,9 @@ code; its own plumbing is unobservable. Here the framework exposes:
 
 - :func:`start_profiler_server` — per-host ``jax.profiler`` server, so
   TensorBoard's profile plugin (or ``xprof``) can capture device traces.
-- :func:`trace` — context manager around ``jax.profiler.trace`` for
-  programmatic capture windows.
+- :func:`trace` — context manager around ``jax.profiler`` for
+  programmatic capture windows (Python tracer off, so the capture
+  measures the program and not the profiler).
 - :class:`SummaryWriter` — scalar/text summaries for TensorBoard, backed
   by the installed TF's ``tf.summary`` (CPU TF is in the image); no-ops
   cleanly when TF is absent.
@@ -19,7 +20,12 @@ code; its own plumbing is unobservable. Here the framework exposes:
   whole host-side feed cost of a run lands in a single snapshot, and
   bench.py / scripts/profile_fed.py surface it next to
   ``fed_frac_of_device`` — the remaining feed loss is attributed to a
-  stage instead of unexplained.
+  stage instead of unexplained. The serving engine, the fleet router
+  and the goodput ledger keep theirs the same way; given a plane
+  (``engine``, ``feed``) every timed stage is also a host span
+  ``<plane>:<stage>`` in a captured profiler trace, on the device
+  trace's clock, so a device idle gap is named by the stage the host
+  was in.
 - :class:`Counters` — named monotonic counters + gauges for scheduler
   loops: serving.DecodeEngine exports queue depth, slot occupancy,
   tokens-per-step, and the request-lifecycle tallies (``shed`` /
@@ -66,6 +72,7 @@ import itertools
 import logging
 import math
 import os
+import sys
 import threading
 import time
 
@@ -81,13 +88,36 @@ class StageTimers(object):
     the prefetch staging thread is the only cross-thread writer and
     ``snapshot()`` is read at end of run, so the unlocked add is a
     benign last-sample race, never a torn total.
+
+    ``plane`` names whose timers these are (``engine``, ``feed``,
+    ``fleet``, ``badput``). With a plane, every :meth:`timed` interval
+    is also a ``jax.profiler.TraceAnnotation("<plane>:<stage>")``: the
+    profiler writes it as a host span on the device trace's clock, on
+    the thread that did the work, so one ``with`` is the ``/metrics``
+    stage, the counter a benchmark reads and the name an idle gap of
+    the device gets in a captured trace. Always on — with no capture
+    running the annotation is a level check — and only in a process
+    that has imported jax already: the executor-side feeder or a
+    router-only process is never made to import it, and its timers
+    count as before. :meth:`add` records a sample measured elsewhere
+    (an interval that crosses threads, such as a request's queue
+    wait) and annotates nothing.
+
+    Stages nest as their ``with`` blocks do, and every stage's seconds
+    INCLUDE its children's: a stage's self time is its seconds minus
+    the seconds of the stages opened inside it (the engine's
+    ``decode_step`` holds ``step_upload`` + ``step_dispatch`` +
+    ``step_sync``; its ``admit`` holds ``prefix_lookup``,
+    ``block_alloc`` and ``prefill``). Sum siblings, never a parent
+    with its children.
     """
 
-    __slots__ = ("_t", "_n")
+    __slots__ = ("_t", "_n", "_plane")
 
-    def __init__(self):
+    def __init__(self, plane=None):
         self._t = {}
         self._n = {}
+        self._plane = plane
 
     def add(self, stage, seconds):
         """Accumulate one sample for ``stage``."""
@@ -95,8 +125,17 @@ class StageTimers(object):
         self._n[stage] = self._n.get(stage, 0) + 1
 
     def timed(self, stage):
-        """``with timers.timed("decode"):`` — context-manager sampling."""
-        return _StageSpan(self, stage)
+        """``with timers.timed("decode"):`` — context-manager sampling,
+        and a host span of the profiler's trace (class docstring)."""
+        annotation = None
+        if self._plane is not None:
+            # never import jax for this: only a process that runs
+            # device work has a trace to appear in
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            if profiler is not None:
+                annotation = profiler.TraceAnnotation(
+                    self._plane + ":" + stage)
+        return _StageSpan(self, stage, annotation)
 
     def snapshot(self):
         """{stage: total_seconds} — stable copy for artifacts/logs."""
@@ -266,6 +305,18 @@ METRIC_FAMILIES = {
         ("counter", "", "tokens emitted by decode steps only"),
     "tfos_serving_decode_steps":
         ("counter", "", "fixed-shape decode steps run"),
+    "tfos_serving_kv_block_steps":
+        ("counter", "", "KV blocks held by in-flight sequences, summed "
+                        "at every decode step (over "
+                        "tfos_serving_decode_steps: the mean pool "
+                        "occupancy the steps saw; paged engines only)"),
+    "tfos_serving_admit_scans_blocked_slots":
+        ("counter", "", "admission scans that left a queued request "
+                        "waiting because no slot was free"),
+    "tfos_serving_admit_scans_blocked_blocks":
+        ("counter", "", "admission scans that left a queued request "
+                        "waiting because the KV pool could not supply "
+                        "its blocks"),
     "tfos_serving_prefills":
         ("counter", "", "prompt prefills (one per admission)"),
     "tfos_serving_requests_completed":
@@ -353,12 +404,20 @@ METRIC_FAMILIES = {
     "tfos_serving_slot_occupancy":
         ("gauge", "", "slots holding an in-flight sequence"),
     "tfos_serving_stage_seconds":
-        ("counter", "stage", "scheduler wall seconds per stage "
-                             "(qos_plan / prefill / decode_step / "
-                             "host_schedule; speculative engines add "
-                             "spec_round / draft_prefill plus the "
-                             "draft and verify probes, int8 engines "
-                             "the dequant probe)"),
+        ("counter", "stage", "scheduler wall seconds per stage, a "
+                             "parent's seconds including its "
+                             "children's: park (idle for want of "
+                             "work) / qos_plan / preempt / kv_job / "
+                             "admit (holds prefix_lookup, block_alloc, "
+                             "prefill) / evict / grow_blocks (holds "
+                             "block_alloc) / decode_step (holds "
+                             "step_upload, step_dispatch, step_sync) / "
+                             "host_schedule, and queue_wait (submit to "
+                             "first admission, one sample a request); "
+                             "speculative engines add spec_round "
+                             "(holding the three step parts) / "
+                             "draft_prefill plus the draft and verify "
+                             "probes, int8 engines the dequant probe)"),
     "tfos_serving_stage_samples":
         ("counter", "stage", "samples behind tfos_serving_stage_seconds"),
     "tfos_serving_replica_info":
@@ -1435,18 +1494,23 @@ def flight_recorder():
 
 
 class _StageSpan(object):
-    __slots__ = ("_timers", "_stage", "_t0")
+    __slots__ = ("_timers", "_stage", "_annotation", "_t0")
 
-    def __init__(self, timers, stage):
+    def __init__(self, timers, stage, annotation):
         self._timers = timers
         self._stage = stage
+        self._annotation = annotation
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         self._timers.add(self._stage, time.monotonic() - self._t0)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 #: port of this process's already-started jax profiler server, if any
@@ -1482,7 +1546,14 @@ def start_profiler_server(port=9012):
 
 
 class trace(object):
-    """``with tracing.trace(log_dir):`` captures a device trace window."""
+    """``with tracing.trace(log_dir):`` captures a device trace window
+    with the program's own spans in it: every :class:`StageTimers`
+    stage with a plane (``engine:decode_step``, ``feed:device_put``)
+    is a host span on the device trace's clock. The Python tracer is
+    off and the host tracer at level 1 — what ``TraceAnnotation``
+    spans and the runtime's own need: with the Python tracer hooking
+    every call the feed's consumer ran three times slower (PERF.md,
+    PR 26), so a capture measured the profiler, not the program."""
 
     def __init__(self, log_dir):
         self.log_dir = log_dir
@@ -1490,7 +1561,10 @@ class trace(object):
     def __enter__(self):
         import jax
 
-        jax.profiler.start_trace(self.log_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(self.log_dir, profiler_options=options)
         return self
 
     def __exit__(self, *exc):

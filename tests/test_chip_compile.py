@@ -193,20 +193,12 @@ def test_resnet50_train_step_compiles_for_v5e(topo, chips):
     assert ("all-reduce" in compiled.as_text()) == (chips > 1)
 
 
-def test_gpt2_small_decode_step_compiles_for_v5e(one_chip, monkeypatch):
-    """The engine's one decode program (phase 2): 8 slots over the
-    paged pool through the Pallas kernel. ``impl=None`` asks
-    ``jax.default_backend()``, which is the CPU here, so THIS test
-    steers the model's attention call to the kernel."""
+def _gpt2_small_paged(one_chip):
+    """(paged model, abstract params, abstract cache, sds) at phase 2's
+    widths: what ``paged_step_fns`` programs are lowered with."""
     from chip_smoke import GPT2_SMALL
-    from tensorflowonspark_tpu import generation
     from tensorflowonspark_tpu.models.decoder import DecoderLM
 
-    paged_attention = pa.paged_attention
-    monkeypatch.setattr(
-        pa, "paged_attention",
-        lambda *a, impl=None, **kw: paged_attention(
-            *a, impl="pallas", interpret=False, **kw))
     blocks = SLOTS * SEQ // KV_BLOCK
     model = DecoderLM(decode=True, kv_block_size=KV_BLOCK,
                       kv_blocks=blocks + 1, **GPT2_SMALL)
@@ -216,9 +208,31 @@ def test_gpt2_small_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
+    return (model, _on(one_chip, variables["params"]),
+            _on(one_chip, variables["cache"]), sds)
+
+
+@pytest.fixture
+def kernel_on_cpu_backend(monkeypatch):
+    """``impl=None`` asks ``jax.default_backend()``, which is the CPU
+    here, so the TEST steers the model's attention call to the kernel."""
+    paged_attention = pa.paged_attention
+    monkeypatch.setattr(
+        pa, "paged_attention",
+        lambda *a, impl=None, **kw: paged_attention(
+            *a, impl="pallas", interpret=False, **kw))
+
+
+def test_gpt2_small_decode_step_compiles_for_v5e(one_chip,
+                                                 kernel_on_cpu_backend):
+    """The engine's one decode program (phase 2): 8 slots over the
+    paged pool through the Pallas kernel."""
+    from chip_smoke import GPT2_SMALL
+    from tensorflowonspark_tpu import generation
+
+    model, params, cache, sds = _gpt2_small_paged(one_chip)
     compiled = _compile(
-        lambda *a: generation.paged_decode_step(model, *a),
-        _on(one_chip, variables["params"]), _on(one_chip, variables["cache"]),
+        lambda *a: generation.paged_decode_step(model, *a), params, cache,
         sds((SLOTS,), jnp.int32), sds((SLOTS,), jnp.int32),
         sds((SLOTS, SEQ // KV_BLOCK), jnp.int32))
     # one kernel call per layer
@@ -227,3 +241,43 @@ def test_gpt2_small_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["paged_decode_step", "paged_prefill"])
+def test_engine_programs_carry_their_names_for_v5e(one_chip,
+                                                   kernel_on_cpu_backend,
+                                                   program):
+    """What a trace shows of the engine is named by the program: the
+    module of each jitted engine program (the device's ``XLA Modules``
+    line, the host's ``PjitFunction(...)`` span) and the Pallas call in
+    it — lowered for the chip, nothing compiled."""
+    from tensorflowonspark_tpu import generation
+
+    model, params, cache, sds = _gpt2_small_paged(one_chip)
+    prefill, decode = generation.paged_step_fns(model)
+    key = sds((2,), jnp.uint32)
+    if program == "paged_decode_step":
+        lowered = decode.lower(
+            params, cache, sds((SLOTS,), jnp.int32),
+            sds((SLOTS,), jnp.int32),
+            sds((SLOTS, SEQ // KV_BLOCK), jnp.int32), key)
+    else:
+        lowered = prefill.lower(
+            params, cache, sds((SEQ // KV_BLOCK,), jnp.int32),
+            sds((128,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+            key)
+    text = lowered.as_text()
+    assert "module @jit_{} ".format(program) in text
+    assert text.count('kernel_name = "paged_attention"') >= 1
+
+
+def test_flash_kernels_carry_their_names_for_v5e(one_chip):
+    flash, args = _flash_case("gpt2_bf16_causal", one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash(q, k, v).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).as_text()
+    for name in ("flash_attention_fwd", "flash_attention_dq",
+                 "flash_attention_dkv"):
+        assert 'kernel_name = "{}"'.format(name) in text

@@ -593,12 +593,17 @@ def kernel_phase(seed, platform="tpu", batch=8, seq=1024, heads=12,
     pools = [jax.random.normal(jax.random.PRNGKey(seed + i),
                                (pool_rows, block_size, heads, head_dim),
                                jnp.float32) for i in (1, 2)]
+
+    def flat(pool):  # the op's pools: heads and head_dim in one axis
+        return pool.reshape(pool_rows, block_size, heads * head_dim)
+
     for dtype in (jnp.bfloat16, jnp.float32):
         for pool in ("float", "int8"):
             if pool == "int8":
                 (kp, ks), (vp, vs) = (pa.quantize_kv(p) for p in pools)
+                kp, vp = flat(kp), flat(vp)
             else:
-                kp, vp = (p.astype(dtype) for p in pools)
+                kp, vp = (flat(p.astype(dtype)) for p in pools)
                 ks = vs = None
             for name, rows, s_q in (("decode", batch, 1),
                                     ("prefill", 1, prefill)):

@@ -26,13 +26,18 @@ float noise in general and bitwise-equal on the engine's pinned
 serving configs.
 
 PAGED KV (PR 8): with ``kv_block_size > 0`` the decode cache stores
-K/V in a shared BLOCK POOL ``[kv_blocks, kv_block_size, N, D]``
+K/V in a shared BLOCK POOL ``[kv_blocks, kv_block_size, N * D]``
 instead of per-row contiguous ``[B, max_len, N, D]`` regions, plus a
-per-row ``block_table`` mapping logical block index -> pool row.
-Writes scatter through the table (position ``p`` lands in pool row
-``table[b, p // bs]`` at offset ``p % bs``); attention then runs one
-of two formulations selected by ``attn_impl`` (PR 11, both in
-ops/paged_attention.py):
+per-row ``block_table`` mapping logical block index -> pool row. The
+pool is FLAT (heads and head_dim in one axis) for every model: that
+minor pair tiles the chip's (8, 128) exactly, so the write below
+updates the donated pool in place, where a ``[.., N, 64]`` pool was
+copied whole into another layout and back around every call
+(ops/paged_attention.py, "Pool layout"). Writes scatter through the
+table (position ``p`` lands in pool row ``table[b, p // bs]`` at
+offset ``p % bs``, all heads of the token as one row); attention then
+runs one of two formulations selected by ``attn_impl`` (PR 11, both
+in ops/paged_attention.py):
 
 - ``"fused"`` (the default) — paged attention consumes the pool and
   the block table DIRECTLY: a Pallas kernel on TPU whose K/V index
@@ -133,7 +138,7 @@ class CausalSelfAttention(nn.Module):
                         "'int8', got {!r}".format(self.kv_dtype))
                 kv_q = self.kv_dtype == "int8"
                 bs_blk = self.kv_block_size
-                pool_shape = (self.kv_blocks, bs_blk) + k.shape[2:]
+                pool_shape = (self.kv_blocks, bs_blk, h)
                 cached_key = self.variable(
                     "cache", "cached_key", jnp.zeros, pool_shape,
                     jnp.int8 if kv_q else k.dtype)
@@ -210,18 +215,17 @@ class CausalSelfAttention(nn.Module):
                     # scales through the same table routing; attention
                     # dequantizes in-formulation so the per-step HBM
                     # traffic is the int8 bytes
-                    qk, sk = pa.quantize_kv(k)
-                    qv, sv = pa.quantize_kv(v)
-                    pk = cached_key.value.at[blk, off].set(qk)
-                    pv = cached_value.value.at[blk, off].set(qv)
+                    (k, sk), (v, sv) = pa.quantize_kv(k), pa.quantize_kv(v)
                     ksc = key_scale.value.at[blk, off].set(sk)
                     vsc = value_scale.value.at[blk, off].set(sv)
                     key_scale.value = ksc
                     value_scale.value = vsc
                 else:
-                    pk = cached_key.value.at[blk, off].set(k)
-                    pv = cached_value.value.at[blk, off].set(v)
                     ksc = vsc = None
+                # a token's heads are one row of the flat pool
+                pk = cached_key.value.at[blk, off].set(k.reshape(b, s, h))
+                pv = cached_value.value.at[blk, off].set(
+                    v.reshape(b, s, h))
                 cached_key.value = pk
                 cached_value.value = pv
                 cache_index.value = idx + s

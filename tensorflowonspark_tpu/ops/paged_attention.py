@@ -1,8 +1,9 @@
 """Fused paged attention: attend through a block table, no gather.
 
 The device half of the paged KV cache (PR 8) stores K/V in a shared
-pool ``[pool_rows, block_size, heads, head_dim]`` per layer, with each
-batch row reaching its sequence through a ``block_table`` row. PR 8's
+pool ``[pool_rows, block_size, heads * head_dim]`` per layer (heads
+flattened into the lanes, see "Pool layout" below), with each batch
+row reaching its sequence through a ``block_table`` row. PR 8's
 attention was the XLA *gather* formulation: materialize the logical
 ``[B, L, heads, dim]`` view (``pool[table]``) every step, then attend —
 resident memory is paged, but transient compute memory is not, so
@@ -22,8 +23,8 @@ implementations share one contract:
   the pipeline DMAs exactly the pool block each grid step attends —
   paged attention as an index-mapping problem, no gather
   materialization. A block is one pool row with ALL its heads
-  (``[block_size, heads, head_dim]``, the pool's own layout, so no
-  relayout of the pool is ever needed); the kernel loops the heads.
+  (``[block_size, heads * head_dim]``); the kernel loops the heads,
+  reading each as a static lane slice of the block.
   Dead table slots (past a row's live length) clamp their index map to
   the row's last live block: consecutive equal indices make Pallas
   skip the copy, so DMA traffic tracks live blocks, and a ``pl.when``
@@ -52,15 +53,33 @@ sits at logical position ``pos[b, i]`` and attends every key position
 ``<= pos[b, i]``. Callers write the step's K/V through the table
 BEFORE attending (models/decoder.py), so the current token sees
 itself. Layout: ``q [B, S_q, N, D]``, pools
-``[P, block_size, N, D]``, ``block_table [B, MB]`` int32,
-``pos [B, S_q]`` int32; returns ``[B, S_q, N, D]``.
+``[P, block_size, N * D]`` (``N`` and ``D`` are taken from ``q``),
+``block_table [B, MB]`` int32, ``pos [B, S_q]`` int32; returns
+``[B, S_q, N, D]``.
+
+Pool layout (PR 30): the pools are FLAT, heads and head_dim in one
+minor axis, because of what the chip does with anything else. The TPU
+tiles an array's two minor dimensions ``(8, 128)``; a ``[.., N, 64]``
+minor pair pads 64 lanes to 128 (and 20 heads to 24 sublanes), so the
+runtime keeps such a buffer in another, compact layout than the
+row-major one a scatter and a ``tpu_custom_call`` operand want, and
+every program that touched a 4-D pool copied the WHOLE pool in and
+out again around its one-row write (two copies per pool per call: 83%
+of the device's busy time at GPT-2 large, ledger PR 29). A
+``[block_size, N * D]`` minor pair tiles exactly for any head width,
+so the stored layout is the row-major one: the write is in place in
+the donated buffer and the kernel reads its result (pinned by
+tests/test_chip_compile.py). A formulation that wants heads apart
+reshapes a gathered BLOCK (a block-sized transient), never the pool.
 
 INT8 KV (PR 15): with ``k_scale``/``v_scale`` supplied, the pools hold
 ``int8`` codes and the scales (``[P, block_size, heads]`` float32 —
 one per head per token row of each block, stored block-aligned beside
 the pool) dequantize them INSIDE each formulation: the gather path
-dequantizes the materialized view, the blockwise loop and the Pallas
-kernel dequantize one block at a time right after its load — so the
+dequantizes the materialized view, the blockwise loop one block at a
+time right after its load, and the Pallas kernel unpacks a block's
+codes in VMEM and lays each head's scales on its scores and its
+probabilities (the same products in another order) — so the
 HBM traffic a decode step pays is the int8 bytes, not the float ones
 (per-step KV bandwidth halves vs bf16, quarters vs f32; the exact
 follow-up PR 11 named). Quantization itself happens at WRITE time in
@@ -119,13 +138,13 @@ def _gather(q, k_pool, v_pool, block_table, pos, scale, k_scale=None,
     bs_blk = k_pool.shape[1]
     mb = block_table.shape[1]
     L = mb * bs_blk
-    ck = k_pool[block_table]
-    cv = v_pool[block_table]
+    ck = k_pool[block_table].reshape(b, mb, bs_blk, n, d)
+    cv = v_pool[block_table].reshape(b, mb, bs_blk, n, d)
     if k_scale is not None:
         ck = dequantize_kv(ck, k_scale[block_table])
         cv = dequantize_kv(cv, v_scale[block_table])
-    ck = ck.reshape((b, L) + ck.shape[3:])
-    cv = cv.reshape((b, L) + cv.shape[3:])
+    ck = ck.reshape(b, L, n, d)
+    cv = cv.reshape(b, L, n, d)
     logits = jnp.einsum("bqnd,bknd->bnqk", q, ck,
                         preferred_element_type=jnp.float32)
     logits = logits * scale
@@ -156,9 +175,9 @@ def _blockwise(q, k_pool, v_pool, block_table, pos, scale,
     """Online-softmax over each row's live blocks, pure ``lax``: the
     CPU tier-1 formulation of the fused kernel (and the fallback for
     any non-TPU backend). ONE ``fori_loop`` — iteration ``j`` gathers
-    block ``j`` of every row at once ([B, bs, N, D], a
-    live-block-sized transient) and folds it into the recurrence;
-    rows whose own depth is < j mask to -inf, which makes their
+    block ``j`` of every row at once ([B, bs, N * D] viewed as
+    [B, bs, N, D], a live-block-sized transient) and folds it into the
+    recurrence; rows whose own depth is < j mask to -inf, which makes their
     update an EXACT no-op (p = 0, correction = 1). The trip count is
     the batch's deepest live block count (traced), so mixed-depth
     batches cost the deepest row, never the table width — except on
@@ -180,8 +199,8 @@ def _blockwise(q, k_pool, v_pool, block_table, pos, scale,
         jj = jnp.minimum(j, nblk - 1)            # [B]
         bid = jnp.take_along_axis(block_table, jj[:, None],
                                   axis=1)[:, 0]  # [B]
-        kb = k_pool[bid]                         # [B, bs, N, D]
-        vb = v_pool[bid]
+        kb = k_pool[bid].reshape(b, bs_blk, n, d)
+        vb = v_pool[bid].reshape(b, bs_blk, n, d)
         if k_scale is not None:
             # int8 fast path: the gather above moved the int8 bytes;
             # dequant happens here, on the one-block transient
@@ -218,12 +237,11 @@ def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
     accumulators; emit on the last table slot. The K/V BlockSpec index
     maps already routed the RIGHT pool block here (and clamped dead
     slots to the last live block, skipping their copy), so the kernel
-    only guards compute. Blocks keep the heads axis WHOLE (the TPU
-    lowering needs the last two block dims to span the array's), so
+    only guards compute. A block arrives flat, ``[rows, N * D]``, so
     the per-head recurrence is a static loop over ``num_heads`` reading
-    each head's ``[rows, D]`` slice out of the token-major block.
+    each head's ``[rows, D]`` lanes out of the token-major block.
     ``quantized`` adds per-head scale refs riding the SAME index maps
-    as K/V; dequant happens in-VMEM right after the (int8-sized) copy
+    as K/V; the codes are unpacked in VMEM after the (int8-sized) copy
     — the bandwidth the fast path saves is exactly the bytes the DMA
     no longer moves."""
     from jax.experimental import pallas as pl
@@ -234,10 +252,9 @@ def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
     else:
         (table_ref, nblk_ref, q_ref, pos_ref, k_ref, v_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
-        ks_ref = vs_ref = None
     j = pl.program_id(2)
     nblk = nblk_ref[pl.program_id(0), pl.program_id(1)]
-    block_q = q_ref.shape[1]
+    block_q, head_dim = q_ref.shape[1], q_ref.shape[3]
 
     @pl.when(j == 0)
     def _init():
@@ -250,16 +267,22 @@ def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
         kpos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_size), 1)
         vis = kpos <= pos_ref[0]                            # [bq, bs]
+        if quantized:
+            # a head's scales as a ROW [1, bs], laid on its scores and
+            # its probabilities: q.(c*s) = (q.c)*s, p@(c*s) = (p*s)@c.
+            # Scaling K and V themselves by a [bs, 1] column cut out of
+            # the lanes of the scale block cost more than the attention
+            ks_t, vs_t = ks_ref[0].T, vs_ref[0].T           # [N, bs]
         for h in range(num_heads):
             q = q_ref[0, :, h, :].astype(jnp.float32)       # [bq, D]
-            kb = k_ref[0, :, h, :].astype(jnp.float32)      # [bs, D]
-            vb = v_ref[0, :, h, :].astype(jnp.float32)
-            if ks_ref is not None:
-                kb = kb * ks_ref[0, :, h:h + 1]
-                vb = vb * vs_ref[0, :, h:h + 1]
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            kb = k_ref[0, :, lanes].astype(jnp.float32)     # [bs, D]
+            vb = v_ref[0, :, lanes].astype(jnp.float32)
             sc = jax.lax.dot_general(
                 q, kb, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
+            if quantized:
+                sc = sc * ks_t[h:h + 1]
             sc = jnp.where(vis, sc, -jnp.inf)
             m = m_ref[h]                                    # [bq, 1]
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
@@ -270,7 +293,8 @@ def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
             l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1,
                                                  keepdims=True)
             acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p, vb, dimension_numbers=(((1,), (0,)), ((), ())),
+                p * vs_t[h:h + 1] if quantized else p, vb,
+                dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -316,15 +340,10 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
 
     def pool_index(row, i, j, table_ref, nblk_ref):
         live = jnp.minimum(j, nblk_ref[row, i] - 1)
-        return (table_ref[row, live], 0, 0, 0)
-
-    def scale_index(*args):
-        # the scales ride the exact pool-block routing K/V use (same
-        # dead-slot clamp, so their copy is skipped together)
-        return pool_index(*args)[:3]
+        return (table_ref[row, live], 0, 0)
 
     q_spec = pl.BlockSpec((1, block_q, n, d), q_index)
-    pool_spec = pl.BlockSpec((1, bs_blk, n, d), pool_index)
+    pool_spec = pl.BlockSpec((1, bs_blk, n * d), pool_index)
     in_specs = [
         q_spec,
         pl.BlockSpec((1, block_q, 1),
@@ -334,7 +353,9 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
     ]
     inputs = [table, nblk, q, pos[..., None], k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs_blk, n), scale_index)] * 2
+        # the scales ride the exact pool-block routing K/V use (same
+        # dead-slot clamp, so their copy is skipped together)
+        in_specs += [pl.BlockSpec((1, bs_blk, n), pool_index)] * 2
         inputs += [k_scale.astype(jnp.float32),
                    v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -379,7 +400,9 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
     "gather" is PR 8's materialize-the-view reference oracle;
     "blockwise"/"pallas" force a specific fused formulation
     (``interpret``/``force_pallas`` route the kernel through the
-    Pallas interpreter for CPU tests). ``k_scale``/``v_scale``
+    Pallas interpreter for CPU tests). The pools are flat,
+    ``[P, block_size, N * D]``, with ``N`` and ``D`` read off ``q``
+    (module docstring, "Pool layout"). ``k_scale``/``v_scale``
     (``[P, block_size, heads]`` float32, both or neither) mark the
     pools as int8 codes and dequantize them inside the chosen
     formulation — see the module docstring's int8-KV section."""
@@ -388,6 +411,12 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
     block_table = jnp.asarray(block_table, jnp.int32)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
+    flat = (k_pool.shape[1], q.shape[2] * q.shape[3])
+    if k_pool.shape[1:] != flat or v_pool.shape[1:] != flat:
+        raise ValueError(
+            "pools must be [P, block_size, heads * head_dim] = [P, {}, "
+            "{}] for q {}, got {} and {}".format(
+                flat[0], flat[1], q.shape, k_pool.shape, v_pool.shape))
     if impl in (None, "auto"):
         impl = "pallas" if (force_pallas or on_tpu()) else "blockwise"
     if impl == "gather":

@@ -1,9 +1,14 @@
 """Block-granular KV cache accounting: free-list allocator + prefix cache.
 
 The HOST half of the paged KV cache (PR 8). The device half lives in
-models/decoder.py (a ``[num_blocks, block_size, heads, dim]`` K/V pool
-per attention layer, gathered through per-slot block tables); this
-module owns which block holds what:
+models/decoder.py (a ``[num_blocks, block_size, heads * dim]`` K/V pool
+per attention layer, gathered through per-slot block tables; heads and
+dim share the minor axis because the TPU keeps a ``[.., heads, 64]``
+buffer in a layout its own scatter and kernel cannot use, and copied
+the whole pool around every call: ops/paged_attention.py, "Pool
+layout"). A block id here is a row of that pool; nothing in this
+module reads the pool's shape. This module owns which block holds
+what:
 
 - **free-list allocation** — blocks are fixed-size; a sequence consumes
   ``ceil(len / block_size)`` of them as it grows instead of reserving a

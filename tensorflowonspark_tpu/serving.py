@@ -1493,7 +1493,8 @@ class DecodeEngine(object):
             "cached_key", "cached_value", "key_scale", "value_scale")
         kp, vp = leaves["cached_key"], leaves["cached_value"]
         ks, vs = leaves["key_scale"], leaves["value_scale"]
-        n, d = kp.shape[2], kp.shape[3]
+        n = self.model.num_heads
+        d = kp.shape[2] // n    # pools are flat: [P, block, heads * dim]
         depth = int(depth) if depth is not None else self.total_len // 2
         depth = max(1, min(depth, self.total_len))
         q = jnp.zeros((self.slots, 1, n, d), kp.dtype)
@@ -1539,22 +1540,26 @@ class DecodeEngine(object):
         call, or None on a non-int8 engine."""
         if not self._kv_quant:
             return None
-        import importlib
-
         import jax
+        import jax.numpy as jnp
 
-        pa = importlib.import_module(
-            "tensorflowonspark_tpu.ops.paged_attention")
         leaves = self._first_cache_leaves(
             "cached_key", "cached_value", "key_scale", "value_scale")
         kp, vp = leaves["cached_key"], leaves["cached_value"]
         ks, vs = leaves["key_scale"], leaves["value_scale"]
         if self._dequant_probe is None:
             # BOTH pools: a step's attention dequantizes K and V, so a
-            # K-only probe would under-report the add-on by 2x
+            # K-only probe would under-report the add-on by 2x. The
+            # codes are flat ([P, block, heads * dim]) and stay so: a
+            # head's scale is repeated over its lanes, since a view of
+            # the pool with heads apart would time a relayout instead
+            def dequant(codes, scales):
+                lanes = codes.shape[-1] // scales.shape[-1]
+                return codes.astype(jnp.float32) * jnp.repeat(
+                    scales, lanes, axis=-1)
+
             self._dequant_probe = jax.jit(
-                lambda k, ksc, v, vsc: (pa.dequantize_kv(k, ksc),
-                                        pa.dequantize_kv(v, vsc)))
+                lambda k, ksc, v, vsc: (dequant(k, ksc), dequant(v, vsc)))
         jax.block_until_ready(self._dequant_probe(kp, ks, vp, vs))
         for _ in range(max(1, int(reps))):
             with self.timers.timed("dequant"):

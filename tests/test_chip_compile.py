@@ -17,7 +17,9 @@ this one file on purpose.
 """
 
 import importlib
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,7 +138,7 @@ def test_paged_attention_compiles_for_v5e(one_chip, name):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = sds((rows * mb + 1, bs, n, d), jnp.int8 if quant else dtype)
+    pool = sds((rows * mb + 1, bs, n * d), jnp.int8 if quant else dtype)
     scale = sds((rows * mb + 1, bs, n), jnp.float32) if quant else None
 
     def paged(q, k, v, table, pos, ks, vs):
@@ -193,23 +195,56 @@ def test_resnet50_train_step_compiles_for_v5e(topo, chips):
     assert ("all-reduce" in compiled.as_text()) == (chips > 1)
 
 
-def _gpt2_small_paged(one_chip):
-    """(paged model, abstract params, abstract cache, sds) at phase 2's
-    widths: what ``paged_step_fns`` programs are lowered with."""
-    from chip_smoke import GPT2_SMALL
+#: benchmarks/configs/gpt2-large.json's serving shapes at two layers
+#: (the programs repeat per layer): 20 heads of 64, 16 slots over 512
+#: tokens, a pool of 512 blocks of 16 and the scratch row; a small
+#: vocabulary, which no pool-shaped value depends on
+GPT2_LARGE_2L = {"vocab": 512, "hidden": 1280, "num_heads": 20,
+                 "num_layers": 2, "max_len": 1024}
+LARGE_SLOTS, LARGE_TOTAL = 16, 512
+
+
+def _paged_engine_shapes(one_chip, widths, slots, total, blocks,
+                         kv_dtype=""):
+    """(paged model, abstract params, abstract cache, sds): what the
+    engine's ``paged_step_fns`` programs are lowered with, ``slots``
+    rows of ``total`` tokens over a pool of ``blocks`` blocks."""
     from tensorflowonspark_tpu.models.decoder import DecoderLM
 
-    blocks = SLOTS * SEQ // KV_BLOCK
     model = DecoderLM(decode=True, kv_block_size=KV_BLOCK,
-                      kv_blocks=blocks + 1, **GPT2_SMALL)
+                      kv_blocks=blocks + 1, kv_dtype=kv_dtype, **widths)
     variables = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((SLOTS, SEQ), jnp.int32)))
+        jax.random.PRNGKey(0), jnp.zeros((slots, total), jnp.int32)))
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     return (model, _on(one_chip, variables["params"]),
             _on(one_chip, variables["cache"]), sds)
+
+
+def _gpt2_small_paged(one_chip):
+    """Phase 2's widths: GPT-2 small, 8 slots over the whole context."""
+    from chip_smoke import GPT2_SMALL
+
+    return _paged_engine_shapes(one_chip, GPT2_SMALL, SLOTS, SEQ,
+                                SLOTS * SEQ // KV_BLOCK)
+
+
+def _lower_engine_program(program, model, params, cache, sds, slots, total):
+    """The engine's donated ``paged_decode_step`` or ``paged_prefill``
+    (bucket 128), lowered from shapes."""
+    from tensorflowonspark_tpu import generation
+
+    prefill, decode = generation.paged_step_fns(model)
+    key = sds((2,), jnp.uint32)
+    if program == "paged_decode_step":
+        return decode.lower(
+            params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots, total // KV_BLOCK), jnp.int32), key)
+    return prefill.lower(
+        params, cache, sds((total // KV_BLOCK,), jnp.int32),
+        sds((128,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32), key)
 
 
 @pytest.fixture
@@ -251,24 +286,83 @@ def test_engine_programs_carry_their_names_for_v5e(one_chip,
     module of each jitted engine program (the device's ``XLA Modules``
     line, the host's ``PjitFunction(...)`` span) and the Pallas call in
     it — lowered for the chip, nothing compiled."""
-    from tensorflowonspark_tpu import generation
-
-    model, params, cache, sds = _gpt2_small_paged(one_chip)
-    prefill, decode = generation.paged_step_fns(model)
-    key = sds((2,), jnp.uint32)
-    if program == "paged_decode_step":
-        lowered = decode.lower(
-            params, cache, sds((SLOTS,), jnp.int32),
-            sds((SLOTS,), jnp.int32),
-            sds((SLOTS, SEQ // KV_BLOCK), jnp.int32), key)
-    else:
-        lowered = prefill.lower(
-            params, cache, sds((SEQ // KV_BLOCK,), jnp.int32),
-            sds((128,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
-            key)
-    text = lowered.as_text()
+    text = _lower_engine_program(program, *_gpt2_small_paged(one_chip),
+                                 SLOTS, SEQ).as_text()
     assert "module @jit_{} ".format(program) in text
     assert text.count('kernel_name = "paged_attention"') >= 1
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?)\s([a-z][a-z-]*)\(")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]\{([^}]*)\}")
+_DTYPES = {"float32": "f32", "int8": "s8"}
+
+
+def _pool_values(compiled, pool):
+    """(opcode, layout) of every value of the entry computation that
+    holds an array of the pool's type, the layout as the compiler
+    prints it less the memory space: ``2,1,0:T(8,128)``."""
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    dims = ",".join(str(n) for n in pool.shape)
+    found = []
+    for line in entry.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        opcode = m.group(2)
+        if opcode == "custom-call":
+            opcode = re.search(r'custom_call_target="([^"]*)"', line).group(1)
+        found += [(opcode, re.sub(r"S\(\d+\)", "", layout))
+                  for dt, shape, layout in _ARRAY.findall(m.group(1))
+                  if (dt, shape) == (_DTYPES[pool.dtype.name], dims)]
+    return found
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+@pytest.mark.parametrize("program", ["paged_decode_step", "paged_prefill"])
+def test_paged_pool_keeps_one_layout_for_v5e(one_chip, kernel_on_cpu_backend,
+                                             program, kv_dtype):
+    """The donated programs the engine runs, at GPT-2 large's widths,
+    leave the KV pools where and as they are: one row is scattered into
+    the donated buffer and the kernel reads the result. A pool whose
+    minor pair is ``[heads, 64]`` is kept by the runtime in another
+    layout than the scatter and the kernel want, and was copied whole
+    into theirs and back around every call (8 ``copy`` and 306 MB of
+    temporaries in these two-layer programs; 83% of the device's busy
+    time in the ledger's ``gpt2-large-chat``, PR 29).
+
+    The int8 pair is here for what the float32 pair cannot show: an
+    int8 buffer has a tiling of its own (``T(8,128)(4,1)``) and the
+    write quantises first, and the 4-D int8 pool had its 8 ``copy``
+    between two layouts too. What it does NOT assert is staging: the
+    compiler moves a buffer of some ten megabytes through the chip's
+    fast memory on its own account (``copy-start``/``ConcatBitcast``,
+    same layout, no temporaries). It does for the 10.5 MB int8 pools
+    here on the flat pool as it did on the 4-D one; that is its choice
+    by size and room, not a relayout (PERF.md, PR 30, has the int8
+    step's time on the chip)."""
+    layers = GPT2_LARGE_2L["num_layers"]
+    model, params, cache, sds = _paged_engine_shapes(
+        one_chip, GPT2_LARGE_2L, LARGE_SLOTS, LARGE_TOTAL, 512, kv_dtype)
+    compiled = _lower_engine_program(program, model, params, cache, sds,
+                                     LARGE_SLOTS, LARGE_TOTAL).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= layers
+
+    pool = cache["block_0"]["attn"]["cached_key"]
+    values = _pool_values(compiled, pool)
+    stored = {layout for op, layout in values if op == "parameter"}
+    assert len(stored) == 1, values
+    # the scatters' results are pool-shaped, so there is something to see
+    assert sum(op == "fusion" for op, _ in values) >= 2 * layers, values
+    assert not [v for v in values if v[1] not in stored], values
+    assert not [v for v in values if v[0] == "copy"], values
+    if not kv_dtype:
+        assert not [v for v in values
+                    if v[0] in ("copy-start", "ConcatBitcast")], values
+    pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes
+    # every pool is updated in the buffer it came in
+    assert mem.alias_size_in_bytes >= 2 * layers * pool_bytes
 
 
 def test_flash_kernels_carry_their_names_for_v5e(one_chip):

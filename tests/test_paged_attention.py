@@ -28,13 +28,14 @@ pa = importlib.import_module(
 
 
 def _case(seed, b=3, s_q=1, n=4, d=16, pool=11, bs=8, mb=4):
-    """Random pools + per-row tables and positions; every row's table
-    entries are distinct allocated rows (no scratch aliasing) so the
-    live-set accounting in the poison test is exact."""
+    """Random pools (flat, ``[pool, bs, n * d]``: the op's contract)
+    + per-row tables and positions; every row's table entries are
+    distinct allocated rows (no scratch aliasing) so the live-set
+    accounting in the poison test is exact."""
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(b, s_q, n, d), jnp.float32)
-    kp = jnp.asarray(rng.randn(pool, bs, n, d), jnp.float32)
-    vp = jnp.asarray(rng.randn(pool, bs, n, d), jnp.float32)
+    kp = jnp.asarray(rng.randn(pool, bs, n * d), jnp.float32)
+    vp = jnp.asarray(rng.randn(pool, bs, n * d), jnp.float32)
     table = np.stack([rng.choice(np.arange(1, pool), size=mb,
                                  replace=False) for _ in range(b)])
     # each row at its own depth; positions cover first/mid/last block
@@ -63,6 +64,70 @@ def test_pallas_interpret_matches_gather_reference(s_q):
                              interpret=True)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("n,d", [(4, 16), (3, 24)])
+def test_lane_sliced_heads_match_gather_off_the_lane_tile(n, d):
+    """The kernel reads head ``h`` as lanes ``[h*D, (h+1)*D)`` of a
+    flat block. At 4 heads of 16 a block row is 64 lanes, at 3 heads
+    of 24 it is 72 and no head starts on anything round: neither is a
+    multiple of the chip's 128. Every head must still come from ITS
+    lanes: V of head h is put on a scale of 10**h, so a head read
+    from a neighbour's lanes is off by a factor, not by accumulation
+    noise."""
+    q, kp, vp, table, pos = _case(13, s_q=8, n=n, d=d)
+    mags = 10.0 ** np.arange(n)
+    vp = vp * jnp.repeat(jnp.asarray(mags, jnp.float32), d)
+    ref = pa.paged_attention(q, kp, vp, table, pos, impl="gather")
+    pal = pa.paged_attention(q, kp, vp, table, pos, impl="pallas",
+                             interpret=True)
+    per_head = mags[None, None, :, None]
+    np.testing.assert_allclose(np.asarray(pal) / per_head,
+                               np.asarray(ref) / per_head,
+                               atol=2e-6, rtol=2e-6)
+    # the oracle keeps heads apart too: head h is on head h's scale
+    seen = np.abs(np.asarray(ref)).max(axis=(0, 1, 3))
+    assert np.all(seen > 0.1 * mags) and np.all(seen < 10 * mags), seen
+
+
+@pytest.mark.parametrize("s_q", [1, 8])
+def test_int8_scales_on_scores_match_dequantized_gather(s_q):
+    """On int8 pools the kernel never scales K or V: it lays a head's
+    scales on its scores and its probabilities (``q.(c*s) = (q.c)*s``,
+    ``p@(c*s) = (p*s)@c``). The same products in another order, so it
+    must agree to accumulation noise with the plain form: the pools
+    dequantized whole by ``dequantize_kv``, then ``_gather`` on the
+    floats. Heads on scales a factor of ten apart, so a head under a
+    neighbour's scale is off by that factor."""
+    q, kp, vp, table, pos = _case(17, s_q=s_q)
+    n, d = q.shape[2:]
+    mags = 10.0 ** np.arange(n)
+    vp = vp * jnp.repeat(jnp.asarray(mags, jnp.float32), d)
+    (qk, sk), (qv, sv) = (_quantize_pool(p, q) for p in (kp, vp))
+    plain_k, plain_v = (
+        pa.dequantize_kv(c.reshape(s.shape + (d,)), s).reshape(c.shape)
+        for c, s in ((qk, sk), (qv, sv)))
+    ref = pa._gather(q, plain_k, plain_v, table, pos, d ** -0.5)
+    pal = pa.paged_attention(q, qk, qv, table, pos, impl="pallas",
+                             interpret=True, k_scale=sk, v_scale=sv)
+    per_head = mags[None, None, :, None]
+    np.testing.assert_allclose(np.asarray(pal) / per_head,
+                               np.asarray(ref) / per_head,
+                               atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("impl", [None, "gather", "blockwise", "pallas"])
+def test_pool_with_heads_apart_is_refused(impl):
+    """No formulation takes a ``[P, block, N, D]`` pool: reshaping it
+    on entry IS the whole-pool relayout copy the flat pool exists to
+    avoid. The error names the shape asked for and the shape given."""
+    q, kp, vp, table, pos = _case(5)
+    four_d = kp.shape[:2] + q.shape[2:]
+    with pytest.raises(ValueError) as err:
+        pa.paged_attention(q, kp.reshape(four_d), vp.reshape(four_d),
+                           table, pos, impl=impl)
+    assert "[P, 8, 64]" in str(err.value)
+    assert str(four_d) in str(err.value)
 
 
 def test_overshooting_pad_rows_match_reference():
@@ -119,9 +184,17 @@ def _int8_case(seed, **kw):
     to accumulation noise (the int8 parity contract; the float-vs-int8
     ERROR is the engine-level agreement test's business)."""
     q, kp, vp, table, pos = _case(seed, **kw)
-    qk, sk = pa.quantize_kv(kp)
-    qv, sv = pa.quantize_kv(vp)
+    qk, sk = _quantize_pool(kp, q)
+    qv, sv = _quantize_pool(vp, q)
     return q, qk, qv, sk, sv, table, pos
+
+
+def _quantize_pool(pool, q):
+    """Per-head codes of a flat float pool, flat again, and their
+    ``[pool, bs, n]`` scales — what models/decoder.py's write does."""
+    codes, scales = pa.quantize_kv(
+        pool.reshape(pool.shape[:2] + q.shape[2:]))
+    return codes.reshape(pool.shape), scales
 
 
 def test_quantize_kv_round_trip_exact():
@@ -189,8 +262,8 @@ def test_int8_scales_validated_and_close_to_float():
     grid is fine enough that attention outputs move by quantization
     noise, not structure)."""
     q, kp, vp, table, pos = _case(9)
-    qk, sk = pa.quantize_kv(kp)
-    qv, sv = pa.quantize_kv(vp)
+    qk, sk = _quantize_pool(kp, q)
+    qv, sv = _quantize_pool(vp, q)
     with pytest.raises(ValueError, match="together"):
         pa.paged_attention(q, qk, qv, table, pos, k_scale=sk)
     ref = pa.paged_attention(q, kp, vp, table, pos, impl="gather")

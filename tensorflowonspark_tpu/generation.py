@@ -380,6 +380,37 @@ def _set_paged_leaves(cache, idx, tables):
     return jax.tree_util.tree_map_with_path(repl, cache)
 
 
+def _slot_view(cache, table_row, start):
+    """The batch-1 view of ONE slot on the shared pool: cursor leaves at
+    ``start``, block-table leaves the slot's row, pool leaves as they
+    are (they are batch-independent)."""
+    table_row = jnp.asarray(table_row, jnp.int32)
+    start = jnp.asarray(start, jnp.int32)
+
+    def view(path, leaf):
+        name = _leaf_name(path)
+        if name in _CURSOR_LEAVES:
+            return jnp.full((1,), start, leaf.dtype)
+        if name == "block_table":
+            return table_row[None, :].astype(leaf.dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(view, cache)
+
+
+def _merge_pools(cache, updated):
+    """``cache`` with the pool leaves of ``updated`` (a slot view after
+    its call): the engine-shaped [S] cursor and [S, MB] table leaves
+    keep their (host-overwritten-anyway) storage so the cache pytree's
+    shapes never change."""
+    def merge(path, big, new):
+        if _leaf_name(path) in _CURSOR_LEAVES + ("block_table",):
+            return big
+        return new
+
+    return jax.tree_util.tree_map_with_path(merge, cache, updated)
+
+
 def paged_prefill_into_slot(model, params, cache, table_row, tokens,
                             tail_len, start, temperature=0.0, top_k=None,
                             top_p=None, rng=None):
@@ -400,36 +431,14 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
     ``(cache', first_token)`` with the first generated token picked
     from the logits at the last real tail position (so a warm
     ``max_new_tokens=1`` request costs one tiny-bucket forward)."""
-    table_row = jnp.asarray(table_row, jnp.int32)
-    start = jnp.asarray(start, jnp.int32)
     tail_len = jnp.asarray(tail_len, jnp.int32)
-
-    def view(path, leaf):
-        name = _leaf_name(path)
-        if name in _CURSOR_LEAVES:
-            return jnp.full((1,), start, leaf.dtype)
-        if name == "block_table":
-            return table_row[None, :].astype(leaf.dtype)
-        return leaf
-
-    mini = jax.tree_util.tree_map_with_path(view, cache)
     logits, upd = model.apply(
-        {"params": params, "cache": mini}, tokens[None, :],
-        mutable=["cache"])
+        {"params": params, "cache": _slot_view(cache, table_row, start)},
+        tokens[None, :], mutable=["cache"])
     cap = jax.lax.dynamic_index_in_dim(
         logits, tail_len - 1, axis=1, keepdims=False)
     first = _pick_tokens(cap, rng, temperature, top_k, top_p)[0]
-
-    def merge(path, big, new):
-        # pool leaves take the update; the engine-shaped [S] cursor and
-        # [S, MB] table leaves keep their (host-overwritten-anyway)
-        # storage so the cache pytree's shapes never change
-        if _leaf_name(path) in _CURSOR_LEAVES + ("block_table",):
-            return big
-        return new
-
-    cache = jax.tree_util.tree_map_with_path(merge, cache, upd["cache"])
-    return cache, first
+    return _merge_pools(cache, upd["cache"]), first
 
 
 def paged_decode_step(model, params, cache, tokens, idx, tables,
@@ -473,6 +482,136 @@ def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
 
     return (jax.jit(paged_prefill, donate_argnums=(1,)),
             jax.jit(paged_decode_step, donate_argnums=(1,)))
+
+
+# -- block-stepping primitives (diffusion over blocks) -------------------
+#
+# A model that generates by diffusion over blocks (models/sdar_moe.py:
+# its ``block_len`` field says so) does not yield one token per row per
+# step. A step runs the ``block_len`` positions of every slot's current
+# block against the paged cache - their K/V computed from the block's
+# current, partly masked state and written at the slot's cursor - and
+# answers, per position, the most probable token and its probability.
+# The HOST decides what that means (:func:`unmask`): it keeps the
+# cursor where it is while masks are left (so the next pass overwrites
+# the block's K/V) and moves it on by ``block_len`` after the pass that
+# ran the final tokens (the commit). Every slot is in its own phase, so
+# there is ONE step program of shape ``[slots, block_len]``; a prefill
+# writes the prompt's whole blocks and samples nothing.
+
+
+def _expert_ids(intermediates):
+    """``[layers, positions, experts_per_tok]``: the experts the router
+    sent each position of this call to, as the model's expert layers
+    sowed them. Padding and idle slots' positions are routed like any
+    other: the caller, who knows which are live, does the counting."""
+    return jnp.stack(jax.tree.leaves(intermediates))
+
+
+def paged_block_prefill(model, params, cache, table_row, tokens, start):
+    """Write the K/V of whole prompt blocks ``tokens [bucket]`` (padded;
+    pad rows land past the slot's cursor or in scratch) from logical
+    position ``start`` into the blocks ``table_row`` maps. No head, no
+    token: returns ``(cache', expert_ids [layers, bucket, k])``."""
+    _, upd = model.apply(
+        {"params": params, "cache": _slot_view(cache, table_row, start)},
+        tokens[None, :], head=False, mutable=["cache", "intermediates"])
+    return _merge_pools(cache, upd["cache"]), \
+        _expert_ids(upd["intermediates"])
+
+
+def paged_block_step(model, params, cache, tokens, idx, tables):
+    """One pass over every slot's current block: ``tokens [S, B]`` (MASK
+    where a position is still masked) at cursors ``idx [S]``. Returns
+    ``(cache', (best [S, B] int32, confidence [S, B] float32,
+    expert_ids [layers, S * B, k] int32))``: the most probable token of
+    each position and its probability."""
+    cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
+                              jnp.asarray(tables, jnp.int32))
+    logits, upd = model.apply(
+        {"params": params, "cache": cache}, tokens,
+        mutable=["cache", "intermediates"])
+    top = jnp.max(logits, axis=-1)
+    conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
+    return upd["cache"], (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                          conf, _expert_ids(upd["intermediates"]))
+
+
+_paged_block_prefill, _paged_block_step = paged_block_prefill, \
+    paged_block_step
+
+
+@functools.lru_cache(maxsize=32)
+def paged_block_fns(model):
+    """(jitted block prefill, jitted block step) for one block-stepping
+    model, cache-donating - the sibling of :func:`paged_step_fns`, same
+    compile-count contract (one step program, one prefill per bucket)
+    and the same naming of programs.
+
+    The step runs between every two host decisions with the device
+    waiting on both sides, and every array that crosses is a round
+    trip of its own. So it takes ONE array (:func:`pack_block_feed`)
+    and answers ONE (:func:`unpack_block_step`): ``step(params, cache,
+    feed [S, B + 1 + MB] int32) -> (cache', answers int32)``."""
+    def paged_block_prefill(params, cache, table_row, tokens, start):
+        return _paged_block_prefill(model, params, cache, table_row,
+                                    tokens, start)
+
+    def paged_block_step(params, cache, feed):
+        b = model.block_len
+        cache, (best, conf, routed) = _paged_block_step(
+            model, params, cache, feed[:, :b], feed[:, b], feed[:, b + 1:])
+        # the confidences bit for bit among the int32
+        return cache, jnp.concatenate([
+            best.ravel(),
+            jax.lax.bitcast_convert_type(conf, jnp.int32).ravel(),
+            routed.astype(jnp.int32).ravel()])
+
+    return (jax.jit(paged_block_prefill, donate_argnums=(1,)),
+            jax.jit(paged_block_step, donate_argnums=(1,)))
+
+
+def pack_block_feed(tokens, idx, tables):
+    """The jitted block step's one argument (host side, numpy): each
+    slot's block ``tokens [S, B]``, its cursor ``idx [S]`` and its
+    table row ``tables [S, MB]`` side by side, int32."""
+    import numpy as np
+
+    return np.concatenate([tokens, idx[:, None], tables],
+                          axis=1, dtype=np.int32)
+
+
+def unpack_block_step(packed, slots, block_len, top_k):
+    """``(best [S, B] int32, confidence [S, B] float32, expert_ids
+    [layers, S * B, top_k] int32)`` out of the one array the jitted
+    block step of :func:`paged_block_fns` answers (host side, numpy;
+    views, nothing is copied)."""
+    import numpy as np
+
+    packed = np.asarray(packed)
+    n = slots * block_len
+    return (packed[:n].reshape(slots, block_len),
+            packed[n:2 * n].view(np.float32).reshape(slots, block_len),
+            packed[2 * n:].reshape(-1, n, top_k))
+
+
+def unmask(conf, masked, quota, threshold):
+    """Which masked positions of a block a denoising pass unmasks (host
+    side, numpy): every one whose confidence exceeds ``threshold``, or,
+    if those are fewer than ``quota``, the ``quota`` most confident
+    (``low_confidence_dynamic``; a threshold of 1 or more is the static
+    schedule of ``quota`` a pass). Ties go to the earlier position.
+    Boolean, ``masked``'s shape: one block ``[B]``, or the blocks of
+    many rows ``[..., B]`` at once, each by itself."""
+    import numpy as np
+
+    conf = np.where(masked, conf, -np.inf)
+    high = conf > threshold
+    # rank 0 = the most confident; unmasked positions (-inf) come last
+    rank = np.argsort(np.argsort(-conf, axis=-1, kind="stable"),
+                      axis=-1, kind="stable")
+    return np.where(high.sum(axis=-1, keepdims=True) >= quota, high,
+                    (rank < quota) & masked)
 
 
 # -- KV block-row shipping primitives (PR 17) ---------------------------

@@ -67,6 +67,116 @@ import jax
 import jax.numpy as jnp
 
 
+class PagedKV(object):
+    """One attention module's paged K/V cache: the ONE place the pool,
+    the block table and the write cursor are declared, written and
+    attended through, shared by every decoder family (``DecoderLM``
+    here, ``models/sdar_moe.py``).
+
+    Built inside a module's ``__call__`` it declares, in ``mod``'s
+    ``cache`` collection, the flat pools ``cached_key``/``cached_value``
+    ``[kv_blocks, block_size, kv_heads * head_dim]`` (int8 codes plus
+    ``key_scale``/``value_scale`` ``[.., kv_heads]`` when
+    ``quantized``), the per-row ``block_table [B, ceil(s / block_size)]``
+    (sized at CREATION from the dummy pass's length — init_cache's
+    total_len; entry 0, the scratch block, everywhere until the host
+    allocator assigns real blocks) and the per-row ``cache_index [B]``.
+    ``initialized`` is False on that creation pass (shapes only).
+    """
+
+    def __init__(self, mod, b, s, kv_heads, head_dim, dtype, block_size,
+                 blocks, quantized=False):
+        if blocks < 2:
+            raise ValueError(
+                "paged decode needs kv_blocks >= 2 (row 0 is the scratch "
+                "block), got {}".format(blocks))
+        self.initialized = mod.has_variable("cache", "cached_key")
+        self.block_size, self.quantized = block_size, quantized
+        shape = (blocks, block_size, kv_heads * head_dim)
+        store = jnp.int8 if quantized else dtype
+        self.key = mod.variable("cache", "cached_key", jnp.zeros, shape,
+                                store)
+        self.value = mod.variable("cache", "cached_value", jnp.zeros,
+                                  shape, store)
+        if quantized:
+            # per-head scales, one per token row of each block, stored
+            # block-aligned so attention's index maps route them with
+            # the codes (ones: dequant of the zero codes stays exactly
+            # zero)
+            self.key_scale = mod.variable(
+                "cache", "key_scale", jnp.ones, shape[:2] + (kv_heads,),
+                jnp.float32)
+            self.value_scale = mod.variable(
+                "cache", "value_scale", jnp.ones, shape[:2] + (kv_heads,),
+                jnp.float32)
+        self.table = mod.variable(
+            "cache", "block_table",
+            lambda: jnp.zeros((b, -(-s // block_size)), jnp.int32))
+        # Per-ROW write cursor [B], not a scalar: each batch row is an
+        # independent sequence (a serving "slot") at its own depth.
+        self.index = mod.variable(
+            "cache", "cache_index", lambda: jnp.zeros((b,), jnp.int32))
+
+    def positions(self, s):
+        """Logical positions ``[B, s]`` this call's tokens sit at."""
+        return self.index.value[:, None] + jnp.arange(s)[None, :]
+
+    def attend(self, q, k, v, pos, visible=None, attn_impl="fused"):
+        """Write ``k``/``v`` ``[B, s, kv_heads, D]`` at ``pos`` through
+        the block table, advance the cursor by ``s``, and attend ``q
+        [B, s, heads, D]`` through the table: query ``i`` of row ``b``
+        sees every key position ``<= visible[b, i]`` (``pos`` itself
+        when None: causal). The fused formulation (default) streams the
+        row's LIVE blocks through an online softmax; ``"gather"``
+        materializes the logical [B, L] view and attends exactly like a
+        contiguous cache (same mask, same einsums — the PR 8 reference
+        oracle)."""
+        import importlib
+
+        if attn_impl not in ("fused", "gather"):
+            raise ValueError(
+                "attn_impl must be 'fused' or 'gather', got "
+                "{!r}".format(attn_impl))
+        pa = importlib.import_module(
+            "tensorflowonspark_tpu.ops.paged_attention")
+        b, s = pos.shape
+        bs_blk = self.block_size
+        table = self.table.value                   # [B, MB]
+        mb = table.shape[1]
+        blk_idx = pos // bs_blk
+        # pad positions past the logical capacity route to the scratch
+        # block (pool row 0): bucket-padded prefill tails can overshoot
+        # L, and a clamped write would otherwise land on a VISIBLE
+        # offset of whatever block sits in the last table entry
+        blk = jnp.take_along_axis(
+            table, jnp.minimum(blk_idx, mb - 1), axis=1)
+        blk = jnp.where(blk_idx < mb, blk, 0)
+        off = pos % bs_blk
+        if self.quantized:
+            # int8 fast path (PR 15): quantize at write time (per head,
+            # per token row), scatter codes AND scales through the same
+            # table routing; attention dequantizes in-formulation so
+            # the per-step HBM traffic is the int8 bytes
+            (k, sk), (v, sv) = pa.quantize_kv(k), pa.quantize_kv(v)
+            ksc = self.key_scale.value.at[blk, off].set(sk)
+            vsc = self.value_scale.value.at[blk, off].set(sv)
+            self.key_scale.value = ksc
+            self.value_scale.value = vsc
+        else:
+            ksc = vsc = None
+        # a token's heads are one row of the flat pool
+        pk = self.key.value.at[blk, off].set(k.reshape(b, s, -1))
+        pv = self.value.value.at[blk, off].set(v.reshape(b, s, -1))
+        self.key.value = pk
+        self.value.value = pv
+        self.index.value = self.index.value + s
+        return pa.paged_attention(
+            q, pk, pv, table, pos if visible is None else visible,
+            scale=q.shape[-1] ** -0.5,
+            impl=None if attn_impl == "fused" else "gather",
+            k_scale=ksc, v_scale=vsc)
+
+
 class CausalSelfAttention(nn.Module):
     """Causal attention: fused flash kernel for training, explicit KV
     cache for decode.
@@ -127,112 +237,36 @@ class CausalSelfAttention(nn.Module):
             paged = self.kv_block_size > 0
             is_initialized = self.has_variable("cache", "cached_key")
             if paged:
-                if self.kv_blocks < 2:
-                    raise ValueError(
-                        "paged decode needs kv_blocks >= 2 (row 0 is "
-                        "the scratch block), got {}".format(
-                            self.kv_blocks))
                 if self.kv_dtype not in ("", "int8"):
                     raise ValueError(
                         "kv_dtype must be '' (compute dtype) or "
                         "'int8', got {!r}".format(self.kv_dtype))
-                kv_q = self.kv_dtype == "int8"
-                bs_blk = self.kv_block_size
-                pool_shape = (self.kv_blocks, bs_blk, h)
-                cached_key = self.variable(
-                    "cache", "cached_key", jnp.zeros, pool_shape,
-                    jnp.int8 if kv_q else k.dtype)
-                cached_value = self.variable(
-                    "cache", "cached_value", jnp.zeros, pool_shape,
-                    jnp.int8 if kv_q else v.dtype)
-                if kv_q:
-                    # per-head scales, one per token row of each block,
-                    # stored block-aligned so attention's index maps
-                    # route them with the codes (ones: dequant of the
-                    # zero codes stays exactly zero)
-                    key_scale = self.variable(
-                        "cache", "key_scale", jnp.ones,
-                        pool_shape[:2] + (self.num_heads,), jnp.float32)
-                    value_scale = self.variable(
-                        "cache", "value_scale", jnp.ones,
-                        pool_shape[:2] + (self.num_heads,), jnp.float32)
-                # per-row block table [B, MB]: logical block j of row b
-                # lives in pool row table[b, j]. Sized at CREATION from
-                # the dummy pass's length (init_cache's total_len);
-                # entry 0 (the scratch block) everywhere until the host
-                # allocator assigns real blocks.
-                block_table = self.variable(
-                    "cache", "block_table",
-                    lambda: jnp.zeros((b, -(-s // bs_blk)), jnp.int32))
+                pool = PagedKV(self, b, s, self.num_heads, head_dim,
+                               k.dtype, self.kv_block_size, self.kv_blocks,
+                               quantized=self.kv_dtype == "int8")
             else:
                 cached_key = self.variable(
                     "cache", "cached_key", jnp.zeros, k.shape, k.dtype)
                 cached_value = self.variable(
                     "cache", "cached_value", jnp.zeros, v.shape, v.dtype)
-            # Per-ROW write cursor [B], not a scalar: each batch row is an
-            # independent sequence (a serving "slot"), so row b writes its
-            # token at its own position and attends its own prefix. Whole-
-            # batch generation is the degenerate case where every row
-            # carries the same index — bitwise-identical to the old scalar
-            # formulation (the mask/scatter broadcasts agree elementwise).
-            cache_index = self.variable(
-                "cache", "cache_index",
-                lambda: jnp.zeros((b,), jnp.int32))
+                # Per-ROW write cursor [B], not a scalar: each batch row
+                # is an independent sequence (a serving "slot"), so row b
+                # writes its token at its own position and attends its
+                # own prefix. Whole-batch generation is the degenerate
+                # case where every row carries the same index — bitwise-
+                # identical to the old scalar formulation (the
+                # mask/scatter broadcasts agree elementwise).
+                cache_index = self.variable(
+                    "cache", "cache_index",
+                    lambda: jnp.zeros((b,), jnp.int32))
             if is_initialized and paged:
                 # PAGED step/prefill, any s: write K/V for logical
                 # positions [idx, idx+s) through the block table, then
-                # attend through the table via ops/paged_attention.py —
-                # the fused formulation (default) streams the row's
-                # LIVE blocks through an online softmax; the gather
-                # formulation materializes the logical [B, L] view and
-                # attends exactly like the contiguous branches below
-                # (same mask, same einsums — the PR 8 reference
-                # oracle). s==1 is a decode step; s>1 a fused
-                # (possibly mid-sequence, prefix-cached) prefill.
-                if self.attn_impl not in ("fused", "gather"):
-                    raise ValueError(
-                        "attn_impl must be 'fused' or 'gather', got "
-                        "{!r}".format(self.attn_impl))
-                pa = importlib.import_module(
-                    "tensorflowonspark_tpu.ops.paged_attention")
-                idx = cache_index.value                    # [B]
-                table = block_table.value                  # [B, MB]
-                mb = table.shape[1]
-                pos = idx[:, None] + jnp.arange(s)[None, :]  # [B, s]
-                blk_idx = pos // bs_blk
-                # pad positions past the logical capacity route to the
-                # scratch block (pool row 0): bucket-padded prefill
-                # tails can overshoot L, and a clamped write would
-                # otherwise land on a VISIBLE offset of whatever block
-                # sits in the last table entry
-                blk = jnp.take_along_axis(
-                    table, jnp.minimum(blk_idx, mb - 1), axis=1)
-                blk = jnp.where(blk_idx < mb, blk, 0)
-                off = pos % bs_blk
-                if kv_q:
-                    # int8 fast path (PR 15): quantize at write time
-                    # (per head, per token row), scatter codes AND
-                    # scales through the same table routing; attention
-                    # dequantizes in-formulation so the per-step HBM
-                    # traffic is the int8 bytes
-                    (k, sk), (v, sv) = pa.quantize_kv(k), pa.quantize_kv(v)
-                    ksc = key_scale.value.at[blk, off].set(sk)
-                    vsc = value_scale.value.at[blk, off].set(sv)
-                    key_scale.value = ksc
-                    value_scale.value = vsc
-                else:
-                    ksc = vsc = None
-                # a token's heads are one row of the flat pool
-                pk = cached_key.value.at[blk, off].set(k.reshape(b, s, h))
-                pv = cached_value.value.at[blk, off].set(
-                    v.reshape(b, s, h))
-                cached_key.value = pk
-                cached_value.value = pv
-                cache_index.value = idx + s
-                ctx = pa.paged_attention(
-                    q, pk, pv, table, pos, scale=head_dim ** -0.5,
-                    impl=None if self.attn_impl == "fused"
-                    else "gather", k_scale=ksc, v_scale=vsc)
+                # attend through the table (PagedKV.attend). s==1 is a
+                # decode step; s>1 a fused (possibly mid-sequence,
+                # prefix-cached) prefill.
+                ctx = pool.attend(q, k, v, pool.positions(s),
+                                  attn_impl=self.attn_impl)
             elif is_initialized and s == 1:
                 # one token per step against the cache prefix
                 idx = cache_index.value

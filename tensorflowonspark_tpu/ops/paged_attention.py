@@ -55,7 +55,13 @@ BEFORE attending (models/decoder.py), so the current token sees
 itself. Layout: ``q [B, S_q, N, D]``, pools
 ``[P, block_size, N * D]`` (``N`` and ``D`` are taken from ``q``),
 ``block_table [B, MB]`` int32, ``pos [B, S_q]`` int32; returns
-``[B, S_q, N, D]``.
+``[B, S_q, N, D]``. ``pos`` is what a query may SEE, not where it sits:
+a caller whose mask is not causal passes another position (block
+diffusion, models/sdar_moe.py: the last position of the query's block).
+A pool may hold FEWER heads than ``q`` (grouped-query attention): its
+width over ``D`` is the number of K/V heads, query head ``h`` reads K/V
+head ``h // group``, and the group is folded into query rows before any
+formulation runs (:func:`paged_attention`).
 
 Pool layout (PR 30): the pools are FLAT, heads and head_dim in one
 minor axis, because of what the chip does with anything else. The TPU
@@ -401,8 +407,10 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
     "blockwise"/"pallas" force a specific fused formulation
     (``interpret``/``force_pallas`` route the kernel through the
     Pallas interpreter for CPU tests). The pools are flat,
-    ``[P, block_size, N * D]``, with ``N`` and ``D`` read off ``q``
-    (module docstring, "Pool layout"). ``k_scale``/``v_scale``
+    ``[P, block_size, kv_heads * D]``, with ``D`` read off ``q`` and
+    ``kv_heads`` off the pool's width (module docstring, "Pool layout";
+    fewer than ``q``'s heads is grouped-query attention).
+    ``k_scale``/``v_scale``
     (``[P, block_size, heads]`` float32, both or neither) mark the
     pools as int8 codes and dequantize them inside the chosen
     formulation — see the module docstring's int8-KV section."""
@@ -411,12 +419,34 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
     block_table = jnp.asarray(block_table, jnp.int32)
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
-    flat = (k_pool.shape[1], q.shape[2] * q.shape[3])
-    if k_pool.shape[1:] != flat or v_pool.shape[1:] != flat:
+    b, s_q, n, d = q.shape
+    kv_heads, rest = divmod(k_pool.shape[-1], d)
+    if k_pool.ndim != 3 or v_pool.shape != k_pool.shape or rest \
+            or not kv_heads or n % kv_heads:
         raise ValueError(
-            "pools must be [P, block_size, heads * head_dim] = [P, {}, "
-            "{}] for q {}, got {} and {}".format(
-                flat[0], flat[1], q.shape, k_pool.shape, v_pool.shape))
+            "pools must be [P, block_size, kv_heads * head_dim] = "
+            "[P, {}, {}] for q {} (or narrower by a whole group of query "
+            "heads), got {} and {}".format(
+                k_pool.shape[1], n * d, q.shape, k_pool.shape,
+                v_pool.shape))
+    group = n // kv_heads
+    if group > 1:
+        # Fewer K/V heads than query heads: query head h reads K/V head
+        # h // group. The group's queries of one position become
+        # ``group`` query ROWS of their K/V head at that position, and
+        # every formulation below runs as it does for equal heads
+        # (group 1 takes none of this: the same program as before).
+        if k_scale is not None:
+            raise ValueError("int8 pools need as many K/V heads as "
+                             "query heads")
+        q = q.reshape(b, s_q, kv_heads, group, d).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, s_q * group, kv_heads, d)
+        out = paged_attention(
+            q, k_pool, v_pool, block_table, jnp.repeat(pos, group, axis=1),
+            scale=scale, impl=impl, interpret=interpret,
+            force_pallas=force_pallas)
+        return out.reshape(b, s_q, group, kv_heads, d) \
+            .transpose(0, 1, 3, 2, 4).reshape(b, s_q, n, d)
     if impl in (None, "auto"):
         impl = "pallas" if (force_pallas or on_tpu()) else "blockwise"
     if impl == "gather":

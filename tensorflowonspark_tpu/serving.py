@@ -272,6 +272,11 @@ class GenerationHandle(object):
         self.trace = int(trace) if trace is not None \
             else tracing.next_trace_id()
         self._tokens = []
+        # block-stepping engines only: for each token the pass of its
+        # block (0 = the first) at which it was unmasked, and how many
+        # tokens each delivery handed over
+        self._passes = []
+        self._deliveries = []
         self._q = queue_mod.Queue()
         self._done = threading.Event()
         self._error = None
@@ -290,6 +295,14 @@ class GenerationHandle(object):
     def _emit(self, token):
         self._tokens.append(int(token))
         self._q.put(int(token))
+
+    def _emit_block(self, tokens, passes):
+        """One delivery of a whole block: ``stream()`` still yields its
+        tokens one by one, all at once."""
+        self._tokens.extend(tokens)
+        self._passes.extend(passes)
+        self._deliveries.append(len(tokens))
+        self._q.put(tuple(tokens))
 
     def _finish(self, error=None):
         self._error = error
@@ -346,7 +359,11 @@ class GenerationHandle(object):
                     raise self._error
                 return
             try:
-                yield item
+                if isinstance(item, tuple):  # a block, delivered whole
+                    for token in item:
+                        yield token
+                else:
+                    yield item
             except GeneratorExit:
                 # close()/GC landed at the yield: the consumer is gone
                 # (cancel() is a no-op if the request already finished)
@@ -366,6 +383,21 @@ class GenerationHandle(object):
     def generated(self):
         """Tokens emitted so far (complete once :meth:`result` returns)."""
         return list(self._tokens)
+
+    @property
+    def unmask_passes(self):
+        """From a block-stepping engine: for each generated token the
+        pass of its block (0 = the first) at which it was unmasked, so
+        that every intermediate state of every block can be rebuilt
+        from what was served. Empty from a token-stepping engine."""
+        return list(self._passes)
+
+    @property
+    def deliveries(self):
+        """From a block-stepping engine: how many tokens each delivery
+        handed over, in order (``stream()`` yields them one by one; the
+        tokens of one delivery arrive together)."""
+        return list(self._deliveries)
 
     @property
     def latency(self):
@@ -769,6 +801,21 @@ class DecodeEngine(object):
                 "tfos_qos_queue_wait_{}_seconds".format(name))
             for name in qos.PRIORITIES}
         self._temperature = float(temperature)
+        #: positions per step and row: 0 for a model that yields one
+        #: token per step, ``model.block_len`` for one that generates by
+        #: diffusion over blocks (models/sdar_moe.py). Read off the
+        #: MODEL: there is no engine option for it.
+        self._block_len = int(getattr(model, "block_len", 0) or 0)
+        if self._block_len:
+            self._check_block_mode(
+                model, total_len, temperature=temperature, top_k=top_k,
+                top_p=top_p, eos_token=eos_token,
+                kv_block_size=kv_block_size, attn_impl=attn_impl,
+                speculate_k=speculate_k, kv_dtype=kv_dtype, tier=tier)
+            # denoising passes write a block's K/V before it is final,
+            # so no block of such a sequence is ever registered for
+            # sharing
+            prefix_cache = False
         norm_top_k = None if top_k is None else int(top_k)
         norm_top_p = None if top_p is None else float(top_p)
         # -- paged KV setup (PR 8) ------------------------------------
@@ -793,6 +840,12 @@ class DecodeEngine(object):
                 kv_block_size = 0
         self.kv_block_size = int(kv_block_size)
         self._paged = self.kv_block_size > 0
+        if self.kv_block_size % (self._block_len or 1):
+            # a block must never straddle two KV blocks: growth looks
+            # one position ahead and a commit moves a whole block
+            raise ValueError(
+                "block_len {} must divide the KV block size {}".format(
+                    self._block_len, self.kv_block_size))
         # int8 KV knob (PR 15): None / "fp32" / "float32" keep the
         # compute-dtype pool; "int8" stores quantized codes + per-head
         # scales (models/decoder.py) and halves+ per-step KV bandwidth
@@ -872,8 +925,13 @@ class DecodeEngine(object):
                         type(model).__name__,
                         "/kv_dtype" if self._kv_quant else ""))
             self._model = model
-            self._prefill_fn, self._decode_fn = generation.paged_step_fns(
-                model, self._temperature, norm_top_k, norm_top_p)
+            if self._block_len:
+                self._prefill_fn, self._decode_fn = \
+                    generation.paged_block_fns(model)
+            else:
+                self._prefill_fn, self._decode_fn = \
+                    generation.paged_step_fns(
+                        model, self._temperature, norm_top_k, norm_top_p)
             if self._spec_k:
                 # draft-model speculation (PR 15): a reduced-depth,
                 # weight-TIED clone of the served model proposes
@@ -995,6 +1053,20 @@ class DecodeEngine(object):
             self._slot_registered = [0] * self.slots
             self._attn_probe = None  # measure_attn's cached jit
             self._dequant_probe = None  # measure_dequant's cached jit
+        if self._block_len:
+            # each slot's current block, the host's: its tokens, which
+            # positions are still masked, at which pass of the block
+            # each was unmasked (for the handle), how many leading
+            # positions were given (prompt, or delivered before a
+            # preemption), how many are inside the request, and the
+            # number of denoising passes the block has had
+            blk = (self.slots, self._block_len)
+            self._blk_tok = np.zeros(blk, np.int32)
+            self._blk_masked = np.ones(blk, bool)
+            self._blk_when = np.full(blk, -1, np.int32)
+            self._blk_given = np.zeros(self.slots, np.int32)
+            self._blk_live = np.zeros(self.slots, np.int32)
+            self._blk_pass = np.zeros(self.slots, np.int32)
         self._cache = generation.init_cache(model, self.slots, total_len)
         #: resolved pool storage dtype — the pinned schema string
         #: load_stats / /healthz / the fleet BEAT payload carry
@@ -1014,6 +1086,44 @@ class DecodeEngine(object):
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="tfos-decode-engine")
         self._thread.start()
+
+    @staticmethod
+    def _check_block_mode(model, total_len, temperature, top_k, top_p,
+                          eos_token, kv_block_size, attn_impl, speculate_k,
+                          kv_dtype, tier):
+        """Refuse, with the reason, what an engine that steps by blocks
+        does not do (docs/serving.md, "Stepping by blocks")."""
+        b = int(model.block_len)
+        why = None
+        if temperature or top_k is not None or top_p is not None:
+            why = "it unmasks by confidence at temperature 0: no " \
+                  "temperature, top_k or top_p"
+        elif eos_token is not None:
+            why = "a request ends at max_new_tokens: no eos_token"
+        elif speculate_k is not None:
+            why = "a pass already yields several tokens: no speculate_k"
+        elif kv_dtype is not None:
+            why = "its K/V pool is stored at the model's dtype: no kv_dtype"
+        elif kv_block_size == 0:
+            why = "it decodes through the paged cache only: no " \
+                  "kv_block_size=0"
+        elif attn_impl not in (None, "fused"):
+            why = "it attends through the fused formulation only: no " \
+                  "attn_impl={!r}".format(attn_impl)
+        elif tier != "mixed":
+            why = "it neither ships nor adopts K/V blocks: no " \
+                  "tier={!r}".format(tier)
+        elif total_len % b or (kv_block_size or b) % b:
+            # (an auto-picked KV block size is checked once it is known)
+            why = "block_len {} must divide total_len {} and the KV " \
+                  "block size".format(b, total_len)
+        elif b % int(model.denoise_steps):
+            why = "denoise_steps {} must divide block_len {}".format(
+                model.denoise_steps, b)
+        if why:
+            raise ValueError(
+                "{} generates by diffusion over blocks of {}: {}".format(
+                    type(model).__name__, b, why))
 
     # -- client API ------------------------------------------------------
 
@@ -1121,7 +1231,10 @@ class DecodeEngine(object):
         # tokens per slot, so the effective per-token step time is the
         # ratio — shed decisions stay honest when k is on instead of
         # pricing every token at the (heavier) round cost
-        tpr = max(self._tokens_round_ewma or 1.0, 1.0)
+        # (a block-stepping engine emits FEWER than one token per row
+        # and step: block_len of them in denoise_steps + 1 passes)
+        tpr = max(self._tokens_round_ewma or 1.0,
+                  1.0 / (self._block_len + 1))
         step = step / tpr
         backlog = extra_tokens + sum(h.max_new_tokens
                                      for h in self._queue)
@@ -1703,6 +1816,11 @@ class DecodeEngine(object):
         """A step program's per-step inputs put on the device — each
         slot's last token and cursor, the block tables of a paged
         engine, the key: what ``step_upload`` times."""
+        if self._block_len:
+            # one array, handed over as numpy: the call transfers
+            # it itself, with no ``jnp.asarray`` round trip before it
+            return [self._generation.pack_block_feed(
+                self._block_feed(), self._idx, self._tables)]
         feed = [jnp.asarray(self._last), jnp.asarray(self._idx)]
         if self._paged:
             feed.append(jnp.asarray(self._tables))
@@ -2009,7 +2127,9 @@ class DecodeEngine(object):
                             self._cache, toks = self._decode_fn(
                                 self.params, self._cache, *feed)
                         with self.timers.timed("step_sync"):
-                            # the per-step host sync
+                            # the per-step host sync (a block step
+                            # answers tokens, confidences and the
+                            # routed experts in one array)
                             toks = np.asarray(toks)
                 t1 = time.monotonic()
                 self._step_ewma = self._ewma(self._step_ewma, t1 - t0)
@@ -2031,6 +2151,8 @@ class DecodeEngine(object):
                     if self._spec_k:
                         delivered = self._spec_deliver(active, drafts,
                                                        targets)
+                    elif self._block_len:
+                        delivered = self._block_advance(active, toks)
                     else:
                         for s in active:
                             # the step just WROTE the fed token at
@@ -2207,6 +2329,115 @@ class DecodeEngine(object):
                 self._tokens_round_ewma, delivered / len(active))
         return delivered
 
+    # -- stepping by blocks (scheduler thread only) ----------------------
+    #
+    # The host half of generation by diffusion over blocks
+    # (generation.py, "block-stepping primitives"). Each slot is in its
+    # own phase: denoising pass k of its block, or the commit. A pass is
+    # the same program at the same cursor; only after the pass that ran
+    # a block's final tokens does the cursor move on.
+
+    def _block_feed(self):
+        """``[slots, block_len]`` tokens of the next pass: each slot's
+        block as it stands, MASK where a position is still masked (a
+        boolean per position, never a comparison with the MASK id)."""
+        return np.where(self._blk_masked, self._model.mask_token_id,
+                        self._blk_tok).astype(np.int32)
+
+    def _fresh_block(self, slot, handle):
+        """Start the block at the slot's cursor: positions the sequence
+        already holds (the prompt's last ``len % block_len`` tokens, or
+        tokens delivered before a preemption) are given, the others
+        masked; positions past the end of the request stay masked for
+        good and are never unmasked."""
+        seq = handle.prompt + handle._tokens
+        start = int(self._idx[slot])
+        given = len(seq) - start
+        self._blk_given[slot] = given
+        self._blk_live[slot] = min(
+            self._block_len,
+            len(handle.prompt) + handle.max_new_tokens - start)
+        self._blk_tok[slot] = 0
+        self._blk_tok[slot, :given] = seq[start:]
+        self._blk_masked[slot] = np.arange(self._block_len) >= given
+        self._blk_when[slot] = -1
+        self._blk_pass[slot] = 0
+
+    def _block_advance(self, active, answers):
+        """What the pass just run (``answers``: the jitted block step's
+        one array) means for each active slot. Where
+        masks were left, unmask by the rule (generation.unmask) and
+        stay; where none was, that pass was the COMMIT: its K/V is
+        final, so move the cursor on, deliver the block in one
+        delivery and start the next. Returns the tokens delivered.
+        The denoising rows are advanced all at once (array operations
+        over ``[active, block_len]``): this runs between every two
+        passes with the device waiting, and only a commit needs a
+        row's turn of its own."""
+        model = self._model
+        best, conf, expert_ids = self._generation.unpack_block_step(
+            answers, self.slots, self._block_len, model.experts_per_tok)
+        quota = max(1, self._block_len // model.denoise_steps)
+        act = np.asarray(active)
+        self.counters.inc("row_passes", len(act))
+        # positions inside their request; those past its end stay
+        # masked for good and are never unmasked
+        inside = np.arange(self._block_len) < self._blk_live[act, None]
+        of_requests = np.zeros(self._blk_masked.shape, bool)
+        of_requests[act] = inside
+        self._count_expert_rows(expert_ids, of_requests.reshape(-1))
+        masked = self._blk_masked[act] & inside
+        denoising = masked.any(axis=1)
+        # a row in its commit has no mask left: nothing is chosen there
+        chosen = self._generation.unmask(
+            conf[act], masked, quota, model.confidence_threshold)
+        self._blk_tok[act] = np.where(chosen, best[act], self._blk_tok[act])
+        self._blk_when[act] = np.where(chosen, self._blk_pass[act, None],
+                                       self._blk_when[act])
+        self._blk_masked[act] &= ~chosen
+        self._blk_pass[act] += denoising
+        self.counters.inc("tokens_unmasked", int(chosen.sum()))
+        delivered = 0
+        commits = act[~denoising].tolist()
+        for s in commits:
+            with self.timers.timed("block_commit"):
+                handle = self._slot_req[s]
+                given, live = int(self._blk_given[s]), int(self._blk_live[s])
+                tokens = self._blk_tok[s, given:live].tolist()
+                self._idx[s] += self._block_len
+                self._deliver(
+                    s, tokens[-1],
+                    block=(tokens, self._blk_when[s, given:live].tolist()))
+                if self._slot_req[s] is handle:
+                    self._fresh_block(s, handle)
+                delivered += len(tokens)
+        self.counters.inc("commit_row_passes", len(commits))
+        self._tokens_round_ewma = self._ewma(
+            self._tokens_round_ewma, delivered / len(active))
+        return delivered
+
+    def _count_expert_rows(self, expert_ids, live):
+        """The experts' load in one call, from the program's own output
+        ``expert_ids [layers, rows, top_k]`` (the experts each row was
+        routed to) over the rows that belong to a request, ``live
+        [rows]``: an idle slot's rows, a block's positions past the end
+        of its request and a prefill's padding are routed and
+        multiplied like any other and are counted nowhere. Per layer
+        the fullest expert's rows and the mean, summed
+        (``expert_rows_max`` over ``expert_rows_mean`` is the peak
+        load), and what the grouped product had to touch."""
+        ids = expert_ids[:, live]
+        layers, experts = ids.shape[0], self._model.num_experts
+        rows = np.bincount(
+            (ids + experts * np.arange(layers)[:, None, None]).ravel(),
+            minlength=layers * experts).reshape(layers, experts)
+        self.counters.inc("expert_calls", layers)
+        self.counters.inc("expert_rows_max", int(rows.max(axis=1).sum()))
+        self.counters.inc("expert_rows_mean",
+                          float(rows.mean(axis=1).sum()))
+        self.counters.inc("expert_rows", int(rows.sum()))
+        self.counters.inc("experts_touched", int((rows > 0).sum()))
+
     # -- paged-KV block management (PR 8; scheduler thread only) ---------
 
     def _publish_kv_gauges(self):
@@ -2343,6 +2574,10 @@ class DecodeEngine(object):
     def _kv_call(self, job, timeout):
         """Enqueue a KV job for the scheduler thread and wait for its
         verdict (safe from any thread)."""
+        if self._block_len:
+            raise ValueError(
+                "an engine that steps by blocks neither ships nor adopts "
+                "K/V blocks (no block of it is registered for sharing)")
         job["done"] = threading.Event()
         job["error"] = None
         job["result"] = None
@@ -2663,7 +2898,10 @@ class DecodeEngine(object):
         row[:] = 0
         row[:len(ids)] = ids
         self._slot_seq[slot] = next(self._admit_seq)
-        tail = full[start:]
+        # a block-stepping model prefills the sequence's WHOLE blocks
+        # and samples nothing; what is left over starts its first block
+        fill = n - n % self._block_len if self._block_len else n
+        tail = full[start:fill]
         try:
             bucket = self._generation.bucket_for(len(tail), self.buckets)
         except ValueError:
@@ -2699,11 +2937,18 @@ class DecodeEngine(object):
             handle._attr_spans.append(
                 ("preempted", handle._preempt_at, t0))
         with self.timers.timed("prefill"):
-            self._cache, first = self._prefill_fn(
-                self.params, self._cache, jnp.asarray(row),
-                jnp.asarray(toks), jnp.int32(len(tail)),
-                jnp.int32(start), self._next_key())
-            first = int(first)
+            if not self._block_len:
+                self._cache, first = self._prefill_fn(
+                    self.params, self._cache, jnp.asarray(row),
+                    jnp.asarray(toks), jnp.int32(len(tail)),
+                    jnp.int32(start), self._next_key())
+                first = int(first)
+            elif tail:
+                self._cache, routed = self._prefill_fn(
+                    self.params, self._cache, jnp.asarray(row),
+                    jnp.asarray(toks), jnp.int32(start))
+                self._count_expert_rows(np.asarray(routed),
+                                        np.arange(bucket) < len(tail))
         t1 = time.monotonic()
         self._prefill_ewma = self._ewma(self._prefill_ewma, t1 - t0)
         self.flight.span("prefill", t0, t1, trace=handle.trace,
@@ -2744,7 +2989,10 @@ class DecodeEngine(object):
         else:
             self._slot_registered[slot] = 0
         self._publish_kv_gauges()
-        self._idx[slot] = n
+        self._idx[slot] = fill
+        if self._block_len:
+            self._fresh_block(slot, handle)
+            return
         self._last[slot] = first
         self._deliver(slot, first)
         self.counters.inc("tokens")
@@ -2792,14 +3040,20 @@ class DecodeEngine(object):
         self._deliver(slot, first)
         self.counters.inc("tokens")
 
-    def _deliver(self, slot, token):
+    def _deliver(self, slot, token, block=None):
         """Append one emitted token to the slot's request; complete and
         free the slot on EOS or length. Cursor discipline: ``_idx[slot]``
         always holds the position where ``_last[slot]`` will be written
         by the NEXT decode step (the caller advances it for tokens that
-        are already in the cache)."""
+        are already in the cache). ``block`` = ``(tokens, passes)`` hands
+        a committed block over as ONE delivery (``token`` is its
+        last)."""
         handle = self._slot_req[slot]
-        handle._emit(token)
+        if block is None:
+            handle._emit(token)
+        else:
+            handle._emit_block(*block)
+        emitted = 1 if block is None else len(block[0])
         now = time.monotonic()
         if handle._last_emit_at is None:
             self._hist_ttft.observe(now - handle.submitted,
@@ -2814,10 +3068,10 @@ class DecodeEngine(object):
         # so a dedup-replayed retry (which delivers nothing new) can
         # never double-charge. _qos_tokens rides load_stats() to the
         # fleet, hence mutates under _cv; QuotaTable has its own lock.
-        self._quota.charge(handle.tenant, 1)
+        self._quota.charge(handle.tenant, emitted)
         with self._cv:
             self._qos_tokens[handle.tenant] = \
-                self._qos_tokens.get(handle.tenant, 0) + 1
+                self._qos_tokens.get(handle.tenant, 0) + emitted
         done = (self.eos_token is not None and token == self.eos_token) \
             or len(handle._tokens) >= handle.max_new_tokens
         if done:

@@ -375,3 +375,68 @@ def test_flash_kernels_carry_their_names_for_v5e(one_chip):
     for name in ("flash_attention_fwd", "flash_attention_dq",
                  "flash_attention_dkv"):
         assert 'kernel_name = "{}"'.format(name) in text
+
+
+# -- SDAR-30B-A3B's kernels at its published widths ----------------------
+
+#: hidden 2048, 32 query / 4 K/V heads of 128, 128 experts of 768 with 8
+#: a position; the engine's step is 32 slots x 4 positions over a
+#: 1280-token context in 16-token blocks (benchmarks/configs/sdar-30b-a3b)
+SDAR = dict(hidden=2048, heads=32, kv_heads=4, head_dim=128, experts=128,
+            moe_hidden=768, top_k=8, slots=32, block_len=4, total=1280)
+
+EXPERT_GMM_SHAPES = {
+    # (positions, K, N): the step's 128 positions into and out of the
+    # experts, and a 1024-token prefill bucket
+    "step_up": (SDAR["slots"] * SDAR["block_len"], SDAR["hidden"],
+                SDAR["moe_hidden"]),
+    "step_down": (SDAR["slots"] * SDAR["block_len"], SDAR["moe_hidden"],
+                  SDAR["hidden"]),
+    "prefill_1024_up": (1024, SDAR["hidden"], SDAR["moe_hidden"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_GMM_SHAPES))
+def test_expert_gmm_compiles_for_v5e(one_chip, name):
+    """The grouped product of the expert layer, bfloat16, all 128
+    experts held: one weight matrix (3 MB) double-buffered in VMEM."""
+    from tensorflowonspark_tpu.ops import expert_gmm
+
+    positions, kdim, ndim = EXPERT_GMM_SHAPES[name]
+    held, m = SDAR["experts"], positions * SDAR["top_k"]
+    tm = expert_gmm.row_tile(m, held)
+    rows = -(-(m + held * (tm - 1)) // tm) * tm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def gmm(lhs, rhs, tile_expert, live):
+        return expert_gmm.expert_gmm(
+            lhs, rhs, {"tile_expert": tile_expert, "live": live}, tm,
+            impl="pallas", interpret=False)
+
+    compiled = _compile(gmm, sds((rows, kdim), jnp.bfloat16),
+                        sds((held, kdim, ndim), jnp.bfloat16),
+                        sds((rows // tm,), jnp.int32), sds((), jnp.int32))
+    assert _has_kernel(compiled)
+    assert "expert_gmm" in compiled.as_text()
+
+
+@pytest.mark.parametrize("s_q", [SDAR["block_len"], 1024])
+def test_paged_attention_with_grouped_heads_compiles_for_v5e(one_chip, s_q):
+    """Group 8 at head size 128 over a bfloat16 pool ``[P, 16, 512]``:
+    the step's 4 positions a slot, and a 1024-token prefill."""
+    rows = SDAR["slots"] if s_q == SDAR["block_len"] else 1
+    mb = SDAR["total"] // KV_BLOCK
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((4097, KV_BLOCK, SDAR["kv_heads"] * SDAR["head_dim"]),
+               jnp.bfloat16)
+    compiled = _compile(
+        lambda *a: pa.paged_attention(*a, impl="pallas", interpret=False),
+        sds((rows, s_q, SDAR["heads"], SDAR["head_dim"]), jnp.bfloat16),
+        pool, pool, sds((rows, mb), jnp.int32), sds((rows, s_q), jnp.int32))
+    assert _has_kernel(compiled)
+    assert "paged_attention" in compiled.as_text()

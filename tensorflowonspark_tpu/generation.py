@@ -318,6 +318,26 @@ def decode_step(model, params, cache, tokens, idx, temperature=0.0,
     return upd["cache"], picked
 
 
+def _fed_tokens(picked, feed):
+    """Each row's input token of a token step: the host's where it gave
+    one (``feed[:, 0] >= 0``), else the device's own pick of the step
+    before. Inside the step program: no dispatch of its own."""
+    return jnp.where(feed[:, 0] >= 0, feed[:, 0], picked)
+
+
+def pack_step_feed(given, idx, tables=None):
+    """The host's part of a token step's input, ONE int32 array handed
+    to the call as numpy (the call transfers it itself): column 0 the
+    token ``given [S]`` a row starts from, -1 where the row feeds back
+    the device's own pick; column 1 the cursors ``idx [S]``; after them
+    a paged engine's block ``tables [S, MB]``."""
+    import numpy as np
+
+    if tables is None:
+        tables = np.empty((len(idx), 0), np.int32)
+    return pack_block_feed(given[:, None], idx, tables)
+
+
 @functools.lru_cache(maxsize=32)
 def slot_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     """(jitted prefill_into_slot, jitted decode_step) for one model +
@@ -331,16 +351,22 @@ def slot_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     ONCE per (slots, total_len) cache shape; the prefill fn once per
     bucket length. ``fn._cache_size()`` exposes the live program count —
     serving.DecodeEngine surfaces both via ``compile_stats()``.
+
+    The decode fn is ``step(params, cache, picked [S], feed, key) ->
+    (cache', picked' [S])``: ``picked`` is the step before's own answer,
+    still on the device, and ``feed`` the host's part
+    (:func:`pack_step_feed`), so a step can be dispatched before the
+    one before it has been read.
     """
     def slot_prefill(params, cache, slot, tokens, true_len, key):
         return prefill_into_slot(
             model, params, cache, slot, tokens, true_len,
             temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
 
-    def slot_decode_step(params, cache, tokens, idx, key):
+    def slot_decode_step(params, cache, picked, feed, key):
         return decode_step(
-            model, params, cache, tokens, idx, temperature=temperature,
-            top_k=top_k, top_p=top_p, rng=key)
+            model, params, cache, _fed_tokens(picked, feed), feed[:, 1],
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
 
     return (jax.jit(slot_prefill, donate_argnums=(1,)),
             jax.jit(slot_decode_step, donate_argnums=(1,)))
@@ -468,17 +494,20 @@ def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     same compile-count contract: ONE decode program per engine config,
     one prefill program per TAIL bucket (``start``/``tail_len`` are
     traced scalars, so a warm prefix and a cold prompt of equal tail
-    bucket share a program)."""
+    bucket share a program). The decode fn takes the step before's
+    ``picked`` and the host's ``feed`` as :func:`slot_step_fns`'s does,
+    the block tables after the cursors in the one array."""
     def paged_prefill(params, cache, table_row, tokens, tail_len, start,
                       key):
         return paged_prefill_into_slot(
             model, params, cache, table_row, tokens, tail_len, start,
             temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
 
-    def paged_decode_step(params, cache, tokens, idx, tables, key):
+    def paged_decode_step(params, cache, picked, feed, key):
         return _paged_decode_step(
-            model, params, cache, tokens, idx, tables,
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+            model, params, cache, _fed_tokens(picked, feed), feed[:, 1],
+            feed[:, 2:], temperature=temperature, top_k=top_k, top_p=top_p,
+            rng=key)
 
     return (jax.jit(paged_prefill, donate_argnums=(1,)),
             jax.jit(paged_decode_step, donate_argnums=(1,)))

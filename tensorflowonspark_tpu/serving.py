@@ -533,7 +533,12 @@ class DecodeEngine(object):
     instead of one whole-generation program per (batch, prompt_len,
     max_new) request signature. At ``temperature=0`` each request's
     output is bitwise-identical to a solo ``generation.generate`` call
-    (pinned in tests/test_decode_engine.py).
+    (pinned in tests/test_decode_engine.py). A token step's next input
+    is the device's own output, so the loop keeps ONE step in flight:
+    it dispatches step n+1 before it reads step n, and reads, delivers
+    and schedules while the device computes (docs/serving.md, "One step
+    in flight"); an engine whose next input is the host's decision (a
+    block's unmasking, a speculative round's acceptance) reads first.
 
     Args:
       model: decode-mode DecoderLM-family flax module (``decode=True``).
@@ -655,6 +660,7 @@ class DecodeEngine(object):
                  draft_layers=None, kv_dtype=None, tier=None,
                  qos_policy=None):
         import jax
+        import jax.numpy as jnp
 
         from tensorflowonspark_tpu import generation
 
@@ -1035,6 +1041,18 @@ class DecodeEngine(object):
         self._slot_req = [None] * self.slots
         self._idx = np.zeros(self.slots, np.int32)
         self._last = np.zeros(self.slots, np.int32)
+        # the token step in flight (dispatched, its tokens not read
+        # yet): the device's answer ``picked [S]``, which is also the
+        # next step's input, per slot the request it was dispatched for
+        # (None: the row was idle) and when. A token engine keeps at
+        # most one; block-stepping and speculative engines none, ever.
+        self._picked = jnp.zeros((self.slots,), jnp.int32)
+        self._flight = None   # or (picked, [request or None] * S, t0)
+        self._read_at = 0.0   # when a step's tokens were last read
+        # how often it engages and what it cost, exported from the
+        # start: an engine that never runs ahead reads 0, not absent
+        self.counters.inc("steps_dispatched_ahead", 0)
+        self.counters.inc("tokens_dropped_in_flight", 0)
         if self._paged:
             # host-authoritative block tables: row s mirrors
             # _slot_blocks[s] padded with scratch (0). A freed slot's
@@ -1813,19 +1831,51 @@ class DecodeEngine(object):
                     or self._active_slots())
 
     def _step_feed(self, jnp):
-        """A step program's per-step inputs put on the device — each
-        slot's last token and cursor, the block tables of a paged
-        engine, the key: what ``step_upload`` times."""
+        """The per-step inputs of a block step or a speculative round:
+        what ``step_upload`` times (a token step: :meth:`_token_feed`)."""
         if self._block_len:
             # one array, handed over as numpy: the call transfers
             # it itself, with no ``jnp.asarray`` round trip before it
             return [self._generation.pack_block_feed(
                 self._block_feed(), self._idx, self._tables)]
-        feed = [jnp.asarray(self._last), jnp.asarray(self._idx)]
-        if self._paged:
-            feed.append(jnp.asarray(self._tables))
-        feed.append(self._next_key())
-        return feed
+        return [jnp.asarray(self._last), jnp.asarray(self._idx),
+                jnp.asarray(self._tables), self._next_key()]
+
+    def _in_flight(self, slot):
+        """Whether the step in flight computes a token of the request
+        now in ``slot`` (a slot freed and admitted again since the
+        dispatch holds another request: not its token)."""
+        handle = self._slot_req[slot]
+        return handle is not None and self._flight is not None \
+            and self._flight[1][slot] is handle
+
+    def _owes_step(self, slot):
+        """Whether the request in ``slot`` needs a row in the next
+        step: not when the tokens it holds and the one in flight make
+        up its ``max_new_tokens`` (finish by length is known before
+        the read)."""
+        handle = self._slot_req[slot]
+        return len(handle._tokens) + self._in_flight(slot) \
+            < handle.max_new_tokens
+
+    def _token_feed(self, rows):
+        """The host's part of a token step for the slots ``rows``
+        (generation.pack_step_feed): a row whose last token is still
+        on the device (the step in flight computes it) feeds that back,
+        a row admitted or resumed since is given the host's; every
+        other row runs idle at cursor 0 over the scratch table, an
+        active slot that owes no step among them (it keeps its blocks
+        until its last token is delivered)."""
+        take = np.zeros(self.slots, bool)
+        take[rows] = True
+        given = np.full(self.slots, -1, np.int32)
+        for s in rows:
+            if not self._in_flight(s):
+                given[s] = self._last[s]
+        return self._generation.pack_step_feed(
+            given, np.where(take, self._idx, 0),
+            np.where(take[:, None], self._tables, 0) if self._paged
+            else None)
 
     def _ewma(self, prev, sample):
         return sample if prev is None \
@@ -2047,6 +2097,10 @@ class DecodeEngine(object):
         steps = 0
         try:
             while True:
+                if not self._active_slots():
+                    # every request of the step in flight is gone
+                    # (cancelled, evicted): read it off before parking
+                    self._retire()
                 with self._cv:
                     if self._idle_locked():
                         # idle for want of work: its own stage, so
@@ -2055,6 +2109,8 @@ class DecodeEngine(object):
                             while self._idle_locked():
                                 self._cv.wait()
                     if self._stopping:
+                        # a last token in flight completes its request
+                        self._retire()
                         self._fail_outstanding(
                             RuntimeError("engine stopped"))
                         return
@@ -2084,6 +2140,8 @@ class DecodeEngine(object):
                     if self._slot_req[s] is not None:
                         with self.timers.timed("preempt"):
                             self._preempt(s)
+                if kv_jobs:
+                    self._retire()
                 for job in kv_jobs:
                     with self.timers.timed("kv_job"):
                         self._run_kv_job(job)
@@ -2113,13 +2171,23 @@ class DecodeEngine(object):
                 # scopes an only=<replica> injection to THIS engine of
                 # an in-process fleet)
                 chaos.on_decode_step(steps, self.replica_id)
+                if not (self._spec_k or self._block_len):
+                    # a token step's next input is the device's own
+                    # output, so one step stays in flight while the
+                    # host reads and schedules; where the next input
+                    # is a decision the host makes from the answers
+                    # (below) the turn reads before it schedules
+                    rows = [s for s in active if self._owes_step(s)]
+                    self._token_turn(rows, steps)
+                    steps += bool(rows)
+                    continue
                 t0 = time.monotonic()
                 if self._spec_k:
                     drafts, targets = self._spec_round(jnp)
                 else:
                     # upload, the jitted call until it returns (the
                     # host path of a dispatch: the device works on
-                    # after it), the blocking read of the tokens
+                    # after it), the blocking read of the answers
                     with self.timers.timed("decode_step"):
                         with self.timers.timed("step_upload"):
                             feed = self._step_feed(jnp)
@@ -2127,9 +2195,9 @@ class DecodeEngine(object):
                             self._cache, toks = self._decode_fn(
                                 self.params, self._cache, *feed)
                         with self.timers.timed("step_sync"):
-                            # the per-step host sync (a block step
-                            # answers tokens, confidences and the
-                            # routed experts in one array)
+                            # the per-step host sync (tokens,
+                            # confidences and the routed experts in
+                            # one array)
                             toks = np.asarray(toks)
                 t1 = time.monotonic()
                 self._step_ewma = self._ewma(self._step_ewma, t1 - t0)
@@ -2151,16 +2219,8 @@ class DecodeEngine(object):
                     if self._spec_k:
                         delivered = self._spec_deliver(active, drafts,
                                                        targets)
-                    elif self._block_len:
-                        delivered = self._block_advance(active, toks)
                     else:
-                        for s in active:
-                            # the step just WROTE the fed token at
-                            # _idx[s]: advance the cursor, then
-                            # deliver the emission
-                            self._idx[s] += 1
-                            self._deliver(s, int(toks[s]))
-                        delivered = len(active)
+                        delivered = self._block_advance(active, toks)
                     self.counters.inc("tokens", delivered)
                     # decode_tokens excludes prefill-emitted firsts, so
                     # rate("decode_tokens", "decode_steps") is true
@@ -2176,10 +2236,90 @@ class DecodeEngine(object):
                                         len(self._active_slots()))
         except BaseException as e:  # noqa: BLE001 - fail every client
             logger.exception("decode engine loop died")
+            self._flight = None  # a dead loop's answers are not read
             with self._cv:
                 self._broken = e
                 self._fail_outstanding(
                     EngineFailed("decode engine failed: {}".format(e)))
+
+    def _token_turn(self, rows, step):
+        """One turn of a token engine: upload and dispatch a step for
+        the slots ``rows`` (none: nothing owes one), THEN read the
+        step dispatched a turn ago and deliver its tokens, so that the
+        read, the deliveries and the next turn's scheduling run while
+        the device computes. ``step_sync`` is the wait for the OLDER
+        step; a cursor moves on at dispatch (the program writes the
+        fed token there), not at delivery."""
+        older, t0 = self._flight, time.monotonic()
+        with self.timers.timed("decode_step"):
+            if rows:
+                with self.timers.timed("step_upload"):
+                    feed, key = self._token_feed(rows), self._next_key()
+                with self.timers.timed("step_dispatch"):
+                    self._cache, self._picked = self._decode_fn(
+                        self.params, self._cache, self._picked, feed, key)
+                    self._picked.copy_to_host_async()
+                self._idx[rows] += 1
+            self._flight = None
+            if rows:
+                owners = [None] * self.slots
+                for s in rows:
+                    owners[s] = self._slot_req[s]
+                self._flight = (self._picked, owners, t0)
+            if older is not None:
+                with self.timers.timed("step_sync"):
+                    toks = np.asarray(older[0])
+        t1 = time.monotonic()
+        # engine-row span (tid 0): this turn's share of the loop
+        self.flight.span("decode_step", t0, t1, active=len(rows),
+                         step=step)
+        if rows:
+            self.counters.inc("decode_steps")
+            self.counters.inc("steps_dispatched_ahead",
+                              int(older is not None))
+            if self._paged:
+                # blocks held by in-flight sequences, summed over the
+                # steps: kv_block_steps / decode_steps is the mean
+                # occupancy of the pool the steps saw
+                self.counters.inc(
+                    "kv_block_steps",
+                    self._pool.num_blocks - self._pool.allocatable())
+        if older is None:
+            return
+        # the pace, read to read (dispatch to read after a pause):
+        # what a client waits per token, the device's step or the
+        # host's turn, whichever is longer
+        pace = t1 - max(self._read_at, older[2])
+        self._read_at = t1
+        self._step_ewma = self._ewma(self._step_ewma, pace)
+        self._hist_step.observe(pace)
+        with self.timers.timed("host_schedule"):
+            delivered = 0
+            for s, handle in enumerate(older[1]):
+                if handle is None:
+                    continue
+                if self._slot_req[s] is handle:
+                    self._deliver(s, int(toks[s]))
+                    delivered += 1
+                else:
+                    # finished on EOS, cancelled or evicted since the
+                    # dispatch: the row's token is nobody's
+                    self.counters.inc("tokens_dropped_in_flight")
+            self.counters.inc("tokens", delivered)
+            # decode_tokens excludes prefill-emitted firsts, so
+            # rate("decode_tokens", "decode_steps") is true decode
+            # occupancy (bounded by slots)
+            self.counters.inc("decode_tokens", delivered)
+            # occupancy AFTER deliveries: see _loop
+            self.counters.gauge("slot_occupancy",
+                                len(self._active_slots()))
+
+    def _retire(self):
+        """Read and deliver the step in flight, if there is one, with
+        nothing dispatched after it: for whatever needs a slot's tokens
+        whole (a preemption's re-prefill, a KV job, stop, parking)."""
+        if self._flight is not None:
+            self._token_turn((), None)
 
     def _fail_outstanding(self, err):
         """Fail every queued and in-flight handle (scheduler thread
@@ -2517,12 +2657,15 @@ class DecodeEngine(object):
         if not self.prefix_cache:
             return
         bs = self.kv_block_size
-        full = min(int(self._idx[slot]) // bs,
+        # full by the tokens the host HOLDS: every position before the
+        # last of them is written by a program dispatched already; the
+        # cursor may be a step further, over a token not read yet
+        n_prompt = len(handle.prompt)
+        full = min((n_prompt + len(handle._tokens) - 1) // bs,
                    len(self._slot_blocks[slot]))
         if full <= self._slot_registered[slot]:
             return
         chain = handle.prompt + handle._tokens
-        n_prompt = len(handle.prompt)
         for j in range(self._slot_registered[slot], full):
             end = (j + 1) * bs
             self._pool.register(
@@ -2768,7 +2911,12 @@ class DecodeEngine(object):
         already emitted — the client's stream continues seamlessly, and
         at temperature=0 bitwise-identically (pinned in
         tests/test_paged_kv.py)."""
+        # the re-prefill needs the tokens whole: land the step in
+        # flight first (which may finish the victim: nothing to do)
+        self._retire()
         handle = self._slot_req[slot]
+        if handle is None:
+            return
         self._slot_req[slot] = None
         self._release_slot(slot)
         now = time.monotonic()
@@ -2830,9 +2978,11 @@ class DecodeEngine(object):
             # admission could have had — by up to a block). Cheap: an
             # early return when nothing new completed.
             self._register_generated(s, handle)
+            if not self._owes_step(s):
+                continue  # its last token is in flight: no write left
             cover = min(look,
                         max(1, handle.max_new_tokens
-                            - len(handle._tokens)))
+                            - len(handle._tokens) - self._in_flight(s)))
             need = min((int(self._idx[s]) + cover - 1) // bs + 1,
                        self._blocks_per_slot)
             if len(self._slot_blocks[s]) >= need:
@@ -3043,9 +3193,11 @@ class DecodeEngine(object):
     def _deliver(self, slot, token, block=None):
         """Append one emitted token to the slot's request; complete and
         free the slot on EOS or length. Cursor discipline: ``_idx[slot]``
-        always holds the position where ``_last[slot]`` will be written
-        by the NEXT decode step (the caller advances it for tokens that
-        are already in the cache). ``block`` = ``(tokens, passes)`` hands
+        always holds the position the NEXT step dispatched writes: that
+        of ``_last[slot]``, or, with a token step in flight, of the
+        token that step computes (a token engine advances the cursor at
+        dispatch, the others for tokens already in the cache, before
+        they deliver them). ``block`` = ``(tokens, passes)`` hands
         a committed block over as ONE delivery (``token`` is its
         last)."""
         handle = self._slot_req[slot]

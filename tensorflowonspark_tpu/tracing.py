@@ -292,7 +292,9 @@ METRIC_FAMILIES = {
     "tfos_serving_token_latency_seconds":
         ("histogram", "", "gap between consecutive emitted tokens"),
     "tfos_serving_decode_step_seconds":
-        ("histogram", "", "one fixed-shape decode step, wall clock"),
+        ("histogram", "", "one fixed-shape decode step, wall clock (a "
+                          "token engine: the pace, from one step's "
+                          "read to the next)"),
     "tfos_serving_queue_wait_seconds":
         ("histogram", "", "submit -> prefill start (admission queue)"),
     "tfos_serving_request_seconds":
@@ -310,6 +312,16 @@ METRIC_FAMILIES = {
                         "at every decode step (over "
                         "tfos_serving_decode_steps: the mean pool "
                         "occupancy the steps saw; paged engines only)"),
+    "tfos_serving_steps_dispatched_ahead":
+        ("counter", "", "decode steps dispatched while the step before "
+                        "was still unread (over "
+                        "tfos_serving_decode_steps: how often a token "
+                        "engine keeps a step in flight; 0 where the "
+                        "host decides the next input)"),
+    "tfos_serving_tokens_dropped_in_flight":
+        ("counter", "", "tokens of a step in flight that nobody got: "
+                        "the request ended on EOS, was cancelled or "
+                        "evicted after the dispatch"),
     "tfos_serving_admit_scans_blocked_slots":
         ("counter", "", "admission scans that left a queued request "
                         "waiting because no slot was free"),

@@ -239,9 +239,11 @@ def _lower_engine_program(program, model, params, cache, sds, slots, total):
     prefill, decode = generation.paged_step_fns(model)
     key = sds((2,), jnp.uint32)
     if program == "paged_decode_step":
+        # the step before's picks, and the host's feed in one array: a
+        # given token or -1, the cursor, the table row
         return decode.lower(
-            params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-            sds((slots, total // KV_BLOCK), jnp.int32), key)
+            params, cache, sds((slots,), jnp.int32),
+            sds((slots, 2 + total // KV_BLOCK), jnp.int32), key)
     return prefill.lower(
         params, cache, sds((total // KV_BLOCK,), jnp.int32),
         sds((128,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32), key)
@@ -363,6 +365,35 @@ def test_paged_pool_keeps_one_layout_for_v5e(one_chip, kernel_on_cpu_backend,
     assert mem.temp_size_in_bytes < pool_bytes
     # every pool is updated in the buffer it came in
     assert mem.alias_size_in_bytes >= 2 * layers * pool_bytes
+
+
+def test_token_select_adds_no_operation_over_a_pool(one_chip,
+                                                    kernel_on_cpu_backend):
+    """The engine's step takes its input tokens from the device (the
+    step before's picks, a host token where one is given) and its
+    cursors and tables out of one array. Over the KV pools that is
+    nothing: the program holds the very pool-shaped values, opcode by
+    opcode in one layout, of the step that is handed tokens, cursors
+    and tables as three arrays, and no more temporaries than a table's
+    worth."""
+    from tensorflowonspark_tpu import generation
+
+    model, params, cache, sds = _paged_engine_shapes(
+        one_chip, GPT2_LARGE_2L, LARGE_SLOTS, LARGE_TOTAL, 512)
+    fed = _lower_engine_program("paged_decode_step", model, params, cache,
+                                sds, LARGE_SLOTS, LARGE_TOTAL).compile()
+    tables = (LARGE_SLOTS, LARGE_TOTAL // KV_BLOCK)
+    plain = jax.jit(
+        lambda *a: generation.paged_decode_step(model, *a),
+        donate_argnums=(1,)).lower(
+            params, cache, sds((LARGE_SLOTS,), jnp.int32),
+            sds((LARGE_SLOTS,), jnp.int32), sds(tables, jnp.int32)).compile()
+    pool = cache["block_0"]["attn"]["cached_key"]
+    assert sorted(_pool_values(fed, pool)) \
+        == sorted(_pool_values(plain, pool))
+    extra = fed.memory_analysis().temp_size_in_bytes \
+        - plain.memory_analysis().temp_size_in_bytes
+    assert extra <= 4 * 4 * math.prod(tables)
 
 
 def test_flash_kernels_carry_their_names_for_v5e(one_chip):

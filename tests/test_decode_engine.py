@@ -9,6 +9,7 @@ contract that motivates the design: compile count stays O(buckets),
 not O(request signatures).
 """
 
+import threading
 import time
 
 import jax
@@ -16,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tensorflowonspark_tpu import generation, serving
+from tensorflowonspark_tpu import chaos, generation, serving
 from tensorflowonspark_tpu.models.decoder import DecoderLM
 
 V, H, NH, L, MAXLEN = 17, 32, 4, 2, 48
@@ -254,3 +255,219 @@ def test_engine_failure_fails_clients_not_hangs(lm):
             eng.submit([1, 2, 3], 4)
     finally:
         eng.stop()
+
+
+# -- one step in flight ---------------------------------------------------
+#
+# A token engine dispatches step n+1 before it reads step n (the next
+# input is the device's own output), so what the host knows lags the
+# cursor by one step. Everything a client can see must be as if it did
+# not: paged and contiguous, the same cases.
+
+ENGINES = {"paged": dict(kv_block_size=8), "contiguous": dict(kv_block_size=0)}
+
+
+def _counts(eng):
+    return eng.counters.snapshot()["counts"]
+
+
+@pytest.fixture(autouse=True)
+def _disarm_chaos():
+    yield
+    chaos.disarm()
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_streams_of_unequal_lengths_are_solo_token_by_token(lm, kind):
+    """Concurrent requests of unequal lengths, each consumed from
+    ``stream()`` by a thread of its own while steps are in flight: every
+    stream is the solo rollout's tokens one by one, whole and in order,
+    requests that end while their neighbours go on among them."""
+    dec, params = lm
+    reqs = _mixed_requests(np.random.RandomState(11), 9, hi_p=14, lo_n=1,
+                           hi_n=30)
+    want = [_solo(dec, params, p, mn)[len(p):] for p, mn in reqs]
+    got = [[] for _ in reqs]
+
+    def consume(i, handle):
+        for tok in handle.stream(timeout=300):
+            got[i].append(tok)
+
+    with serving.DecodeEngine(dec, params, slots=3, **ENGINES[kind]) as eng:
+        threads = [threading.Thread(target=consume,
+                                    args=(i, eng.submit(p, mn)))
+                   for i, (p, mn) in enumerate(reqs)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+            assert not t.is_alive()
+        counts = _counts(eng)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g == w, (i, g, w)
+    # the steps did run ahead, and by length alone nothing is dropped
+    assert counts["steps_dispatched_ahead"] > 0.5 * counts["decode_steps"]
+    assert counts["tokens_dropped_in_flight"] == 0
+    assert counts["decode_tokens"] == sum(mn - 1 for _, mn in reqs)
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_eos_with_a_step_in_flight_ends_there_and_frees_the_slot(lm, kind):
+    """A request that ends on ``eos_token`` has a row in the step
+    dispatched before its EOS was read: it ends AT the EOS, the row is
+    dropped and counted, and the request admitted into the freed slot
+    on the next turn (one slot: the same one) gets its own tokens."""
+    dec, params = lm
+    rng = np.random.RandomState(2)
+    first = rng.randint(0, V, size=5).tolist()
+    base = _solo(dec, params, first, 12)
+    eos = base[len(first) + 2]  # the second token a decode step emits
+    reqs = [(first, 12)] + _mixed_requests(rng, 3, lo_n=4)
+    want = []
+    for p, mn in reqs:
+        gen = _solo(dec, params, p, mn, eos_token=eos)[len(p):]
+        if eos in gen:
+            gen = gen[:gen.index(eos) + 1]
+        want.append(p + gen)
+    with serving.DecodeEngine(dec, params, slots=1, eos_token=eos,
+                              **ENGINES[kind]) as eng:
+        handles = [eng.submit(p, mn) for p, mn in reqs]  # queued behind
+        got = [h.result(300) for h in handles]
+        counts = _counts(eng)
+    assert got == want
+    assert got[0][-1] == eos and len(got[0]) < len(first) + 12
+    # a request that ended early on a STEP's token had one row in
+    # flight behind it (an EOS from the prefill itself has none)
+    early = sum(len(p) + 1 < len(g) < len(p) + mn
+                for g, (p, mn) in zip(got, reqs))
+    assert early >= 1
+    assert counts["tokens_dropped_in_flight"] == early
+    assert counts["requests_completed"] == len(reqs)
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_eviction_with_a_step_in_flight_drops_its_row(lm, kind, how):
+    """A request cancelled, or past its deadline, while a step holds a
+    row of it: the row's token is nobody's (counted), the victim gets
+    its error, and the neighbour's stream is its solo rollout."""
+    dec, params = lm
+    probe_prompt, probe_new = [3, 1, 4, 1], 14
+    want = _solo(dec, params, probe_prompt, probe_new)
+    with serving.DecodeEngine(dec, params, slots=2, **ENGINES[kind]) as eng:
+        # programs compiled before any clock matters, and no shedding
+        # on the evidence of a compile
+        eng.submit([1, 2], 3).result(300)
+        eng._step_ewma = eng._prefill_ewma = None
+        # the pair's first step boundary is held open: the step
+        # dispatched when it ends carries the victim's row, and the
+        # eviction (it comes before the dispatch in a turn) meets it a
+        # turn later; a deadline runs out half way through the stall
+        stall, deadline = (2.0, 1.0) if how == "deadline" else (1.0, None)
+        chaos.arm("stall_decode_for={}".format(stall))
+        with eng._cv:  # both queued before the scheduler plans a turn
+            victim = eng.submit([2, 7, 1], 40, deadline_s=deadline)
+            probe = eng.submit(probe_prompt, probe_new)
+        assert chaos.poll_until(
+            lambda: _counts(eng).get("prefills", 0) >= 3, timeout=60)
+        if how == "cancel":
+            victim.cancel()
+        assert probe.result(120) == want
+        with pytest.raises(serving.DeadlineExceeded if how == "deadline"
+                           else serving.Cancelled):
+            victim.result(10)
+        counts = _counts(eng)
+    assert counts["deadline_exceeded" if how == "deadline"
+                  else "cancelled"] == 1
+    assert counts["tokens_dropped_in_flight"] == 1
+    assert len(victim.generated) == 1  # the prefill's; the step's dropped
+
+
+@pytest.mark.parametrize("how", ["drain", "stop"])
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_drain_and_stop_with_a_step_in_flight_strand_no_client(lm, kind, how):
+    """``drain()`` with steps in flight finishes every admitted request
+    whole. ``stop()`` first lands the step in flight (its tokens are
+    delivered, a last token would complete its request) and then fails
+    what is outstanding at once: no client waits out a timeout."""
+    dec, params = lm
+    reqs = _mixed_requests(np.random.RandomState(13), 5, lo_n=8, hi_n=30)
+    want = [_solo(dec, params, p, mn) for p, mn in reqs]
+    eng = serving.DecodeEngine(dec, params, slots=2, **ENGINES[kind])
+    try:
+        if how == "stop":
+            # the first step boundary held open: stop() arrives before
+            # the first step, which is then dispatched and found in
+            # flight by the loop's stopping check
+            chaos.arm("stall_decode_for=1.0")
+        with eng._cv:  # all queued before the scheduler plans a turn
+            handles = [eng.submit(p, mn) for p, mn in reqs]
+        if how == "drain":
+            assert eng.drain(timeout=300) is True
+            assert [h.result(1) for h in handles] == want
+            return
+        assert chaos.poll_until(
+            lambda: _counts(eng).get("prefills", 0) >= 2, timeout=60)
+        eng.stop()
+        t0 = time.monotonic()
+        for h in handles:
+            with pytest.raises(RuntimeError, match="stopped"):
+                h.result(5)
+        assert time.monotonic() - t0 < 5
+        # the two in their slots hold the prefill's token and the token
+        # of the step that was in flight; the queued ones nothing
+        assert [len(h.generated) for h in handles] == [2, 2, 0, 0, 0]
+        for h, w in zip(handles[:2], want):
+            assert h.prompt + h.generated == w[:len(h.prompt) + 2]
+        assert _counts(eng)["decode_steps"] == 1
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_sampled_request_alone_draws_as_a_serial_loop_does(lm, kind):
+    """temperature > 0, one request alone: the engine splits its key
+    once per prefill and once per dispatched step, in that order, and
+    feeds the device's own draw back without reading it first; the
+    tokens are those of a loop that reads every draw and hands it back
+    from the host, on the same keys."""
+    dec, params = lm
+    prompt, max_new, slots = [5, 3, 9, 1, 2], 12, 2
+    kw = dict(temperature=0.9, top_k=8)
+    with serving.DecodeEngine(dec, params, slots=slots,
+                              rng=jax.random.PRNGKey(5), **kw,
+                              **ENGINES[kind]) as eng:
+        got = eng.submit(prompt, max_new).result(300)
+        model, paged = eng._model, eng._paged
+        bps = eng._blocks_per_slot if paged else 0
+        assert _counts(eng)["steps_dispatched_ahead"] == max_new - 2
+
+    fns = generation.paged_step_fns if paged else generation.slot_step_fns
+    prefill, step = fns(model, 0.9, 8, None)
+    cache = generation.init_cache(model, slots, MAXLEN)
+    key = jax.random.PRNGKey(5)
+    toks = np.zeros(8, np.int32)
+    toks[:len(prompt)] = prompt
+    key, sub = jax.random.split(key)
+    tables = np.zeros((slots, bps), np.int32)
+    if paged:
+        # the blocks a fresh pool hands out first: 1, 2, ... in order
+        tables[0] = np.arange(1, bps + 1)
+        cache, first = prefill(params, cache, jnp.asarray(tables[0]),
+                               jnp.asarray(toks), jnp.int32(len(prompt)),
+                               jnp.int32(0), sub)
+    else:
+        cache, first = prefill(params, cache, jnp.int32(0),
+                               jnp.asarray(toks), jnp.int32(len(prompt)), sub)
+    want, idx = [int(first)], np.zeros(slots, np.int32)
+    idx[0] = len(prompt)
+    for _ in range(max_new - 1):
+        key, sub = jax.random.split(key)
+        given = np.array([want[-1]] + [-1] * (slots - 1), np.int32)
+        cache, picked = step(
+            params, cache, jnp.zeros(slots, jnp.int32),
+            generation.pack_step_feed(given, idx, tables if paged else None),
+            sub)
+        want.append(int(np.asarray(picked)[0]))
+        idx[0] += 1
+    assert got == prompt + want

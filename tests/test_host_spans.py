@@ -217,11 +217,18 @@ def test_engine_loop_is_split_into_named_stages(lm, paged):
     assert counts.get("preemptions", 0) == 0
     # the three parts of a step: one each per step, inside decode_step
     steps = counts["decode_steps"]
-    assert n["decode_step"] == n["step_upload"] == n["step_dispatch"] \
-        == n["step_sync"] == steps
+    assert n["step_upload"] == n["step_dispatch"] == n["step_sync"] == steps
+    # one step stays in flight, so a turn dispatches a step and reads
+    # the one before it; a first step finds none to read, and the turn
+    # that reads a last step dispatches none: a decode_step each
+    ahead = counts["steps_dispatched_ahead"]
+    assert 0 < ahead < steps
+    assert n["decode_step"] == 2 * steps - ahead
+    assert counts["tokens_dropped_in_flight"] == 0
     parts = sec["step_upload"] + sec["step_dispatch"] + sec["step_sync"]
     assert 0.0 < parts <= sec["decode_step"]
-    # what the parts leave out is three span exits: microseconds a step
+    # what the parts leave out is three span exits and the turn's own
+    # bookkeeping between them: microseconds a step
     assert sec["decode_step"] - parts < 0.001 * steps
     # admit holds its prefill
     assert sec["prefill"] <= sec["admit"]
@@ -240,15 +247,29 @@ def test_engine_stages_are_spans_on_the_scheduler_thread(lm, tmp_path):
         with tracing.trace(str(tmp_path)):
             _serve(eng, n=2)
     events = _host_events(str(tmp_path))
-    steps = events["engine:decode_step"]
+    steps = sorted(events["engine:decode_step"], key=lambda e: e[1])
     threads = {e[0] for e in steps}
     assert len(threads) == 1  # the scheduler's
+    parts = {}
     for part in ("step_upload", "step_dispatch", "step_sync"):
         inside = events["engine:" + part]
-        assert len(inside) == len(steps) and {e[0] for e in inside} == threads
-        for (_, a, b), (_, s0, s1) in zip(sorted(inside, key=lambda e: e[1]),
-                                          sorted(steps, key=lambda e: e[1])):
-            assert s0 <= a and b <= s1
+        assert {e[0] for e in inside} == threads
+        # each lies in a decode_step, and no decode_step holds two
+        holders = [[i for i, (_, s0, s1) in enumerate(steps)
+                    if s0 <= a and b <= s1] for _, a, b in inside]
+        assert all(len(h) == 1 for h in holders)
+        parts[part] = [h[0] for h in holders]
+        assert len(set(parts[part])) == len(inside)
+    # a turn uploads and dispatches together, and every step is read
+    # once: in a later turn, or alone when nothing is left to dispatch
+    assert parts["step_upload"] == parts["step_dispatch"]
+    assert len(parts["step_sync"]) == len(parts["step_dispatch"])
+    assert set(parts["step_sync"]) | set(parts["step_dispatch"]) \
+        == set(range(len(steps)))
+    for (_, d0, d1), (_, r0, r1) in zip(
+            sorted(events["engine:step_dispatch"], key=lambda e: e[1]),
+            sorted(events["engine:step_sync"], key=lambda e: e[1])):
+        assert d1 <= r0  # a step is read after it was dispatched
     assert len(events["engine:admit"]) == 2
     # a cross-thread interval is a sample, never a span
     assert "engine:queue_wait" not in events
@@ -268,6 +289,46 @@ def test_speculative_round_holds_the_three_step_parts(lm):
         == n["step_sync"] >= 1
     assert sec["step_upload"] + sec["step_dispatch"] + sec["step_sync"] \
         <= sec["spec_round"]
+
+
+@pytest.mark.parametrize("kind", ["paged", "contiguous", "speculative",
+                                  "blocks"])
+def test_a_step_is_dispatched_ahead_where_the_device_feeds_itself(lm, kind):
+    """``steps_dispatched_ahead`` over ``decode_steps``: above 0.8 on a
+    busy token engine, whose next input is the device's own output; 0
+    where the host decides the next input from the answers (a
+    speculative round's acceptance, a block's unmasking)."""
+    dec, params = lm
+    if kind == "blocks":
+        from benchmarks.reference import sdar_moe as ref
+        from tensorflowonspark_tpu.models import sdar_moe
+
+        tiny = dict(vocab=97, hidden=64, num_heads=4, num_kv_heads=2,
+                    head_dim=16, num_layers=2, num_experts=8,
+                    experts_per_tok=2, moe_hidden=32, rope_theta=1e6,
+                    rms_eps=1e-6, max_len=64, block_len=4, denoise_steps=4,
+                    confidence_threshold=0.9, mask_token_id=96)
+        dec = sdar_moe.SdarMoeLM(**tiny, dtype=jnp.float32, decode=True)
+        params = ref.init_params(jax.random.PRNGKey(1), tiny)
+    kw = {"paged": dict(kv_block_size=8, kv_blocks=24),
+          "contiguous": dict(kv_block_size=0),
+          "speculative": dict(kv_block_size=8, kv_blocks=24, speculate_k=3),
+          "blocks": dict(kv_block_size=8, kv_blocks=24)}[kind]
+    with serving.DecodeEngine(dec, params, slots=3, **kw) as eng:
+        _serve(eng, n=6, max_new=24)
+        counts = eng.counters.snapshot()["counts"]
+        n = eng.timers.counts()
+    steps = counts["decode_steps"] if kind != "speculative" \
+        else n["spec_round"]
+    assert steps >= 20
+    ahead = counts["steps_dispatched_ahead"]
+    if kind in ("paged", "contiguous"):
+        assert ahead / steps > 0.8
+        assert n["decode_step"] == 2 * steps - ahead
+    else:
+        assert ahead == 0 and counts["tokens_dropped_in_flight"] == 0
+        # read, then schedule: every turn holds its own step's read
+        assert n["step_sync"] == n["step_dispatch"] == steps
 
 
 @pytest.mark.parametrize("short_of", ["slots", "blocks"])

@@ -249,6 +249,27 @@ def test_metrics_exposition_grammar_and_catalog(live_server):
         assert inf and inf[0] == total
 
 
+def test_steps_dispatched_ahead_is_scraped_beside_decode_steps(live_server):
+    """How often the token loop keeps a step in flight is on
+    ``/metrics``: ``steps_dispatched_ahead`` beside ``decode_steps``,
+    of which it is a part; every step but the first of a request served
+    alone was dispatched with its predecessor unread."""
+    url, eng = live_server
+    before = {f: v for f, _, v in _parse_openmetrics(_scrape(url))[1]}
+    _generate(url, [[1, 2, 3, 4]], max_new=12)
+    types, samples = _parse_openmetrics(_scrape(url))
+    after = {f: v for f, _, v in samples}
+    for family in ("tfos_serving_steps_dispatched_ahead",
+                   "tfos_serving_decode_steps"):
+        assert types[family] == "counter"
+    steps = after["tfos_serving_decode_steps"] \
+        - before.get("tfos_serving_decode_steps", 0)
+    ahead = after["tfos_serving_steps_dispatched_ahead"] \
+        - before.get("tfos_serving_steps_dispatched_ahead", 0)
+    assert (steps, ahead) == (11, 10)
+    assert after["tfos_serving_tokens_dropped_in_flight"] == 0
+
+
 def test_histogram_exemplars_in_live_scrape(live_server):
     """The exemplar grammar pin (PR 20): traced observations render an
     OpenMetrics exemplar on their bucket line, the trace id is a real

@@ -516,6 +516,61 @@ def test_generated_prefix_multi_turn_bitwise_and_counters(lm):
         assert stats["cached"] == 0 and stats["free"] == stats["total"]
 
 
+def test_generated_prefix_registered_with_a_step_in_flight(lm):
+    """A block that decode fills is registered while the request goes
+    on, with a step in flight: by the tokens the host holds, one short
+    of the cursor. A follow-up whose prompt covers that block, sent
+    while the first turn still decodes, admits against it and reads
+    the right K/V: its tokens are the solo rollout's, and so are the
+    first turn's."""
+    dec, params = lm
+    rng = np.random.RandomState(29)
+    p1 = rng.randint(0, V, size=11).tolist()
+    t1 = _solo(dec, params, p1, 40)
+    p2 = t1[:26] + [3]  # blocks [0,8) [8,16) [16,24): two hold generated
+    want = _solo(dec, params, p2, 5)
+    with serving.DecodeEngine(dec, params, slots=2,
+                              kv_block_size=8) as eng:
+        # every step boundary of the first turn is a few milliseconds
+        # here; hold one open so the follow-up provably arrives mid-turn
+        h1 = eng.submit(p1, 40)
+        assert chaos.poll_until(lambda: len(h1.generated) >= 16,
+                                timeout=60, interval=0.001)
+        chaos.arm("stall_decode_for=0.3")
+        h2 = eng.submit(p2, 5)
+        assert h2.result(300) == want
+        assert h1.result(300) == t1
+        counts = _counts(eng)
+        # h1's two generated blocks were registered mid-flight (it had
+        # a row in the step in flight each time) and h2 hit them
+        assert counts["generated_prefix_hit_blocks"] == 2
+        assert counts["prefix_hit_blocks"] == 3
+        assert counts["steps_dispatched_ahead"] > 30
+        assert counts["tokens_dropped_in_flight"] == 0
+        assert eng._pool.live_refs() == {}
+
+
+def test_finish_by_length_with_its_last_token_in_flight_grows_no_block(lm):
+    """A request whose last token is in flight owes no step: it gets no
+    row, and no block is allocated for a write that never comes. 8
+    prompt tokens and 9 new ones: the 8 steps write positions 8..15,
+    and the cursor then stands at 16, in a third block nobody writes.
+    One allocation at admission, one at the first step, no third."""
+    dec, params = lm
+    prompt = np.random.RandomState(31).randint(0, V, size=8).tolist()
+    want = _solo(dec, params, prompt, 9)
+    with serving.DecodeEngine(dec, params, slots=1, kv_block_size=8,
+                              kv_blocks=3, prefix_cache=False) as eng:
+        assert eng.submit(prompt, 9).result(300) == want
+        counts = _counts(eng)
+        allocs = eng.timers.counts()["block_alloc"]
+        assert eng._pool.allocatable() == 3
+    assert allocs == 2
+    assert counts["decode_steps"] == 8
+    assert counts["steps_dispatched_ahead"] == 7
+    assert counts["kv_block_steps"] == 2 * 8
+
+
 def test_generated_registration_gated_by_prefix_cache(lm):
     dec, params = lm
     with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,

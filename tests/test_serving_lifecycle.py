@@ -275,6 +275,10 @@ def test_server_drain_healthz_schema_and_refusal(lm):
     host, port = ms.start()
     base = "http://%s:%d" % (host, port)
     try:
+        # stall discipline (see test_cancel_frees_slot...): a warm
+        # engine runs both rollouts in less than an HTTP round trip,
+        # and the drain must provably still be under way when asked
+        chaos.arm("stall_decode_for=1.0")
         handles = [eng.submit([1, 2, 3], 30), eng.submit([4, 5], 30)]
         t = threading.Thread(target=ms.drain, kwargs={"timeout": 300})
         t.start()

@@ -43,23 +43,8 @@ Prints ONE JSON line. Fields:
                          to a stage instead of unexplained.
 - ``mfu``              — model FLOP utilization from XLA's compiled cost
                          analysis vs the chip's bf16 peak.
-- ``serving_decode``   — the serving plane (PR 2): continuous-batching
-                         decode engine vs the run-to-completion window
-                         batcher on 32 mixed-length requests (prompt
-                         8-128, max_new 8-128). ``speedup`` compares
-                         tokens/sec from COLD jit caches (a fresh server
-                         facing fresh traffic — the regime where the
-                         batcher's one-program-per-signature compile
-                         cost is real and unbounded); ``*_warm`` fields
-                         are the steady-state rerun. p50/p95/p99 are
-                         per-request submit->complete latencies read
-                         from the engine's own MetricsRegistry
-                         histograms (PR 5) — the same distributions
-                         ``GET /metrics`` exposes — plus per-histogram
-                         TTFT / per-token / decode-step / queue-wait
-                         quantiles under ``engine.hist``.
-- ``serving_fleet``    — the fleet plane (PR 6): the SAME mixed-length
-                         workload pushed over HTTP through the
+- ``serving_fleet``    — the fleet plane (PR 6): a mixed-length
+                         workload (prompt 8-128, max_new 8-128) pushed over HTTP through the
                          least-loaded ``fleet.FleetRouter`` at 1 vs 2
                          vs 4 DecodeEngine replicas — aggregate
                          tokens/sec, router-observed p50/p99, failover
@@ -371,7 +356,7 @@ def _serving_workload(n_requests, total_len, vocab, seed=0):
     prompt 8-128 and max_new 8-128 (multiples of 8, so the baseline's
     per-signature compile count stays bounded enough to measure), every
     request fitting ``prompt + max_new <= total_len``. Prompts cap at
-    ``total_len // 2`` so small-cache configs (scripts/profile_serving
+    ``total_len // 2`` so small-cache configs (scripts/profile_fleet
     shares this generator) still leave decode room; at the bench's own
     total_len=256 that cap is 128 — no change to the published
     workload. Needs ``total_len >= 16``."""
@@ -396,581 +381,6 @@ def _serving_model(on_tpu):
     return (DecoderLM(decode=False, **kw), DecoderLM(decode=True, **kw))
 
 
-def _batcher_leg(dec, params, reqs):
-    """The OLD serving shape: the window ``_Batcher`` groups only
-    identical-signature requests and runs each group to completion
-    through ``generate_jit`` — so a mixed-length workload degenerates
-    into many small run-to-max groups, each compiling its own
-    whole-generation program. Modeled in-process with the batcher's own
-    policies (perfect same-signature coalescing, rows padded to a
-    power-of-two bucket) — generous to the baseline: a real window
-    would add wait time and miss some coalesces. Latencies land in a
-    ``tracing.Histogram`` (no private percentile math — same read path
-    as the engine leg). Returns (tokens/sec, quantile dict, n_calls)."""
-    import jax.numpy as jnp
-    import numpy as np
-    from tensorflowonspark_tpu import generation, metrics_report, tracing
-
-    groups = {}
-    for i, (prompt, max_new) in enumerate(reqs):
-        groups.setdefault((len(prompt), max_new), []).append(i)
-    hist = tracing.Histogram()
-    tokens = 0
-    t0 = time.monotonic()
-    for (p_len, max_new), members in groups.items():
-        rows = np.asarray([reqs[i][0] for i in members], np.int32)
-        bucket = 1
-        while bucket < len(rows):
-            bucket *= 2
-        if bucket > len(rows):  # _Batcher._run_group's row padding
-            rows = np.concatenate(
-                [rows, np.repeat(rows[-1:], bucket - len(rows), axis=0)])
-        out = generation.generate_jit(dec, params, jnp.asarray(rows),
-                                      max_new)
-        out.block_until_ready()
-        done = time.monotonic() - t0
-        tokens += max_new * len(members)
-        for _ in members:
-            hist.observe(done)
-    wall = time.monotonic() - t0
-    return tokens / wall, metrics_report.quantiles_ms(hist), len(groups)
-
-
-def _engine_leg(dec, params, reqs, slots, **engine_kw):
-    """The NEW serving shape: continuous batching through
-    serving.DecodeEngine. Returns (tokens/sec, latency quantiles,
-    stats) — THE engine-measurement harness; scripts/profile_serving.py
-    imports it so bench numbers and profile attributions describe the
-    same run shape. ``engine_kw`` passes through to the engine
-    (``attn_impl="gather"`` runs the PR 8 reference formulation for
-    kernel-delta comparisons).
-
-    All percentiles are read from the engine's OWN MetricsRegistry
-    histograms (PR 5) — the exact objects ``GET /metrics`` renders —
-    so the published p50/p95/p99 and a scraped series are two views of
-    one distribution, never parallel sample lists. The ``attn`` stage
-    is the engine's standalone attention probe at its live shapes
-    (``measure_attn`` — one layer's worth per call), recorded through
-    the same StageTimers as every other stage so the fused-vs-gather
-    delta reads out of one table."""
-    from tensorflowonspark_tpu import metrics_report, serving
-
-    eng = serving.DecodeEngine(dec, params, slots=slots, **engine_kw)
-    try:
-        t0 = time.monotonic()
-        handles = [eng.submit(p, mn) for p, mn in reqs]
-        for h in handles:
-            h.result(1800)
-        wall = time.monotonic() - t0
-        eng.measure_attn()  # the 'attn' stage sample (idle engine)
-        eng.measure_dequant()  # the 'dequant' probe (int8 engines only)
-        eng.measure_spec()  # draft/verify probes (speculative only)
-        counts = eng.counters.snapshot()["counts"]
-        quantiles = metrics_report.serving_quantiles(eng.metrics)
-        stats = {"compile": eng.compile_stats(),
-                 "tokens": counts.get("tokens", 0),
-                 "wall_s": round(wall, 3),
-                 "tokens_per_step": round(
-                     eng.counters.rate("decode_tokens", "decode_steps"), 2),
-                 "decode_steps": counts.get("decode_steps", 0),
-                 "prefills": counts.get("prefills", 0),
-                 # request-lifecycle tallies (PR 4): all zero on this
-                 # clean workload — published so a regression that sheds
-                 # or evicts benched traffic is VISIBLE, not silent
-                 "lifecycle": {k: counts.get(k, 0) for k in
-                               ("shed", "cancelled", "deadline_exceeded",
-                                "engine_restarts")},
-                 # per-histogram latency quantiles (ttft / per-token /
-                 # decode-step / queue-wait) from the same registry
-                 "hist": {k: v for k, v in quantiles.items()
-                          if k != "latency"},
-                 "stage_ms": metrics_report.stage_ms(eng.timers),
-                 "stage_s_total": metrics_report.stage_totals_s(
-                     eng.timers)}
-        stats["attn_impl"] = eng.attn_impl
-        stats["kv_dtype"] = eng.kv_dtype
-        if eng._spec_k:
-            # speculation view (PR 15): acceptance is THE number that
-            # scales the speedup; tokens_per_step above already reads
-            # as tokens-per-round on a speculative engine
-            load = eng.load_stats()
-            stats["spec"] = {
-                "speculate_k": load["speculate_k"],
-                "draft_layers": eng.draft_layers,
-                "acceptance_rate": load["spec_acceptance_rate"],
-                "rounds": counts.get("spec_rounds", 0),
-                "proposed": counts.get("spec_proposed", 0),
-                "accepted": counts.get("spec_accepted", 0)}
-        if eng._paged:
-            # block-pool view (PR 8): resident KV bytes, pool headroom,
-            # and the prefix-cache tallies for this run shape
-            load = eng.load_stats()
-            stats["kv"] = {
-                "block_size": eng.kv_block_size,
-                "blocks_total": load["kv_blocks_total"],
-                "blocks_free": load["kv_blocks_free"],
-                "prefix_hit_rate": load["prefix_hit_rate"],
-                "generated_prefix_hit_blocks":
-                    load["generated_prefix_hit_blocks"],
-                "generated_prefix_registered":
-                    load["generated_prefix_registered"],
-                "cache_bytes": eng.kv_cache_bytes(),
-                "preemptions": counts.get("preemptions", 0)}
-        return (counts.get("tokens", 0) / wall, quantiles["latency"],
-                stats)
-    finally:
-        eng.stop()
-
-
-def _paged_capacity_leg(dec, params):
-    """Max concurrent sequences at a FIXED resident-KV budget: the
-    contiguous slot model reserves ``total_len`` rows per slot, so a
-    1024-row budget caps it at 4 slots; the paged engine spends the
-    same rows as a 64-block pool and admits every sequence whose
-    ACTUAL length fits — 16 concurrent 56-token sequences here. Peak
-    concurrency is read off the engine's own slot-occupancy gauge
-    while the shared workload runs. Returns the ``paged`` JSON block.
-    """
-    import numpy as np
-
-    from tensorflowonspark_tpu import serving
-
-    rng = np.random.RandomState(11)
-    # 16 requests x (32 prompt + 24 new) = 56 tokens = 4 blocks each
-    reqs = [(rng.randint(0, dec.vocab, size=32).tolist(), 24)
-            for _ in range(16)]
-
-    def peak_while(eng, handles):
-        peak = 0
-        while any(not h._done.is_set() for h in handles):
-            peak = max(peak, eng.counters.snapshot()["gauges"]
-                       .get("slot_occupancy", 0))
-            time.sleep(0.001)
-        for h in handles:
-            h.result(1800)
-        return peak
-
-    legs = {}
-    for label, kw in (
-            ("contiguous", dict(slots=4, kv_block_size=0)),
-            ("paged", dict(slots=16, kv_block_size=16, kv_blocks=64))):
-        eng = serving.DecodeEngine(dec, params, **kw)
-        try:
-            t0 = time.monotonic()
-            peak = peak_while(eng, [eng.submit(p, mn) for p, mn in reqs])
-            wall = time.monotonic() - t0
-            counts = eng.counters.snapshot()["counts"]
-            legs[label] = {
-                "slots": eng.slots,
-                "kv_cache_bytes": eng.kv_cache_bytes(),
-                "peak_concurrent": int(peak),
-                "tokens_per_sec": round(
-                    counts.get("tokens", 0) / wall, 1),
-                "preemptions": counts.get("preemptions", 0)}
-        finally:
-            eng.stop()
-    legs["workload"] = {"requests": len(reqs), "prompt_len": 32,
-                        "max_new": 24, "budget_rows": 4 * dec.max_len}
-    contig = legs["contiguous"]["peak_concurrent"] or 1
-    legs["concurrency_ratio"] = round(
-        legs["paged"]["peak_concurrent"] / contig, 2)
-    return legs
-
-
-def _prefix_reuse_leg(on_tpu):
-    """Warm vs cold TTFT on a shared-system-prompt workload: 12
-    requests share a 960-token system prompt and differ in an 8-token
-    user tail (the agent/RAG traffic shape prefix caching exists for —
-    a long fixed preamble, a short per-request suffix). COLD (prefix
-    cache off) every request prefills all 968 tokens; WARM a resident
-    prefix turns admission into a table write plus an 8-token tail
-    prefill. Uses a dedicated long-context engine config (max_len 1024
-    vs the shared workload's 256) because the claim IS about long
-    shared prompts. TTFT is measured client-side (submit -> first
-    streamed token) with programs prewarmed in both legs, so the ratio
-    is pure prefill economics, not compile skew. Returns the
-    ``prefix_reuse`` JSON block."""
-    import jax
-    import numpy as np
-
-    from tensorflowonspark_tpu import serving
-    from tensorflowonspark_tpu.models.decoder import DecoderLM
-
-    kw = dict(vocab=256, hidden=256 if on_tpu else 64,
-              num_heads=8 if on_tpu else 4,
-              num_layers=4 if on_tpu else 2, max_len=1024)
-    train = DecoderLM(decode=False, **kw)
-    dec = DecoderLM(decode=True, **kw)
-    params = train.init(jax.random.PRNGKey(0),
-                        np.zeros((1, 64), np.int32))["params"]
-    rng = np.random.RandomState(12)
-    sys_prompt = rng.randint(0, dec.vocab, size=960).tolist()
-    reqs = [(sys_prompt + rng.randint(0, dec.vocab, size=8).tolist(), 8)
-            for _ in range(12)]
-
-    def ttft_ms(eng, prompt, max_new):
-        t0 = time.monotonic()
-        handle = eng.submit(prompt, max_new)
-        stream = handle.stream(timeout=1800)
-        next(stream)
-        ttft = (time.monotonic() - t0) * 1000.0
-        for _ in stream:  # drain to completion
-            pass
-        return ttft
-
-    out = {"workload": {"requests": len(reqs), "system_prompt": 960,
-                        "tail": 8, "max_new": 8,
-                        "total_len": dec.max_len}}
-    for label, cache_on in (("cold", False), ("warm", True)):
-        eng = serving.DecodeEngine(dec, params, slots=4,
-                                   kv_block_size=16,
-                                   prefix_cache=cache_on)
-        try:
-            # prewarm: first call compiles the 256-bucket prefill and
-            # the decode program; the second (warm leg only) both
-            # verifies the hit path and compiles the tail bucket
-            warm_tail = rng.randint(0, dec.vocab, size=8).tolist()
-            ttft_ms(eng, sys_prompt + warm_tail, 8)
-            if cache_on:
-                ttft_ms(eng, sys_prompt + warm_tail[::-1], 8)
-            samples = sorted(ttft_ms(eng, p, mn) for p, mn in reqs)
-            load = eng.load_stats()
-            out[label] = {
-                "ttft_ms_p50": round(samples[len(samples) // 2], 3),
-                "ttft_ms_mean": round(sum(samples) / len(samples), 3),
-                "prefix_hit_rate": load["prefix_hit_rate"]}
-        finally:
-            eng.stop()
-    if out["warm"]["ttft_ms_p50"]:
-        out["ttft_speedup_p50"] = round(
-            out["cold"]["ttft_ms_p50"] / out["warm"]["ttft_ms_p50"], 2)
-    return out
-
-
-def _multi_turn_leg(on_tpu, turns=4):
-    """Multi-turn chat: the workload generated-prefix registration
-    (PR 11) exists for. One conversation runs ``turns`` rounds; each
-    round's prompt is the FULL history (prior prompt + prior reply) +
-    a short new user message. WARM (prefix cache on, the default) the
-    prior turns' blocks — including the DECODE-generated reply blocks
-    — are resident, so turn 2+ admission is a table write plus a
-    short-tail prefill; COLD (prefix cache off) every turn re-prefills
-    its whole history. Warm turn-2 TTFT >= 5x faster than cold is the
-    acceptance floor.
-
-    Also publishes ``decode_step_vs_pool``: per-step decode time at a
-    FIXED live-token workload while total_len (and the default pool
-    with it) scales — the fused path's curve must stay flat (it visits
-    live blocks only) while the gather path's grows with the logical
-    view it materializes each step. TTFTs are measured client-side
-    with programs prewarmed on a throwaway conversation, so the ratio
-    is prefill economics, not compile skew."""
-    import jax
-    import numpy as np
-
-    from tensorflowonspark_tpu import metrics_report, serving
-    from tensorflowonspark_tpu.models.decoder import DecoderLM
-
-    kw = dict(vocab=256, hidden=256 if on_tpu else 64,
-              num_heads=8 if on_tpu else 4,
-              num_layers=4 if on_tpu else 2, max_len=1024)
-    train = DecoderLM(decode=False, **kw)
-    dec = DecoderLM(decode=True, **kw)
-    params = train.init(jax.random.PRNGKey(0),
-                        np.zeros((1, 64), np.int32))["params"]
-    rng = np.random.RandomState(13)
-    sys_len, user_len, max_new = 448, 8, 48
-
-    def conversation(seed_off):
-        r = np.random.RandomState(100 + seed_off)
-        return (r.randint(0, dec.vocab, size=sys_len).tolist(),
-                [r.randint(0, dec.vocab, size=user_len).tolist()
-                 for _ in range(turns)])
-
-    def chat_ttfts(eng, seed_off):
-        """Run one conversation; per-turn client-side TTFT. Each
-        turn's reply (handle.result = prompt + generated) becomes the
-        next turn's history, exactly the agent-chat traffic shape."""
-        sys_prompt, users = conversation(seed_off)
-        history = list(sys_prompt)
-        ttfts = []
-        for u in users:
-            prompt = history + u
-            t0 = time.monotonic()
-            handle = eng.submit(prompt, max_new)
-            stream = handle.stream(timeout=1800)
-            next(stream)
-            ttfts.append((time.monotonic() - t0) * 1000.0)
-            for _ in stream:
-                pass
-            history = handle.result(10)
-        return ttfts
-
-    out = {"workload": {"turns": turns, "system_prompt": sys_len,
-                        "user_msg": user_len, "max_new": max_new,
-                        "total_len": dec.max_len}}
-    for label, cache_on in (("cold", False), ("warm", True)):
-        eng = serving.DecodeEngine(dec, params, slots=2,
-                                   kv_block_size=16,
-                                   prefix_cache=cache_on)
-        try:
-            chat_ttfts(eng, seed_off=9)      # prewarm compiles only
-            ttfts = chat_ttfts(eng, seed_off=0)
-            load = eng.load_stats()
-            out[label] = {
-                "ttft_ms_per_turn": [round(t, 3) for t in ttfts],
-                "ttft_ms_turn2": round(ttfts[1], 3),
-                "ttft_ms_turns2plus_p50": round(
-                    metrics_report.median(ttfts[1:]), 3),
-                "prefix_hit_rate": load["prefix_hit_rate"],
-                "generated_prefix_hit_blocks":
-                    load["generated_prefix_hit_blocks"],
-                "generated_prefix_registered":
-                    load["generated_prefix_registered"]}
-        finally:
-            eng.stop()
-    if out["warm"]["ttft_ms_turn2"]:
-        out["ttft_speedup_turn2"] = round(
-            out["cold"]["ttft_ms_turn2"] / out["warm"]["ttft_ms_turn2"],
-            2)
-        out["ttft_speedup_turns2plus_p50"] = round(
-            out["cold"]["ttft_ms_turns2plus_p50"]
-            / out["warm"]["ttft_ms_turns2plus_p50"], 2)
-
-    # per-step decode time vs pool size at FIXED live tokens: 4 short
-    # sequences (16-token prompts, 32 new) decode on engines whose
-    # total_len — and default pool — scales 256 -> 1024. The fused
-    # kernel's per-step cost tracks the ~3 live blocks per row; the
-    # gather formulation re-materializes the total_len-long logical
-    # view every step, so its curve grows with the pool it pages.
-    curve = []
-    for total_len in (256, 512, 1024):
-        point = {"total_len": total_len,
-                 "kv_blocks": 4 * total_len // 16}
-        for impl in ("fused", "gather"):
-            eng = serving.DecodeEngine(
-                dec, params, slots=4, total_len=total_len,
-                kv_block_size=16, attn_impl=impl, prefix_cache=False)
-            try:
-                reqs = [(rng.randint(0, dec.vocab, size=16).tolist(),
-                         32) for _ in range(4)]
-                for h in [eng.submit(p, mn) for p, mn in reqs]:
-                    h.result(1800)
-                hist = eng.metrics.get_histogram(
-                    "tfos_serving_decode_step_seconds")
-                point["{}_step_ms_p50".format(impl)] = \
-                    metrics_report.quantiles_ms(hist)["p50_ms"]
-                # probe at the workload's live depth (48 tokens/row),
-                # not the default half-table, so the attn attribution
-                # describes the benched shapes
-                point["{}_attn_ms".format(impl)] = \
-                    eng.measure_attn(depth=48)
-            finally:
-                eng.stop()
-        curve.append(point)
-    out["decode_step_vs_pool"] = {
-        "workload": {"sequences": 4, "prompt_len": 16, "max_new": 32,
-                     "live_tokens_per_seq": 48},
-        "points": curve}
-    return out
-
-
-def _speculative_leg(on_tpu):
-    """serving_decode.speculative (PR 15): tokens/sec, acceptance
-    rate, and p99 for speculative engines at k in {2, 4, 8} vs the
-    plain paged engine on the shared mixed-length workload. Uses a
-    4-layer model with a 1-layer weight-tied draft and draft-friendly
-    (zero-residual-tail) weights — the regime where speculation's
-    ceiling is visible; the acceptance rate is published so
-    real-model numbers scale honestly. Warm legs (a cold run compiles
-    first), 3-rep MEDIANS per config (the CI box's run-to-run spread
-    exceeds the effect at small k), so the ratio is steady-state
-    decode, not compile skew or box noise. Claim: >= 1.3x tokens/sec
-    over the plain engine at temp=0 (``speedup_best``; greedy outputs
-    bitwise-identical — that half is pinned in
-    tests/test_speculative.py, not here). The CPU box note: a
-    compute-bound verify scales with k where a bandwidth-bound
-    accelerator's barely does, so the break-even k here (≈6) is an
-    UPPER bound on what a TPU would need — k∈{2,4} are published as
-    the accelerator-typical operating points, k=8 as this box's
-    demonstrated win."""
-    import jax
-    import numpy as np
-
-    from tensorflowonspark_tpu.models.decoder import DecoderLM
-
-    kw = dict(vocab=256, hidden=256 if on_tpu else 64,
-              num_heads=8 if on_tpu else 4, num_layers=4, max_len=256)
-    train = DecoderLM(decode=False, **kw)
-    dec = DecoderLM(decode=True, **kw)
-    params = train.init(jax.random.PRNGKey(0),
-                        np.zeros((1, dec.max_len), np.int32))["params"]
-    draft_layers = 1
-    from tests.spec_weights import zero_residual_tail
-
-    params = zero_residual_tail(params, draft_layers, kw["num_layers"])
-    reqs = _serving_workload(24, dec.max_len, dec.vocab, seed=4)
-
-    out = {"workload": {"requests": len(reqs),
-                        "total_tokens": sum(mn for _, mn in reqs),
-                        "reps": 3},
-           "model": {"num_layers": kw["num_layers"],
-                     "draft_layers": draft_layers,
-                     "draft_friendly_weights": True}}
-    legs = [("plain", {})] + [
-        ("spec_k%d" % k, {"speculate_k": k,
-                          "draft_layers": draft_layers})
-        for k in (2, 4, 8)]
-    for label, ekw in legs:
-        _engine_leg(dec, params, reqs, slots=8, **ekw)   # compile leg
-        runs = [_engine_leg(dec, params, reqs, slots=8, **ekw)
-                for _ in range(3)]
-        tps, lat, stats = sorted(runs, key=lambda r: r[0])[1]  # median
-        leg = {"tokens_per_sec": round(tps, 1),
-               "p99_ms": lat["p99_ms"], "p50_ms": lat["p50_ms"],
-               "tokens_per_round": stats["tokens_per_step"]}
-        if "spec" in stats:
-            leg["acceptance_rate"] = stats["spec"]["acceptance_rate"]
-        out[label] = leg
-    plain = out["plain"]["tokens_per_sec"] or 1.0
-    for k in (2, 4, 8):
-        out["speedup_k%d" % k] = round(
-            out["spec_k%d" % k]["tokens_per_sec"] / plain, 2)
-    out["speedup_best"] = max(out["speedup_k%d" % k] for k in (2, 4, 8))
-    return out
-
-
-def _kv_int8_leg(dec, params):
-    """serving_decode.kv_int8 (PR 15): peak concurrent sequences at a
-    FIXED resident-KV byte budget, f32 pool vs int8 pool — the int8
-    codes + per-head scales cost 40 bytes/token/layer at head_dim 16
-    vs f32's 128, so the same budget buys ~3.2x the blocks (the
-    acceptance floor is 1.8x). Slots are sized not to bind in either
-    leg, so block capacity is the ONLY constraint being measured;
-    per-step p50 rides along from the engine's own histogram."""
-    import numpy as np
-
-    from tensorflowonspark_tpu import metrics_report, paging, serving
-
-    rng = np.random.RandomState(15)
-    # 24 requests x (32 prompt + 24 new) = 56 tokens = 4 blocks each
-    reqs = [(rng.randint(0, dec.vocab, size=32).tolist(), 24)
-            for _ in range(24)]
-    heads = dec.num_heads
-    head_dim = dec.hidden // dec.num_heads
-    f32_block = paging.BlockPool(1, 16).block_bytes(
-        heads, head_dim, dec.num_layers)
-    i8_block = paging.BlockPool(1, 16, kv_dtype="int8").block_bytes(
-        heads, head_dim, dec.num_layers)
-    f32_blocks = 24
-    budget = f32_block * f32_blocks
-    i8_blocks = budget // i8_block
-
-    def peak_while(eng, handles):
-        peak = 0
-        while any(not h._done.is_set() for h in handles):
-            peak = max(peak, eng.counters.snapshot()["gauges"]
-                       .get("slot_occupancy", 0))
-            time.sleep(0.001)
-        for h in handles:
-            h.result(1800)
-        return peak
-
-    legs = {"workload": {"requests": len(reqs), "prompt_len": 32,
-                         "max_new": 24, "budget_bytes": int(budget)}}
-    for label, kw_eng in (
-            ("fp32", dict(slots=24, kv_block_size=16,
-                          kv_blocks=f32_blocks)),
-            ("int8", dict(slots=24, kv_block_size=16,
-                          kv_blocks=int(i8_blocks), kv_dtype="int8"))):
-        eng = serving.DecodeEngine(dec, params, **kw_eng)
-        try:
-            t0 = time.monotonic()
-            peak = peak_while(eng, [eng.submit(p, mn) for p, mn in reqs])
-            wall = time.monotonic() - t0
-            counts = eng.counters.snapshot()["counts"]
-            step_hist = eng.metrics.get_histogram(
-                "tfos_serving_decode_step_seconds")
-            legs[label] = {
-                "kv_blocks": eng.kv_blocks,
-                "kv_cache_bytes": eng.kv_cache_bytes(),
-                "peak_concurrent": int(peak),
-                "step_ms_p50": metrics_report.quantiles_ms(
-                    step_hist)["p50_ms"],
-                "dequant_ms": eng.measure_dequant(),
-                "tokens_per_sec": round(
-                    counts.get("tokens", 0) / wall, 1),
-                "preemptions": counts.get("preemptions", 0)}
-        finally:
-            eng.stop()
-    f32_peak = legs["fp32"]["peak_concurrent"] or 1
-    legs["concurrency_ratio"] = round(
-        legs["int8"]["peak_concurrent"] / f32_peak, 2)
-    legs["block_capacity_ratio"] = round(i8_blocks / f32_blocks, 2)
-    return legs
-
-
-def _serving_decode_bench(on_tpu):
-    """Mixed-length serving comparison: continuous-batching engine vs
-    the run-to-completion window batcher, both from COLD jit caches (a
-    fresh server facing fresh traffic — the regime where the baseline's
-    per-signature compiles are its real cost) and again WARM (pure
-    steady-state decode). Returns the ``serving_decode`` JSON block.
-    """
-    import jax
-    import numpy as np
-
-    train, dec = _serving_model(on_tpu)
-    params = train.init(jax.random.PRNGKey(0),
-                        np.zeros((1, dec.max_len), np.int32))["params"]
-    reqs = _serving_workload(32, dec.max_len, dec.vocab)
-
-    def _leg(fn):
-        jax.clear_caches()
-        cold = fn()
-        warm = fn()
-        return cold, warm
-
-    # latency quantiles come back from the legs already read out of
-    # histograms (the engine's own registry / the batcher's standalone
-    # tracing.Histogram) — no private percentile math here
-    (b_cold_tps, b_cold_lat, n_calls), (b_warm_tps, b_warm_lat, _) = _leg(
-        lambda: _batcher_leg(dec, params, reqs))
-    (e_cold_tps, e_cold_lat, e_stats), (e_warm_tps, e_warm_lat, _) = _leg(
-        lambda: _engine_leg(dec, params, reqs, slots=8))
-
-    block = {
-        "workload": {"requests": len(reqs), "prompt_lens": "8-128",
-                     "max_new": "8-128",
-                     "total_tokens": sum(mn for _, mn in reqs),
-                     "signatures": n_calls},
-        "engine": dict(tokens_per_sec=round(e_cold_tps, 1),
-                       **dict(e_cold_lat, **e_stats)),
-        "batcher": dict(tokens_per_sec=round(b_cold_tps, 1),
-                        model_calls=n_calls, **b_cold_lat),
-        "engine_warm": dict(tokens_per_sec=round(e_warm_tps, 1),
-                            **e_warm_lat),
-        "batcher_warm": dict(tokens_per_sec=round(b_warm_tps, 1),
-                             **b_warm_lat),
-        "speedup": round(e_cold_tps / b_cold_tps, 2) if b_cold_tps else None,
-        "speedup_warm": round(e_warm_tps / b_warm_tps, 2)
-        if b_warm_tps else None,
-    }
-    # PR 8 legs: concurrency at a fixed resident-KV budget, and warm
-    # vs cold TTFT under shared-system-prompt traffic
-    block["paged"] = _paged_capacity_leg(dec, params)
-    block["prefix_reuse"] = _prefix_reuse_leg(on_tpu)
-    # PR 11 leg: multi-turn chat (generated-prefix reuse) + per-step
-    # decode time vs pool size for the fused vs gather formulations
-    block["multi_turn"] = _multi_turn_leg(on_tpu)
-    # PR 15 legs: speculative decoding (tokens/sec + acceptance at
-    # k in {2,4} vs the plain engine) and int8 KV concurrency at a
-    # fixed byte budget
-    block["speculative"] = _speculative_leg(on_tpu)
-    block["kv_int8"] = _kv_int8_leg(dec, params)
-    return block
-
-
 def _fleet_leg(dec, params, reqs, n_replicas, slots=8, concurrency=None):
     """Push ``reqs`` over HTTP through a FleetRouter fronting
     ``n_replicas`` in-process DecodeEngines; returns (aggregate
@@ -979,7 +389,7 @@ def _fleet_leg(dec, params, reqs, n_replicas, slots=8, concurrency=None):
     bench numbers and routing-overhead attributions describe the same
     run shape. All percentiles and the overhead split are read from
     the router's OWN MetricsRegistry histograms (the objects its
-    ``GET /metrics`` renders), same discipline as ``_engine_leg``."""
+    ``GET /metrics`` renders)."""
     import concurrent.futures
     import json as json_mod
     import urllib.request
@@ -1862,12 +1272,11 @@ def _serving_fleet_bench(on_tpu, replica_counts=(1, 2, 4)):
     replicas on the shared mixed-length workload. Returns the
     ``serving_fleet`` JSON block.
 
-    Every leg runs WARM: the slot-step programs are shared per (model,
+    Every leg runs WARM: the engine's step programs are shared per (model,
     sampling-config) across all engines, so without a prewarm the
     1-replica leg would pay every compile and the scaling ratios would
-    flatter the bigger fleets with someone else's compile time.
-    Cold-compile economics are ``serving_decode``'s story; this block's
-    claim is CAPACITY scaling."""
+    flatter the bigger fleets with someone else's compile time. This
+    block's claim is CAPACITY scaling."""
     import jax
     import numpy as np
 
@@ -2836,19 +2245,10 @@ def main():
         _FAILED_LEGS.append("device_only")
         print("device_only failed: {}".format(device_error), file=sys.stderr)
 
-    # Serving plane: the continuous-batching decode engine vs the old
-    # run-to-completion window batcher on mixed-length traffic
-    # (tokens/sec + p50/p99 request latency, cold and warm).
-    # TFOS_BENCH_SERVING=0 skips it.
-    serving_decode = None
-    if os.environ.get("TFOS_BENCH_SERVING", "1") == "1":
-        serving_decode = _leg("serving_decode", _serving_decode_bench,
-                              on_tpu)
-
-    # Fleet plane (PR 6): the same workload through the least-loaded
+    # Fleet plane (PR 6): mixed-length traffic through the least-loaded
     # router at 1 vs 2 vs 4 replicas — aggregate tokens/sec scaling +
-    # routing overhead. Shares the serving gate; TFOS_BENCH_FLEET=0
-    # skips just this leg.
+    # routing overhead. TFOS_BENCH_SERVING=0 or TFOS_BENCH_FLEET=0
+    # skips it.
     serving_fleet = None
     if os.environ.get("TFOS_BENCH_SERVING", "1") == "1" \
             and os.environ.get("TFOS_BENCH_FLEET", "1") == "1":
@@ -2917,9 +2317,6 @@ def main():
         "fed_vs_round2": round(best_fed / ROUND2_FED_IMAGES_PER_SEC, 2)
         if best_fed and on_tpu else None,
         "mfu": round(mfu, 4) if mfu is not None else None,
-        # continuous-batching decode engine vs run-to-completion window
-        # batcher on mixed-length traffic (PR 2; BENCH_r06+ tracks this)
-        "serving_decode": serving_decode,
         # fleet plane (PR 6): aggregate tokens/sec + p99 through the
         # least-loaded router at 1 vs 2 vs 4 replicas
         "serving_fleet": serving_fleet,

@@ -21,7 +21,8 @@ CPU):
    uint8 records made from the seed, default (``auto``) feed transport.
 2. ``serving_phase`` — ``ModelServer`` + paged ``DecodeEngine`` over a
    GPT-2-small-width ``DecoderLM``; real HTTP ``:generate`` requests
-   checked against ``generate_jit`` and the ``gather`` oracle.
+   checked against ``generate_jit``, and the paged attention op at the
+   engine's shapes against its ``gather`` formulation.
 3. ``kernel_phase`` — both Pallas kernels alone against their XLA
    oracles, with ``tpu_custom_call`` asserted from the lowered text.
 4. ``multichip_phase`` (``--multichip`` only) — the fed job on a
@@ -373,6 +374,8 @@ def serving_phase(seed, platform="tpu", model=GPT2_SMALL,
     """Phase 2. Requests: one per prompt length, the middle one sent
     twice CONCURRENTLY with the last, then the first repeated (a prefix
     cache hit)."""
+    import functools
+    import importlib
     import threading
 
     import jax
@@ -452,7 +455,6 @@ def serving_phase(seed, platform="tpu", model=GPT2_SMALL,
         code, body = _get(port, "/healthz")
         health = json.loads(body)
         require(code == 200 and health["status"] == "ok", health)
-        require(health["attn_impl"] == "fused", health["attn_impl"])
         require(health["kv_blocks_free"] == health["kv_blocks_total"], health)
         require(health["prefix_hit_rate"] > 0, health)
         code, metrics = _get(port, "/metrics")
@@ -471,27 +473,50 @@ def serving_phase(seed, platform="tpu", model=GPT2_SMALL,
             m = margin(prompts[i], tokens)
             worst_margin = max(worst_margin, m)
             require(m <= LOGIT_MARGIN_TOL, i, m, tokens, want[i])
-    # the kernel against an implementation that shares none of its code
-    with serving.DecodeEngine(dec, params, attn_impl="gather") as oracle:
-        oracle_tokens = oracle.submit(
-            prompts[1], new_tokens).result(600)[len(prompts[1]):]
-    if oracle_tokens != got[(1, 1)]:
-        m = margin(prompts[1], got[(1, 1)])
-        worst_margin = max(worst_margin, m)
-        require(m <= LOGIT_MARGIN_TOL, m, oracle_tokens, got[(1, 1)])
-    parity = "tokens equal" if equal == len(got) \
-        and oracle_tokens == got[(1, 1)] else (
-            "{} of {} requests token-equal to generate_jit, gather oracle "
-            "{}; the others within {} of the top logit of a plain forward "
-            "at every step (worst {:.4f})".format(
-                equal, len(got),
-                "equal" if oracle_tokens == got[(1, 1)] else "differs",
-                LOGIT_MARGIN_TOL, worst_margin))
+    parity = "tokens equal" if equal == len(got) else (
+        "{} of {} requests token-equal to generate_jit; the others within "
+        "{} of the top logit of a plain forward at every step (worst "
+        "{:.4f})".format(equal, len(got), LOGIT_MARGIN_TOL, worst_margin))
+    # the kernel against an implementation that shares none of its code:
+    # one decode-shaped call of the op each way, at the engine's own
+    # pool, table and query shapes
+    pa = importlib.import_module("tensorflowonspark_tpu.ops.paged_attention")
+    heads = model["num_heads"]
+    head_dim = model["hidden"] // heads
+    width = engine.total_len // engine.kv_block_size
+    pool_rows = engine.kv_blocks + 1
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (engine.slots, 1, heads, head_dim))
+    kp, vp = (jax.random.normal(
+        key, (pool_rows, engine.kv_block_size, heads * head_dim))
+        for key in keys[1:])
+    table = jnp.asarray(
+        1 + rng.permutation(pool_rows - 1)[:engine.slots * width]
+        .reshape(engine.slots, width), jnp.int32)
+    pos = jnp.asarray(
+        rng.randint(0, engine.total_len, size=(engine.slots, 1)), jnp.int32)
+
+    @functools.partial(jax.jit, static_argnames="impl")
+    def paged(q, kp, vp, table, pos, impl=None):
+        return pa.paged_attention(q, kp, vp, table, pos, impl=impl)
+
+    args = (q, kp, vp, table, pos)
+    has_kernel = "tpu_custom_call" in paged.lower(*args).as_text()
+    require(has_kernel or platform != "tpu",
+            "no tpu_custom_call in the engine-shaped paged attention")
+    gather_err = _rel_err(paged(*args), paged(*args, impl="gather"))
+    require(gather_err <= KERNEL_FWD_RTOL, gather_err, KERNEL_FWD_RTOL)
     return emit({
         "phase": "serving", "ok": True, "device": device,
         "model": model, "prompt_lens": list(prompt_lens),
         "requests": len(got), "new_tokens": new_tokens,
-        "attn_impl": health["attn_impl"], "parity": parity,
+        "parity": parity,
+        "default_vs_gather": {
+            "what": "ops.paged_attention, impl=None against "
+                    "impl='gather', one decode call at the engine's shapes",
+            "q": list(q.shape), "pool": list(kp.shape),
+            "table": list(table.shape), "rel_err": round(gather_err, 6),
+            "tol": KERNEL_FWD_RTOL, "tpu_custom_call": has_kernel},
         "prefix_hit_rate": health["prefix_hit_rate"],
         "kv_blocks_total": health["kv_blocks_total"],
         "wall_seconds": round(time.monotonic() - t_phase, 2),

@@ -121,7 +121,7 @@ def main(argv=None):
 
     # bench.py's harness + workload — ONE fleet-measurement
     # implementation, shared so this attribution describes the benched
-    # run shape (same discipline as scripts/profile_serving.py)
+    # run shape
     from bench import _fleet_leg, _serving_workload
 
     train = DecoderLM(vocab=args.vocab, hidden=args.hidden, num_heads=4,

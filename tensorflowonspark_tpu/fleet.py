@@ -815,8 +815,8 @@ class ServingNode(object):
       (numpy) params; OR ``builder``, a zero-arg callable returning
       ``(model, params)`` (load from a checkpoint/export path on the
       executor instead of shipping weights over the task wire)
-    - ``engine_kw`` — DecodeEngine knobs (slots, kv paging,
-      ``attn_impl``, ...) — the spawn config rides here verbatim
+    - ``engine_kw`` — DecodeEngine knobs (slots, kv paging, ...) —
+      the spawn config rides here verbatim
     - ``reservation_addr`` / ``beat_interval`` — the driver's BEAT
       registry and cadence
     """
@@ -887,8 +887,7 @@ class ServingNode(object):
         if old is not None:
             old.stop()
         fresh = self.replica.respawn_engine()
-        return {"replica_id": self.replica_id,
-                "attn_impl": fresh.attn_impl, "ok": True}
+        return {"replica_id": self.replica_id, "ok": True}
 
     def _rpc_re_register(self, payload):
         self.replica.re_register()
@@ -1364,12 +1363,8 @@ class FleetRouter(object):
                 "queue_depth": gauges.get("queue_depth", 0),
                 "slot_occupancy": gauges.get("slot_occupancy", 0),
                 "queue_wait_ewma_s": gauges.get("queue_wait_ewma_s", 0.0),
-                # kernel config (PR 11): which attention formulation
-                # each replica runs, so a heterogeneous fleet (e.g. a
-                # staged fused-kernel rollout) is legible from the
-                # router's health view; plus the generated-prefix hit
-                # tally, the multi-turn-reuse signal
-                "attn_impl": gauges.get("attn_impl"),
+                # the generated-prefix hit tally (PR 11), the
+                # multi-turn-reuse signal
                 "generated_prefix_hit_blocks": gauges.get(
                     "generated_prefix_hit_blocks", 0),
                 # speed-path config (PR 15): which replicas speculate
@@ -1680,9 +1675,10 @@ class FleetRouter(object):
                           if str(v.get("replica_id")) == p_rid)
             block = int(p_view.get("prefix_digest_block_size") or 0)
             if block <= 0 or len(prompt_tokens) < block:
-                # an unpaged prefill replica exports nothing, and a
-                # sub-block prompt ships zero full blocks — skip the
-                # round trip instead of prefilling for no shipment
+                # a view that carries no block size gives nothing to
+                # match, and a sub-block prompt ships zero full blocks:
+                # skip the round trip instead of prefilling for no
+                # shipment
                 return None
             # stage 2: decode placement — the same affinity plan the
             # decode attempt will run, so ship target == route target
@@ -2365,7 +2361,6 @@ class FleetRouter(object):
                     "alive": v["alive"], "draining": v["draining"],
                     "queue_depth": v["queue_depth"],
                     "slot_occupancy": v["slot_occupancy"],
-                    "attn_impl": v["attn_impl"],
                     "generated_prefix_hit_blocks":
                         v["generated_prefix_hit_blocks"],
                     "speculate_k": v["speculate_k"],

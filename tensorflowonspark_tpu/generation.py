@@ -16,10 +16,11 @@ PRNG key. ``generate`` feeds one token per step (the flax decode-cache
 contract), which makes its prefill a scan — simple and fully compiled.
 
 For SERVING, this module also provides the slot-structured primitives
-(``prefill_into_slot`` — fused multi-token, shape-bucketed — and
-``decode_step`` over per-slot cursors) that serving.DecodeEngine
-schedules continuously; see docs/serving.md. Both paths produce
-bitwise-identical greedy outputs per sequence.
+over the paged block pool (``paged_prefill_into_slot`` — fused
+multi-token, shape-bucketed — and ``paged_decode_step`` over per-slot
+cursors and block tables) that serving.DecodeEngine schedules
+continuously; see docs/serving.md. Both paths produce identical greedy
+outputs per sequence.
 """
 
 import functools
@@ -187,20 +188,12 @@ def generate(model, params, prompt, max_new_tokens, temperature=0.0,
 # to completion — fine for offline jobs, the wrong shape for serving
 # mixed-length traffic. The primitives below decompose generation so a
 # scheduler (serving.DecodeEngine) can run ITERATION-LEVEL batching over
-# a slot-structured KV cache:
-#
-# - ``init_cache(model, slots, total_len)`` — one cache, S independent
-#   slots (rows), each with its own write cursor (models/decoder.py keeps
-#   ``cache_index``/``pos_idx`` per-ROW for exactly this).
-# - ``prefill_into_slot`` — run one request's prompt (padded to a shape
-#   bucket) through a batch-1 mini cache, then scatter its K/V rows into
-#   the engine cache at the slot index. Compiles once per BUCKET length,
-#   not once per prompt length.
-# - ``decode_step`` — one fixed-shape step over all S slots at their own
-#   cursors. Compiles ONCE per (slots, total_len) engine config.
-#
-# Both jitted wrappers donate the engine cache, so the scheduler's
-# steady-state loop updates the cache in place instead of copying it.
+# a slot-structured KV cache: ``init_cache(model, slots, total_len)``
+# gives one cache with S independent slots (rows), each with its own
+# write cursor (models/decoder.py keeps ``cache_index``/``pos_idx``
+# per-ROW for exactly this), a prefill runs one request's prompt
+# (padded to a shape bucket) into one slot, and a decode step runs one
+# fixed-shape step over all S slots at their own cursors.
 
 
 def _pick_tokens(logits, key, temperature, top_k, top_p):
@@ -225,99 +218,6 @@ def _leaf_name(path):
     return getattr(entry, "key", None) or getattr(entry, "name", str(entry))
 
 
-def _set_cursor_leaves(cache, idx):
-    """Cache pytree with every per-row cursor leaf replaced by ``idx``.
-
-    The scheduler (host) is the authority on each slot's position — a
-    freed slot must NOT keep advancing its cursor while it idles, and a
-    re-admitted slot restarts at its new prompt length. Overwriting the
-    cursors before each step makes the device cache's own increments
-    advisory, so inactive slots just re-write one stale position in
-    place instead of walking off the end of the cache.
-
-    This same discipline is what makes MID-FLIGHT EVICTION (PR 4:
-    cancel / deadline, serving.DecodeEngine._evict_expired) free: an
-    evicted request's slot is simply marked free on the host — no
-    device-side cleanup exists or is needed, because a freed slot's
-    stale K/V was already unreachable (cursor pinned, next occupant's
-    prefill scatters over the full rows) and neighbors never see it.
-    Eviction therefore cannot perturb concurrent sequences, which is
-    why cancelled-neighbor outputs stay bitwise-identical
-    (tests/test_serving_lifecycle.py pins this).
-    """
-    def repl(path, leaf):
-        if _leaf_name(path) in _CURSOR_LEAVES:
-            return idx.astype(leaf.dtype)
-        return leaf
-    return jax.tree_util.tree_map_with_path(repl, cache)
-
-
-def prefill_into_slot(model, params, cache, slot, tokens, true_len,
-                      temperature=0.0, top_k=None, top_p=None, rng=None):
-    """Prefill one request's prompt into slot ``slot`` of ``cache``.
-
-    ``tokens`` is the prompt padded to its shape bucket ``[bucket_len]``
-    (int32); ``true_len`` is the real prompt length. The prompt runs
-    through a fresh batch-1 mini cache as ONE fused multi-token forward
-    (models/decoder.py's prefill branch: K/V rows [0, bucket_len)
-    written in one pass, each query row masked to its causal prefix —
-    bitwise-identical per row to the token-by-token path), and the
-    logits at position ``true_len - 1`` are captured. Pad positions
-    beyond it do execute (static shapes) but their K/V is never
-    visible: the slot's cursor is set to ``true_len`` and decode
-    overwrites position ``true_len + k`` at step k strictly before the
-    visibility mask reaches it. The mini cache's FULL rows are
-    scattered into the slot, wiping any previous occupant's K/V.
-
-    Returns ``(cache', first_token[int32 scalar])`` — the first generated
-    token is picked here, from the true last-prompt-position logits, so a
-    ``max_new_tokens=1`` request never needs a decode step at all.
-    """
-    total_len = next(
-        leaf.shape[1] for path, leaf in
-        jax.tree_util.tree_leaves_with_path(cache)
-        if _leaf_name(path) == "cached_key")
-    mini = init_cache(model, 1, total_len)
-    true_len = jnp.asarray(true_len, jnp.int32)
-
-    logits, upd = model.apply(
-        {"params": params, "cache": mini}, tokens[None, :],
-        mutable=["cache"])
-    mini = upd["cache"]
-    cap = jax.lax.dynamic_index_in_dim(
-        logits, true_len - 1, axis=1, keepdims=False)
-    first = _pick_tokens(cap, rng, temperature, top_k, top_p)[0]
-
-    slot = jnp.asarray(slot, jnp.int32)
-
-    def merge(path, big, small):
-        name = _leaf_name(path)
-        if name in _CURSOR_LEAVES:
-            return big.at[slot].set(true_len.astype(big.dtype))
-        return big.at[slot].set(small[0])
-
-    cache = jax.tree_util.tree_map_with_path(merge, cache, mini)
-    return cache, first
-
-
-def decode_step(model, params, cache, tokens, idx, temperature=0.0,
-                top_k=None, top_p=None, rng=None):
-    """One fixed-shape decode step over every slot.
-
-    ``tokens [S]`` is each slot's previously emitted token; ``idx [S]``
-    each slot's write cursor (the scheduler's host-side copy — see
-    :func:`_set_cursor_leaves`). Every slot computes (static shapes);
-    the scheduler simply ignores emissions from slots it knows are free.
-    Returns ``(cache', next_tokens [S])``.
-    """
-    cache = _set_cursor_leaves(cache, jnp.asarray(idx, jnp.int32))
-    logits, upd = model.apply(
-        {"params": params, "cache": cache}, tokens[:, None],
-        mutable=["cache"])
-    picked = _pick_tokens(logits[:, -1, :], rng, temperature, top_k, top_p)
-    return upd["cache"], picked
-
-
 def _fed_tokens(picked, feed):
     """Each row's input token of a token step: the host's where it gave
     one (``feed[:, 0] >= 0``), else the device's own pick of the step
@@ -325,77 +225,55 @@ def _fed_tokens(picked, feed):
     return jnp.where(feed[:, 0] >= 0, feed[:, 0], picked)
 
 
-def pack_step_feed(given, idx, tables=None):
+def pack_step_feed(given, idx, tables):
     """The host's part of a token step's input, ONE int32 array handed
     to the call as numpy (the call transfers it itself): column 0 the
     token ``given [S]`` a row starts from, -1 where the row feeds back
     the device's own pick; column 1 the cursors ``idx [S]``; after them
-    a paged engine's block ``tables [S, MB]``."""
-    import numpy as np
-
-    if tables is None:
-        tables = np.empty((len(idx), 0), np.int32)
+    the block ``tables [S, MB]``."""
     return pack_block_feed(given[:, None], idx, tables)
-
-
-@functools.lru_cache(maxsize=32)
-def slot_step_fns(model, temperature=0.0, top_k=None, top_p=None):
-    """(jitted prefill_into_slot, jitted decode_step) for one model +
-    sampling config, cache-donating, reused across engines. Every
-    engine program is the jit of a NAMED function: a trace's host
-    spans then read ``PjitFunction(slot_prefill)`` and the device's
-    ``XLA Modules`` line ``jit_slot_prefill``, where a lambda leaves
-    every program ``<lambda>``.
-
-    Compile-count contract (asserted in tests): the decode fn compiles
-    ONCE per (slots, total_len) cache shape; the prefill fn once per
-    bucket length. ``fn._cache_size()`` exposes the live program count —
-    serving.DecodeEngine surfaces both via ``compile_stats()``.
-
-    The decode fn is ``step(params, cache, picked [S], feed, key) ->
-    (cache', picked' [S])``: ``picked`` is the step before's own answer,
-    still on the device, and ``feed`` the host's part
-    (:func:`pack_step_feed`), so a step can be dispatched before the
-    one before it has been read.
-    """
-    def slot_prefill(params, cache, slot, tokens, true_len, key):
-        return prefill_into_slot(
-            model, params, cache, slot, tokens, true_len,
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
-
-    def slot_decode_step(params, cache, picked, feed, key):
-        return decode_step(
-            model, params, cache, _fed_tokens(picked, feed), feed[:, 1],
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
-
-    return (jax.jit(slot_prefill, donate_argnums=(1,)),
-            jax.jit(slot_decode_step, donate_argnums=(1,)))
 
 
 # -- paged-KV slot primitives (PR 8) -----------------------------------
 #
-# The paged siblings of ``prefill_into_slot``/``decode_step`` above,
-# for models built with ``kv_block_size > 0`` (models/decoder.py): K/V
+# For models built with ``kv_block_size > 0`` (models/decoder.py): K/V
 # lives in a shared block pool and each slot reaches its sequence
-# through a block-table row. The model's ``attn_impl`` field selects
-# the attention formulation (fused block-table kernel vs PR 8's gather
-# reference — ops/paged_attention.py); since flax Modules hash by
-# their fields, ``paged_step_fns``'s lru_cache keys distinct programs
-# per formulation automatically. Because the POOL is batch-independent
-# (only tables and cursors are per-row), prefill needs no mini cache +
-# scatter-merge at all: a batch-1 apply with the slot's table row and a
-# start cursor writes the tail's K/V straight into the slot's blocks —
-# which is also exactly how a PREFIX-CACHED admission prefills only the
-# un-shared tail of its prompt (start = shared prefix length, a block
-# multiple; the fused mid-sequence continuation branch reads the shared
-# prefix K/V through the table).
+# through a block-table row. Because the POOL is batch-independent
+# (only tables and cursors are per-row), prefill is a batch-1 apply
+# with the slot's table row and a start cursor, which writes the
+# tail's K/V straight into the slot's blocks — also exactly how a
+# PREFIX-CACHED admission prefills only the un-shared tail of its
+# prompt (start = shared prefix length, a block multiple; the fused
+# mid-sequence continuation branch reads the shared prefix K/V through
+# the table).
+#
+# The jitted wrappers donate the engine cache, so the scheduler's
+# steady-state loop updates the pool in place instead of copying it.
+# Every engine program is the jit of a NAMED function: a trace's host
+# spans then read ``PjitFunction(paged_prefill)`` and the device's
+# ``XLA Modules`` line ``jit_paged_prefill``, where a lambda leaves
+# every program ``<lambda>``.
 
 
 def _set_paged_leaves(cache, idx, tables):
     """Cache pytree with cursor leaves replaced by ``idx`` and
-    ``block_table`` leaves by ``tables`` — the paged extension of
-    :func:`_set_cursor_leaves`: the host scheduler is the authority on
-    both position AND block mapping, every call."""
+    ``block_table`` leaves by ``tables``: the host scheduler is the
+    authority on both position AND block mapping, every call.
+
+    A freed slot must NOT keep advancing its cursor while it idles, and
+    a re-admitted slot restarts at its new length. Overwriting the
+    cursors before each step makes the device cache's own increments
+    advisory, so an inactive slot (cursor 0, every table entry the
+    scratch block) just re-writes one scratch position in place.
+
+    This same discipline is what makes MID-FLIGHT EVICTION (PR 4:
+    cancel / deadline, serving.DecodeEngine._evict_expired) free: an
+    evicted request's slot is simply marked free on the host — no
+    device-side cleanup exists or is needed, because a freed slot's
+    stale K/V is unreachable once no table row names its blocks, and
+    neighbors never see it. Eviction therefore cannot perturb
+    concurrent sequences, which is why cancelled-neighbor outputs stay
+    bitwise-identical (tests/test_serving_lifecycle.py pins this)."""
     def repl(path, leaf):
         name = _leaf_name(path)
         if name in _CURSOR_LEAVES:
@@ -451,7 +329,7 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
     the private blocks the tail writes, then scratch (0) padding that
     absorbs bucket-pad writes.
 
-    Runs as ONE batch-1 apply against the SHARED pool — no mini cache:
+    Runs as ONE batch-1 apply against the SHARED pool:
     the pool leaves are batch-independent, so the slot's writes land in
     place and no other slot's blocks are touched. Returns
     ``(cache', first_token)`` with the first generated token picked
@@ -469,9 +347,14 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
 
 def paged_decode_step(model, params, cache, tokens, idx, tables,
                       temperature=0.0, top_k=None, top_p=None, rng=None):
-    """One fixed-shape decode step over every slot, paged: identical to
-    :func:`decode_step` except the host also supplies ``tables
-    [S, MB]`` — each slot's block-table row — alongside the cursors."""
+    """One fixed-shape decode step over every slot.
+
+    ``tokens [S]`` is each slot's previously emitted token, ``idx [S]``
+    each slot's write cursor and ``tables [S, MB]`` each slot's
+    block-table row (the scheduler's host-side copies — see
+    :func:`_set_paged_leaves`). Every slot computes (static shapes);
+    the scheduler simply ignores emissions from slots it knows are free.
+    Returns ``(cache', next_tokens [S])``."""
     cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
                               jnp.asarray(tables, jnp.int32))
     logits, upd = model.apply(
@@ -490,13 +373,21 @@ _paged_decode_step = paged_decode_step
 @functools.lru_cache(maxsize=32)
 def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     """(jitted paged prefill, jitted paged decode) for one paged model
-    + sampling config — the paged sibling of :func:`slot_step_fns`,
-    same compile-count contract: ONE decode program per engine config,
-    one prefill program per TAIL bucket (``start``/``tail_len`` are
-    traced scalars, so a warm prefix and a cold prompt of equal tail
-    bucket share a program). The decode fn takes the step before's
-    ``picked`` and the host's ``feed`` as :func:`slot_step_fns`'s does,
-    the block tables after the cursors in the one array."""
+    + sampling config, cache-donating, reused across engines.
+
+    Compile-count contract (asserted in tests): ONE decode program per
+    (slots, total_len) engine config, one prefill program per TAIL
+    bucket (``start``/``tail_len`` are traced scalars, so a warm prefix
+    and a cold prompt of equal tail bucket share a program).
+    ``fn._cache_size()`` exposes the live program count —
+    serving.DecodeEngine surfaces both via ``compile_stats()``.
+
+    The decode fn is ``step(params, cache, picked [S], feed, key) ->
+    (cache', picked' [S])``: ``picked`` is the step before's own answer,
+    still on the device, and ``feed`` the host's part
+    (:func:`pack_step_feed`: tokens, cursors, then the block tables in
+    the one array), so a step can be dispatched before the one before
+    it has been read."""
     def paged_prefill(params, cache, table_row, tokens, tail_len, start,
                       key):
         return paged_prefill_into_slot(
@@ -867,32 +758,6 @@ def speculative_step_fns(model, draft_model, k, temperature=0.0,
             top_k=top_k, top_p=top_p, rng=key)
 
     return jax.jit(spec_round, donate_argnums=(2, 3))
-
-
-@functools.lru_cache(maxsize=32)
-def speculative_probe_fns(model, draft_model, k, temperature=0.0,
-                          top_k=None, top_p=None):
-    """NON-donating (propose, verify) jits over the same bodies the
-    fused round composes — the measurement surface behind
-    ``DecodeEngine.measure_spec``: the hot loop runs one fused
-    program (per-op timing is invisible inside it), so the honest
-    draft-vs-verify attribution runs each half standalone at live
-    shapes, exactly the ``measure_attn`` pattern. Non-donating so a
-    probe can run against the engine's LIVE caches without consuming
-    them."""
-    import jax
-
-    def spec_propose(params, cache, last, idx, tables, key):
-        return paged_propose_tokens(
-            draft_model, params, cache, last, idx, tables, int(k),
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
-
-    def spec_verify(params, cache, tokens, idx, tables, key):
-        return paged_verify_step(
-            model, params, cache, tokens, idx, tables,
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
-
-    return jax.jit(spec_propose), jax.jit(spec_verify)
 
 
 def default_buckets(total_len, lo=8):

@@ -1,8 +1,8 @@
 """Shared metric-reading and report-formatting helpers.
 
 One home for the percentile and stage-printing code that was
-copy-pasted across ``bench.py``, ``scripts/profile_serving.py``,
-``scripts/profile_recovery.py``, and ``scripts/profile_fed.py``
+copy-pasted across ``bench.py``, ``scripts/profile_recovery.py``,
+and ``scripts/profile_fed.py``
 (each kept a private sample list and its own ``np.percentile`` /
 median / stage-table variant). Everything here READS the
 observability plane (``tracing.MetricsRegistry`` / ``Histogram`` /
@@ -32,27 +32,6 @@ def quantiles_ms(hist, pcts=(50, 95, 99)):
         out["p{:g}_ms".format(p)] = None if q is None \
             else round(q * 1e3, 3)
     return out
-
-
-#: the serving histograms every latency report reads, in report order:
-#: {report key: registry family}
-SERVING_HISTOGRAMS = (
-    ("latency", "tfos_serving_request_seconds"),
-    ("ttft", "tfos_serving_ttft_seconds"),
-    ("per_token", "tfos_serving_token_latency_seconds"),
-    ("decode_step", "tfos_serving_decode_step_seconds"),
-    ("queue_wait", "tfos_serving_queue_wait_seconds"),
-)
-
-
-def serving_quantiles(registry, pcts=(50, 95, 99)):
-    """Per-histogram latency quantiles from a serving engine's
-    registry: {latency, ttft, per_token, decode_step, queue_wait} ->
-    quantile dicts. The block ``bench.py serving_decode`` publishes and
-    ``scripts/profile_serving.py`` prints — read from the SAME
-    histograms ``GET /metrics`` renders."""
-    return {key: quantiles_ms(registry.get_histogram(family), pcts)
-            for key, family in SERVING_HISTOGRAMS}
 
 
 def stage_ms(timers):

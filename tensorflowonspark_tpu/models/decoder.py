@@ -36,28 +36,25 @@ copied whole into another layout and back around every call
 (ops/paged_attention.py, "Pool layout"). Writes scatter through the
 table (position ``p`` lands in pool row ``table[b, p // bs]`` at
 offset ``p % bs``, all heads of the token as one row); attention then
-runs one of two formulations selected by ``attn_impl`` (PR 11, both
-in ops/paged_attention.py):
+consumes the pool and the block table DIRECTLY
+(``ops.paged_attention.paged_attention`` with its default
+formulation: a Pallas kernel on TPU whose K/V index maps read the
+table, so per-step traffic scales with LIVE tokens, and a blockwise
+``fori_loop`` online-softmax formulation elsewhere). No transient
+``[B, L, N, D]`` materialization.
 
-- ``"fused"`` (the default) — paged attention consumes the pool and
-  the block table DIRECTLY: a Pallas kernel on TPU whose K/V index
-  maps read the table (per-step traffic scales with LIVE tokens), a
-  blockwise ``fori_loop`` online-softmax formulation elsewhere. No
-  transient ``[B, L, N, D]`` materialization.
-- ``"gather"`` — PR 8's XLA formulation, kept verbatim as the
-  reference oracle: gather the row's blocks back into logical order
-  and attend exactly as the contiguous path does (same shapes, same
-  mask, same einsums), so gather outputs are bitwise-identical to
-  contiguous ones whenever ``kv_block_size * table_width == the
-  contiguous cache length`` (serving.DecodeEngine enforces this).
-
-The two formulations compute the same visible set under the same
-scale; they differ only in float accumulation order (one softmax over
-the logical row vs the online recurrence), so the serving parity pin
-fused == gather == solo is TOKEN-level at temperature=0
+The paged and the contiguous paths compute the same visible set under
+the same scale; they differ only in float accumulation order (the
+online recurrence vs one softmax over the logical row), so the serving
+parity pin paged == solo is TOKEN-level at temperature=0
 (tests/test_paged_kv.py). Block allocation, sharing, and reclamation
 are HOST decisions (paging.BlockPool via the engine); the module just
 writes and attends where the table says.
+
+With ``kv_block_size == 0`` the cache is contiguous, ``[B, max_len, N,
+D]`` per row: what ``generation.generate`` / ``generate_jit`` run on,
+and so the solo oracle of every bitwise pin. serving.DecodeEngine is
+paged only.
 """
 
 import functools
@@ -121,22 +118,15 @@ class PagedKV(object):
         """Logical positions ``[B, s]`` this call's tokens sit at."""
         return self.index.value[:, None] + jnp.arange(s)[None, :]
 
-    def attend(self, q, k, v, pos, visible=None, attn_impl="fused"):
+    def attend(self, q, k, v, pos, visible=None):
         """Write ``k``/``v`` ``[B, s, kv_heads, D]`` at ``pos`` through
         the block table, advance the cursor by ``s``, and attend ``q
         [B, s, heads, D]`` through the table: query ``i`` of row ``b``
         sees every key position ``<= visible[b, i]`` (``pos`` itself
-        when None: causal). The fused formulation (default) streams the
-        row's LIVE blocks through an online softmax; ``"gather"``
-        materializes the logical [B, L] view and attends exactly like a
-        contiguous cache (same mask, same einsums — the PR 8 reference
-        oracle)."""
+        when None: causal), streaming the row's LIVE blocks through an
+        online softmax."""
         import importlib
 
-        if attn_impl not in ("fused", "gather"):
-            raise ValueError(
-                "attn_impl must be 'fused' or 'gather', got "
-                "{!r}".format(attn_impl))
         pa = importlib.import_module(
             "tensorflowonspark_tpu.ops.paged_attention")
         b, s = pos.shape
@@ -172,8 +162,7 @@ class PagedKV(object):
         self.index.value = self.index.value + s
         return pa.paged_attention(
             q, pk, pv, table, pos if visible is None else visible,
-            scale=q.shape[-1] ** -0.5,
-            impl=None if attn_impl == "fused" else "gather",
+            scale=q.shape[-1] ** -0.5, impl=None,
             k_scale=ksc, v_scale=vsc)
 
 
@@ -195,16 +184,12 @@ class CausalSelfAttention(nn.Module):
     num_heads: int
     decode: bool = False
     #: paged KV (PR 8): block size in tokens; 0 = contiguous per-row
-    #: cache (the pre-paged layout, kept for comparison benches and the
-    #: bitwise three-way pin)
+    #: cache (what generation.generate runs on: the solo oracle of the
+    #: bitwise pins)
     kv_block_size: int = 0
     #: pool rows when paged (INCLUDING the scratch block row 0 that
     #: absorbs pad-position writes — see paging.py)
     kv_blocks: int = 0
-    #: paged attention formulation (PR 11): "fused" consumes the block
-    #: table directly (Pallas on TPU, blockwise lax elsewhere);
-    #: "gather" materializes the logical view (PR 8's reference path)
-    attn_impl: str = "fused"
     #: KV pool storage (PR 15; paged only): "" stores K/V at the
     #: compute dtype; "int8" stores symmetric per-head absmax codes
     #: with float32 scales per token row of each block ("key_scale" /
@@ -265,8 +250,7 @@ class CausalSelfAttention(nn.Module):
                 # attend through the table (PagedKV.attend). s==1 is a
                 # decode step; s>1 a fused (possibly mid-sequence,
                 # prefix-cached) prefill.
-                ctx = pool.attend(q, k, v, pool.positions(s),
-                                  attn_impl=self.attn_impl)
+                ctx = pool.attend(q, k, v, pool.positions(s))
             elif is_initialized and s == 1:
                 # one token per step against the cache prefix
                 idx = cache_index.value
@@ -297,9 +281,8 @@ class CausalSelfAttention(nn.Module):
                 # engine's pinned configs (tests/test_decode_engine.py);
                 # across arbitrary chunk shapes XLA's accumulation
                 # order may differ in the last float bit. A fresh cache
-                # (idx 0) is plain prompt prefill
-                # (generation.prefill_into_slot's mini cache); an
-                # advanced cache gets correct CHUNKED continuation
+                # (idx 0) is plain prompt prefill (generation.generate);
+                # an advanced cache gets correct CHUNKED continuation
                 # rather than the silent restart-at-zero a position-0
                 # assumption would produce.
                 idx = cache_index.value
@@ -338,7 +321,6 @@ class DecoderBlock(nn.Module):
     decode: bool = False
     kv_block_size: int = 0
     kv_blocks: int = 0
-    attn_impl: str = "fused"
     kv_dtype: str = ""
 
     @nn.compact
@@ -347,7 +329,6 @@ class DecoderBlock(nn.Module):
         y = CausalSelfAttention(self.num_heads, decode=self.decode,
                                 kv_block_size=self.kv_block_size,
                                 kv_blocks=self.kv_blocks,
-                                attn_impl=self.attn_impl,
                                 kv_dtype=self.kv_dtype,
                                 name="attn")(y)
         x = x + y
@@ -380,11 +361,6 @@ class DecoderLM(nn.Module):
     #: CausalSelfAttention and docs/serving.md.
     kv_block_size: int = 0
     kv_blocks: int = 0
-    #: paged attention formulation (PR 11): "fused" (block-table
-    #: kernel) or "gather" (PR 8's materialized-view reference);
-    #: ignored unless kv_block_size > 0. The engine's ``attn_impl``
-    #: knob clones the model with this set.
-    attn_impl: str = "fused"
     #: KV pool storage (PR 15): "" = compute dtype, "int8" = quantized
     #: codes + per-head scales (see CausalSelfAttention.kv_dtype);
     #: ignored unless kv_block_size > 0. The engine's ``kv_dtype``
@@ -447,7 +423,6 @@ class DecoderLM(nn.Module):
             x = DecoderBlock(self.num_heads, decode=self.decode,
                              kv_block_size=self.kv_block_size,
                              kv_blocks=self.kv_blocks,
-                             attn_impl=self.attn_impl,
                              kv_dtype=self.kv_dtype,
                              name="block_%d" % i)(x)
         x = nn.LayerNorm(name="ln_f")(x)

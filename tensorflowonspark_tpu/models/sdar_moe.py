@@ -108,7 +108,6 @@ class BlockAttention(nn.Module):
     decode: bool = False
     kv_block_size: int = 0
     kv_blocks: int = 0
-    attn_impl: str = "fused"
 
     @nn.compact
     def __call__(self, x):
@@ -134,8 +133,7 @@ class BlockAttention(nn.Module):
                 ctx = pool.attend(
                     rope(q, pos, self.rope_theta),
                     rope(k, pos, self.rope_theta), v, pos,
-                    visible=block_end(pos, self.block_len),
-                    attn_impl=self.attn_impl)
+                    visible=block_end(pos, self.block_len))
         else:
             pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
             q = rope(q, pos, self.rope_theta)
@@ -231,7 +229,6 @@ class SdarMoeLM(nn.Module):
     #: model with these set, as for DecoderLM
     kv_block_size: int = 0
     kv_blocks: int = 0
-    attn_impl: str = "fused"
 
     @nn.compact
     def __call__(self, tokens, head=True):
@@ -247,8 +244,7 @@ class SdarMoeLM(nn.Module):
             head_dim=self.head_dim, block_len=self.block_len,
             rope_theta=self.rope_theta, rms_eps=self.rms_eps,
             dtype=self.dtype, decode=self.decode,
-            kv_block_size=self.kv_block_size, kv_blocks=self.kv_blocks,
-            attn_impl=self.attn_impl)
+            kv_block_size=self.kv_block_size, kv_blocks=self.kv_blocks)
         moe = dict(
             num_experts=self.num_experts,
             experts_per_tok=self.experts_per_tok,

@@ -486,8 +486,8 @@ def serve_replica(spec):
     the target executor (PR 13): the paper's executor-role map_fun
     applied to the serving plane. The closure builds the replica
     IN the executor process — ``fleet.ServingNode``: DecodeEngine
-    (spawn config rides ``spec["engine_kw"]`` — slots, paging,
-    ``attn_impl``; the multi-tenant QoS policy — tenant weights,
+    (spawn config rides ``spec["engine_kw"]`` — slots,
+    paging; the multi-tenant QoS policy — tenant weights,
     priority classes, token quotas — rides ``spec["qos"]``, applied as
     the engine's ``qos_policy`` so every executor-hosted replica
     enforces the same tenant contract the router does, PR 18),

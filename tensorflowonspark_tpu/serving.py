@@ -80,6 +80,14 @@ _STREAM_DONE = object()
 #: default replica-identity source (see DecodeEngine.replica_id)
 _ENGINE_IDS = itertools.count()
 
+#: the one refusal of an engine without a block pool, whichever way it
+#: was asked for (``kv_block_size=0``, or a model without the fields)
+_PAGED_ONLY = (
+    "DecodeEngine reaches K and V through the paged block pool only: it "
+    "needs kv_block_size > 0 (got {}) and a model with the paged-KV "
+    "fields kv_block_size/kv_blocks/kv_dtype (got {}); "
+    "generation.generate is the contiguous-cache path")
+
 
 class Retriable(RuntimeError):
     """The request failed for a TRANSIENT serving-side reason — shed at
@@ -119,8 +127,8 @@ class EngineFailed(Retriable):
 
 class SpliceRejected(RuntimeError):
     """A shipped KV prefix was DELIBERATELY refused (PR 17): fenced
-    source epoch, mismatched pool geometry/dtype, pool pressure, or an
-    unpaged target. NOT retriable-as-is — the decode side answers 409
+    source epoch, mismatched pool geometry/dtype, or pool pressure.
+    NOT retriable-as-is — the decode side answers 409
     and the prefill side falls back to letting the decode replica
     re-prefill cold. ``reason`` is the bounded label the
     ``tfos_splice_failures_total{reason=...}`` counter carries."""
@@ -515,25 +523,34 @@ class DedupWindow(object):
 
 
 class DecodeEngine(object):
-    """Continuous-batching decode engine over a slot-structured KV cache.
+    """Continuous-batching decode engine over a paged KV block pool.
 
     The serving answer to ``generate_jit``'s run-to-completion shape
     (and the window ``_Batcher``'s group-by-identical-signature shape):
-    a persistent scheduler thread owns ONE ``[slots, total_len]`` KV
-    cache and runs a fixed-shape decode step over it forever. Each of
-    the S slots independently holds one in-flight sequence at its own
-    position; requests are admitted into freed slots at decode-step
-    boundaries (no run-to-max groups), exit individually on EOS or
-    length, and prompts prefill through shape BUCKETS (padded to the
-    next bucket length), so the whole engine compiles
+    a persistent scheduler thread owns ONE pool of KV blocks
+    (``[kv_blocks + 1, kv_block_size, heads * head_dim]`` per layer, row
+    0 the scratch block) and runs a fixed-shape decode step over it
+    forever. Each of the S slots independently holds one in-flight
+    sequence at its own position and reaches its K and V through its row
+    of a host-authoritative block table, which the step takes as an
+    argument: a sequence holds ``ceil(len / kv_block_size)`` blocks as it
+    grows, not ``total_len`` rows up front, and attention reads the pool
+    through the table (``ops/paged_attention.py``: the Pallas kernel on
+    the TPU, its blockwise ``lax`` form elsewhere). Requests are
+    admitted into freed slots at decode-step boundaries (no run-to-max
+    groups), exit individually on EOS or length, and prompts prefill
+    through shape BUCKETS (padded to the next bucket length), so the
+    whole engine compiles
 
         1 decode program per (slots, total_len) config
       + 1 prefill program per bucket
 
     instead of one whole-generation program per (batch, prompt_len,
     max_new) request signature. At ``temperature=0`` each request's
-    output is bitwise-identical to a solo ``generation.generate`` call
-    (pinned in tests/test_decode_engine.py). A token step's next input
+    output is bitwise-identical to a solo ``generation.generate`` call,
+    which runs on the contiguous ``[batch, total_len]`` cache and is the
+    oracle of every such pin (tests/test_decode_engine.py,
+    tests/test_paged_kv.py). A token step's next input
     is the device's own output, so the loop keeps ONE step in flight:
     it dispatches step n+1 before it reads step n, and reads, delivers
     and schedules while the device computes (docs/serving.md, "One step
@@ -541,10 +558,12 @@ class DecodeEngine(object):
     block's unmasking, a speculative round's acceptance) reads first.
 
     Args:
-      model: decode-mode DecoderLM-family flax module (``decode=True``).
+      model: decode-mode DecoderLM-family flax module (``decode=True``)
+        with the paged fields (``kv_block_size``, ``kv_blocks``,
+        ``kv_dtype``); the engine serves a clone re-speced for its pool.
       params: its parameters.
       slots: concurrent sequences (S). Throughput lever.
-      total_len: cache length per slot; every request needs
+      total_len: longest sequence a slot may hold; every request needs
         ``len(prompt) + max_new_tokens <= total_len``. Defaults to
         ``model.max_len``.
       buckets: ascending prefill bucket lengths (default: powers of two
@@ -567,22 +586,19 @@ class DecodeEngine(object):
         it, sustained overload grows the queue without limit while
         every client times out and abandons work the engine still
         decodes to completion.
-      kv_block_size: paged-KV block size in tokens (PR 8). None (the
-        default) auto-picks the largest divisor of ``total_len`` up to
-        16; 0 selects the pre-paged CONTIGUOUS per-slot cache (kept
-        for comparison benches and the three-way bitwise pin). Paged,
-        K/V lives in a shared block pool and a sequence consumes
-        ``ceil(len / block_size)`` blocks as it grows instead of a
-        ``total_len`` region up front — memory stops capping
-        concurrency at ``slots = pool_bytes / max_len_bytes``.
-      kv_blocks: pool size in blocks (paged only). Default:
-        ``slots * total_len / kv_block_size`` — capacity parity with
-        the contiguous layout; shrink it to serve more slots from the
-        same KV budget (admission gates on block availability, and a
+      kv_block_size: KV block size in tokens (PR 8). None (the default)
+        picks the largest divisor of ``total_len`` up to 16; it must
+        divide ``total_len``. 0 is refused: the engine has no
+        contiguous per-slot cache (``generation.generate`` is that
+        path).
+      kv_blocks: pool size in blocks. Default:
+        ``slots * total_len / kv_block_size`` — room for every slot at
+        full length; shrink it to serve more slots from the same KV
+        budget (admission gates on block availability, and a
         sequence outgrowing the pool preempts the youngest admission,
         which resumes seamlessly when blocks free).
       prefix_cache: share resident prefix blocks across requests
-        (paged only; default True). Full blocks of every prompt are
+        (default True). Full blocks of every prompt are
         registered under their exact token chain at admission, and
         full blocks DECODE fills are registered as the sequence grows
         (PR 11: generated-prefix registration) — so a multi-turn
@@ -594,16 +610,7 @@ class DecodeEngine(object):
         Released registered blocks are RETAINED (LRU-evicted under
         pressure), so repeat system prompts — and conversation
         histories — keep hitting.
-      attn_impl: paged attention formulation (PR 11; paged only).
-        None (the default) selects ``"fused"`` — attention consumes
-        the block table directly (Pallas kernel on TPU, blockwise
-        ``lax`` elsewhere; per-step bandwidth scales with LIVE tokens,
-        not table width). ``"gather"`` keeps PR 8's materialize-the-
-        logical-view formulation as the reference oracle; the two are
-        pinned token-identical at temperature=0. Surfaced through
-        ``load_stats()`` / ``/healthz`` / the fleet BEAT payload so
-        routers can tell kernel configs apart across a fleet.
-      speculate_k: draft-model speculation window (PR 15; paged only;
+      speculate_k: draft-model speculation window (PR 15;
         None = off, else >= 2). Each scheduling round a reduced-depth
         weight-tied draft proposes k tokens (one scanned program) and
         the target verifies the whole window in ONE fused apply —
@@ -626,7 +633,7 @@ class DecodeEngine(object):
         head (``generation.draft_params`` — no separate weights, no
         training pipeline), so acceptance measures how much of the
         target's choice the early layers already decide.
-      kv_dtype: KV pool storage (PR 15; paged only). None (or
+      kv_dtype: KV pool storage (PR 15). None (or
         "fp32"/"float32") keeps the compute dtype; "int8" stores
         symmetric per-head absmax codes with float32 scales per token
         row of each block, quantizing at write time and dequantizing
@@ -656,7 +663,7 @@ class DecodeEngine(object):
                  eos_token=None, rng=None, counters=None, timers=None,
                  max_queue=1024, metrics=None, flight=None,
                  replica_id=None, kv_block_size=None, kv_blocks=None,
-                 prefix_cache=True, attn_impl=None, speculate_k=None,
+                 prefix_cache=True, speculate_k=None,
                  draft_layers=None, kv_dtype=None, tier=None,
                  qos_policy=None):
         import jax
@@ -681,7 +688,7 @@ class DecodeEngine(object):
             top_p=top_p, eos_token=eos_token, rng=rng,
             max_queue=max_queue, replica_id=self.replica_id,
             kv_block_size=kv_block_size, kv_blocks=kv_blocks,
-            prefix_cache=prefix_cache, attn_impl=attn_impl,
+            prefix_cache=prefix_cache,
             speculate_k=speculate_k, draft_layers=draft_layers,
             kv_dtype=kv_dtype, tier=tier, qos_policy=qos_policy)
         self._generation = generation
@@ -727,8 +734,7 @@ class DecodeEngine(object):
             else tracing.StageTimers("engine")
         #: the engine's observability plane (PR 5): one MetricsRegistry
         #: carrying its counters, stage timers, and latency histograms
-        #: — ModelServer's GET /metrics renders it, bench.py and
-        #: scripts/profile_serving.py read p50/p95/p99 from it.
+        #: — ModelServer's GET /metrics renders it.
         #: Registration is idempotent, so a respawned engine re-adds
         #: the same shared objects under the same family names.
         self.metrics = metrics if metrics is not None \
@@ -812,11 +818,24 @@ class DecodeEngine(object):
         #: diffusion over blocks (models/sdar_moe.py). Read off the
         #: MODEL: there is no engine option for it.
         self._block_len = int(getattr(model, "block_len", 0) or 0)
+        # -- paged KV setup (PR 8) ------------------------------------
+        # kv_block_size: None = the largest divisor of total_len up to
+        # 16 (the divisibility makes the paged logical view exactly
+        # total_len long, the bitwise-parity condition). The block pool
+        # is the only way this engine reaches K and V.
+        if kv_block_size is None:
+            kv_block_size = next(b for b in range(16, 0, -1)
+                                 if total_len % b == 0)
+        self.kv_block_size = int(kv_block_size)
+        if self.kv_block_size < 1 or not (
+                hasattr(model, "kv_block_size") and hasattr(model, "clone")):
+            raise ValueError(_PAGED_ONLY.format(
+                self.kv_block_size, type(model).__name__))
         if self._block_len:
             self._check_block_mode(
                 model, total_len, temperature=temperature, top_k=top_k,
                 top_p=top_p, eos_token=eos_token,
-                kv_block_size=kv_block_size, attn_impl=attn_impl,
+                kv_block_size=self.kv_block_size,
                 speculate_k=speculate_k, kv_dtype=kv_dtype, tier=tier)
             # denoising passes write a block's K/V before it is final,
             # so no block of such a sequence is ever registered for
@@ -824,34 +843,6 @@ class DecodeEngine(object):
             prefix_cache = False
         norm_top_k = None if top_k is None else int(top_k)
         norm_top_p = None if top_p is None else float(top_p)
-        # -- paged KV setup (PR 8) ------------------------------------
-        # kv_block_size: None = auto (largest divisor of total_len up
-        # to 16 — the divisibility makes the paged logical view exactly
-        # total_len long, the bitwise-parity condition); 0 = the
-        # pre-paged contiguous per-slot cache (kept for comparison
-        # benches and the three-way bitwise pin).
-        kv_auto = kv_block_size is None
-        if kv_auto:
-            kv_block_size = next(b for b in range(16, 0, -1)
-                                 if total_len % b == 0)
-            if not (hasattr(model, "kv_block_size")
-                    and hasattr(model, "clone")):
-                # AUTO mode must not break model types that predate the
-                # paged fields — they keep the contiguous path they had;
-                # only an EXPLICIT kv_block_size>0 hard-errors below
-                logger.info(
-                    "model %s has no paged-KV fields; serving with the "
-                    "contiguous per-slot cache",
-                    type(model).__name__)
-                kv_block_size = 0
-        self.kv_block_size = int(kv_block_size)
-        self._paged = self.kv_block_size > 0
-        if self.kv_block_size % (self._block_len or 1):
-            # a block must never straddle two KV blocks: growth looks
-            # one position ahead and a commit moves a whole block
-            raise ValueError(
-                "block_len {} must divide the KV block size {}".format(
-                    self._block_len, self.kv_block_size))
         # int8 KV knob (PR 15): None / "fp32" / "float32" keep the
         # compute-dtype pool; "int8" stores quantized codes + per-head
         # scales (models/decoder.py) and halves+ per-step KV bandwidth
@@ -871,139 +862,92 @@ class DecodeEngine(object):
         if speculate_k is None and draft_layers is not None:
             raise ValueError("draft_layers needs speculate_k")
         self._spec_k = 0 if speculate_k is None else int(speculate_k)
-        if self._paged:
-            if total_len % self.kv_block_size:
-                raise ValueError(
-                    "kv_block_size {} must divide total_len {} (the "
-                    "paged logical view must equal the contiguous "
-                    "cache length for bitwise parity)".format(
-                        self.kv_block_size, total_len))
-            self._blocks_per_slot = total_len // self.kv_block_size
-            # pool default: capacity parity with the contiguous layout
-            # (slots x total_len tokens) — shrink kv_blocks to trade
-            # memory for admission pressure (paging makes short
-            # sequences stop paying max_len worth of blocks)
-            self.kv_blocks = int(kv_blocks) if kv_blocks is not None \
-                else self.slots * self._blocks_per_slot
-            if self.kv_blocks < 1:
-                raise ValueError("kv_blocks must be >= 1, got {}".format(
-                    self.kv_blocks))
-            self.prefix_cache = bool(prefix_cache)
-            # attention formulation (PR 11): fused by default — the
-            # block-table kernel whose per-step bandwidth scales with
-            # live tokens; "gather" keeps PR 8's materialized-view
-            # code as the reference oracle (pinned token-identical)
-            if attn_impl is None:
-                attn_impl = "fused"
-            if attn_impl not in ("fused", "gather"):
-                raise ValueError(
-                    "attn_impl must be 'fused' or 'gather', got "
-                    "{!r}".format(attn_impl))
-            self.attn_impl = attn_impl
-            self._pool = paging.BlockPool(
-                self.kv_blocks, self.kv_block_size,
-                kv_dtype="int8" if self._kv_quant else "float32")
-            self._last_prefix_evictions = 0
-            self._last_prefix_hits = 0
-            self._last_prefix_misses = 0
-            self._last_generated_registered = 0
-            self._last_generated_hits = 0
-            #: (head handle, available) when the queue head last failed
-            #: the block gate — skips re-planning it until the pool
-            #: changes (see the admission scan)
-            self._head_block_memo = None
-            clone_kw = dict(kv_block_size=self.kv_block_size,
-                            kv_blocks=self.kv_blocks + 1,
-                            attn_impl=self.attn_impl)
-            if self._kv_quant:
-                clone_kw["kv_dtype"] = "int8"
-            try:
-                # the served model is the caller's, re-speced for the
-                # pool (+1 device row: the scratch block pad writes
-                # land in). Params are layout-identical — only the
-                # cache collection's structure changes.
-                model = model.clone(**clone_kw)
-            except TypeError:
-                raise ValueError(
-                    "model {} does not support paged KV (no "
-                    "kv_block_size/kv_blocks/attn_impl{} fields); pass "
-                    "kv_block_size=0 for the contiguous cache".format(
-                        type(model).__name__,
-                        "/kv_dtype" if self._kv_quant else ""))
-            self._model = model
-            if self._block_len:
-                self._prefill_fn, self._decode_fn = \
-                    generation.paged_block_fns(model)
-            else:
-                self._prefill_fn, self._decode_fn = \
-                    generation.paged_step_fns(
-                        model, self._temperature, norm_top_k, norm_top_p)
-            if self._spec_k:
-                # draft-model speculation (PR 15): a reduced-depth,
-                # weight-TIED clone of the served model proposes
-                # speculate_k tokens per round; the target verifies
-                # them in one fused multi-token apply. The draft keeps
-                # its own (smaller) pool pytree but shares the host
-                # block tables and cursors, so ONE BlockPool governs
-                # both and every target write has a mirrored draft
-                # write — which is what keeps prefix-cache hits valid
-                # against the draft pool too.
-                n_layers = getattr(model, "num_layers", None)
-                if n_layers is None:
-                    raise ValueError(
-                        "speculate_k needs a model with a num_layers "
-                        "field to derive a reduced-depth draft; {} "
-                        "has none".format(type(model).__name__))
-                if draft_layers is None:
-                    draft_layers = max(1, int(n_layers) // 2)
-                draft_layers = int(draft_layers)
-                if not 1 <= draft_layers <= int(n_layers):
-                    raise ValueError(
-                        "draft_layers must be in [1, num_layers={}], "
-                        "got {}".format(n_layers, draft_layers))
-                self.draft_layers = draft_layers
-                draft_model = model.clone(num_layers=draft_layers)
-                self._draft_model = draft_model
-                self._draft_params = generation.draft_params(
-                    params, draft_layers)
-                self._round_fn = generation.speculative_step_fns(
-                    model, draft_model, self._spec_k,
-                    self._temperature, norm_top_k, norm_top_p)
-                # measure_spec's standalone halves (lazy-compiled,
-                # non-donating): the hot loop runs ONE fused program
-                self._spec_probe_fns = generation.speculative_probe_fns(
-                    model, draft_model, self._spec_k,
-                    self._temperature, norm_top_k, norm_top_p)
-                self._draft_prefill_fn = generation.paged_step_fns(
-                    draft_model, self._temperature, norm_top_k,
-                    norm_top_p)[0]
-            else:
-                self.draft_layers = 0
+        if total_len % self.kv_block_size:
+            raise ValueError(
+                "kv_block_size {} must divide total_len {} (the "
+                "paged logical view must equal the contiguous "
+                "cache length for bitwise parity)".format(
+                    self.kv_block_size, total_len))
+        self._blocks_per_slot = total_len // self.kv_block_size
+        # pool default: room for every slot at full length (slots x
+        # total_len tokens) — shrink kv_blocks to trade memory for
+        # admission pressure (paging makes short sequences stop paying
+        # max_len worth of blocks)
+        self.kv_blocks = int(kv_blocks) if kv_blocks is not None \
+            else self.slots * self._blocks_per_slot
+        if self.kv_blocks < 1:
+            raise ValueError("kv_blocks must be >= 1, got {}".format(
+                self.kv_blocks))
+        self.prefix_cache = bool(prefix_cache)
+        self._pool = paging.BlockPool(
+            self.kv_blocks, self.kv_block_size,
+            kv_dtype="int8" if self._kv_quant else "float32")
+        self._last_prefix_evictions = 0
+        self._last_prefix_hits = 0
+        self._last_prefix_misses = 0
+        self._last_generated_registered = 0
+        self._last_generated_hits = 0
+        #: (head handle, available) when the queue head last failed
+        #: the block gate — skips re-planning it until the pool
+        #: changes (see the admission scan)
+        self._head_block_memo = None
+        clone_kw = dict(kv_block_size=self.kv_block_size,
+                        kv_blocks=self.kv_blocks + 1)
+        if self._kv_quant:
+            clone_kw["kv_dtype"] = "int8"
+        try:
+            # the served model is the caller's, re-speced for the
+            # pool (+1 device row: the scratch block pad writes
+            # land in). Params are layout-identical — only the
+            # cache collection's structure changes.
+            model = model.clone(**clone_kw)
+        except TypeError:
+            raise ValueError(_PAGED_ONLY.format(
+                self.kv_block_size, type(model).__name__))
+        self._model = model
+        if self._block_len:
+            self._prefill_fn, self._decode_fn = \
+                generation.paged_block_fns(model)
         else:
-            if kv_blocks is not None:
+            self._prefill_fn, self._decode_fn = \
+                generation.paged_step_fns(
+                    model, self._temperature, norm_top_k, norm_top_p)
+        if self._spec_k:
+            # draft-model speculation (PR 15): a reduced-depth,
+            # weight-TIED clone of the served model proposes
+            # speculate_k tokens per round; the target verifies
+            # them in one fused multi-token apply. The draft keeps
+            # its own (smaller) pool pytree but shares the host
+            # block tables and cursors, so ONE BlockPool governs
+            # both and every target write has a mirrored draft
+            # write — which is what keeps prefix-cache hits valid
+            # against the draft pool too.
+            n_layers = getattr(model, "num_layers", None)
+            if n_layers is None:
                 raise ValueError(
-                    "kv_blocks needs a paged engine (kv_block_size>0)")
-            if attn_impl is not None:
+                    "speculate_k needs a model with a num_layers "
+                    "field to derive a reduced-depth draft; {} "
+                    "has none".format(type(model).__name__))
+            if draft_layers is None:
+                draft_layers = max(1, int(n_layers) // 2)
+            draft_layers = int(draft_layers)
+            if not 1 <= draft_layers <= int(n_layers):
                 raise ValueError(
-                    "attn_impl needs a paged engine (kv_block_size>0)")
-            if self._kv_quant:
-                raise ValueError(
-                    "kv_dtype='int8' needs a paged engine "
-                    "(kv_block_size>0): quantized KV lives in the "
-                    "block pool")
-            if self._spec_k:
-                raise ValueError(
-                    "speculate_k needs a paged engine "
-                    "(kv_block_size>0): the fused verify writes "
-                    "through the block tables' scratch routing")
-            self.kv_blocks = 0
-            self.prefix_cache = False
-            self.attn_impl = "contiguous"
+                    "draft_layers must be in [1, num_layers={}], "
+                    "got {}".format(n_layers, draft_layers))
+            self.draft_layers = draft_layers
+            draft_model = model.clone(num_layers=draft_layers)
+            self._draft_model = draft_model
+            self._draft_params = generation.draft_params(
+                params, draft_layers)
+            self._round_fn = generation.speculative_step_fns(
+                model, draft_model, self._spec_k,
+                self._temperature, norm_top_k, norm_top_p)
+            self._draft_prefill_fn = generation.paged_step_fns(
+                draft_model, self._temperature, norm_top_k,
+                norm_top_p)[0]
+        else:
             self.draft_layers = 0
-            self._pool = None
-            self._model = model
-            self._prefill_fn, self._decode_fn = generation.slot_step_fns(
-                model, self._temperature, norm_top_k, norm_top_p)
         self._key = rng if rng is not None else jax.random.PRNGKey(0)
         self._queue = collections.deque()
         # KV ship/splice jobs (PR 17): export and import must run on
@@ -1053,24 +997,21 @@ class DecodeEngine(object):
         # start: an engine that never runs ahead reads 0, not absent
         self.counters.inc("steps_dispatched_ahead", 0)
         self.counters.inc("tokens_dropped_in_flight", 0)
-        if self._paged:
-            # host-authoritative block tables: row s mirrors
-            # _slot_blocks[s] padded with scratch (0). A freed slot's
-            # row resets to scratch AND its cursor to 0, so the idle
-            # slot's per-step write lands in the scratch block instead
-            # of whatever its released blocks became.
-            self._slot_blocks = [[] for _ in range(self.slots)]
-            self._tables = np.zeros(
-                (self.slots, self._blocks_per_slot), np.int32)
-            self._admit_seq = itertools.count()
-            self._slot_seq = [0] * self.slots
-            # generated-prefix registration cursor (PR 11): how many
-            # leading FULL blocks of each slot's sequence have been
-            # published to the prefix registry — admission seeds it,
-            # boundary crossings and completion advance it
-            self._slot_registered = [0] * self.slots
-            self._attn_probe = None  # measure_attn's cached jit
-            self._dequant_probe = None  # measure_dequant's cached jit
+        # host-authoritative block tables: row s mirrors
+        # _slot_blocks[s] padded with scratch (0). A freed slot's
+        # row resets to scratch AND its cursor to 0, so the idle
+        # slot's per-step write lands in the scratch block instead
+        # of whatever its released blocks became.
+        self._slot_blocks = [[] for _ in range(self.slots)]
+        self._tables = np.zeros(
+            (self.slots, self._blocks_per_slot), np.int32)
+        self._admit_seq = itertools.count()
+        self._slot_seq = [0] * self.slots
+        # generated-prefix registration cursor (PR 11): how many
+        # leading FULL blocks of each slot's sequence have been
+        # published to the prefix registry — admission seeds it,
+        # boundary crossings and completion advance it
+        self._slot_registered = [0] * self.slots
         if self._block_len:
             # each slot's current block, the host's: its tokens, which
             # positions are still masked, at which pass of the block
@@ -1107,8 +1048,8 @@ class DecodeEngine(object):
 
     @staticmethod
     def _check_block_mode(model, total_len, temperature, top_k, top_p,
-                          eos_token, kv_block_size, attn_impl, speculate_k,
-                          kv_dtype, tier):
+                          eos_token, kv_block_size, speculate_k, kv_dtype,
+                          tier):
         """Refuse, with the reason, what an engine that steps by blocks
         does not do (docs/serving.md, "Stepping by blocks")."""
         b = int(model.block_len)
@@ -1122,17 +1063,12 @@ class DecodeEngine(object):
             why = "a pass already yields several tokens: no speculate_k"
         elif kv_dtype is not None:
             why = "its K/V pool is stored at the model's dtype: no kv_dtype"
-        elif kv_block_size == 0:
-            why = "it decodes through the paged cache only: no " \
-                  "kv_block_size=0"
-        elif attn_impl not in (None, "fused"):
-            why = "it attends through the fused formulation only: no " \
-                  "attn_impl={!r}".format(attn_impl)
         elif tier != "mixed":
             why = "it neither ships nor adopts K/V blocks: no " \
                   "tier={!r}".format(tier)
-        elif total_len % b or (kv_block_size or b) % b:
-            # (an auto-picked KV block size is checked once it is known)
+        elif total_len % b or kv_block_size % b:
+            # a block must never straddle two KV blocks: growth looks
+            # one position ahead and a commit moves a whole block
             why = "block_len {} must divide total_len {} and the KV " \
                   "block size".format(b, total_len)
         elif b % int(model.denoise_steps):
@@ -1174,14 +1110,13 @@ class DecodeEngine(object):
             raise ValueError(
                 "prompt {} + max_new_tokens {} exceeds total_len {}".format(
                     len(prompt), max_new, self.total_len))
-        if self._paged:
-            need = self._pool.blocks_for(len(prompt) + max_new)
-            if need > self.kv_blocks:
-                # permanent infeasibility, not load: the request's
-                # worst case can never fit the pool even running alone
-                raise ValueError(
-                    "request needs up to {} KV blocks but the pool has "
-                    "{} (kv_blocks)".format(need, self.kv_blocks))
+        need = self._pool.blocks_for(len(prompt) + max_new)
+        if need > self.kv_blocks:
+            # permanent infeasibility, not load: the request's
+            # worst case can never fit the pool even running alone
+            raise ValueError(
+                "request needs up to {} KV blocks but the pool has "
+                "{} (kv_blocks)".format(need, self.kv_blocks))
         return prompt, max_new
 
     def submit(self, prompt, max_new_tokens, deadline_s=None,
@@ -1265,7 +1200,7 @@ class DecodeEngine(object):
                 remaining.append(owed)
         wait = (len(self._queue) + extra_requests) * prefill \
             + backlog * step / self.slots
-        if self._paged and prompt is not None and step:
+        if prompt is not None and step:
             # block-pressure pricing (PR 8): when the pool cannot
             # supply this request's prefill blocks right now, no slot
             # math helps — it waits until an in-flight sequence
@@ -1384,8 +1319,7 @@ class DecodeEngine(object):
                             retry_after=math.ceil(est["queue_wait_s"]))
                     ahead_requests += 1
                     ahead_tokens += max_new
-                    if self._paged:
-                        ahead_blocks += self._pool.blocks_for(len(prompt))
+                    ahead_blocks += self._pool.blocks_for(len(prompt))
             deadline = None if deadline_s is None \
                 else time.monotonic() + deadline_s
             handles = []
@@ -1483,14 +1417,6 @@ class DecodeEngine(object):
                                  "tokens": qos_tokens.get(t, 0)}
                              for t in set(tenant_queued)
                              | set(tenant_active) | set(qos_tokens)}}
-        # block-pool view (PR 8) + kernel config (PR 11): rides the
-        # fleet BEAT payload and /healthz so routers and operators see
-        # memory headroom and which attention formulation serves each
-        # replica, not just slot occupancy (a paged engine can be
-        # slot-free but block-bound, or the reverse). Contiguous
-        # engines report the zero schema (attn_impl "contiguous") so
-        # consumers need no presence checks.
-        stats["attn_impl"] = self.attn_impl
         # speculative decoding + int8 KV config (PR 15): which fast
         # paths serve this replica, and the LIVE acceptance rate —
         # mirrored into /healthz and the fleet BEAT payload so
@@ -1518,46 +1444,35 @@ class DecodeEngine(object):
                 self.kv_counters.get("spliced_bytes")
             stats["kv_spliced_blocks"] = \
                 self.kv_counters.get("spliced_blocks")
-        if self._paged:
-            ps = self._pool.stats()
-            stats["kv_blocks_total"] = ps["total"]
-            stats["kv_blocks_free"] = ps["free"]
-            stats["prefix_hit_rate"] = round(ps["hit_rate"], 4)
-            stats["generated_prefix_hit_blocks"] = ps["generated_hits"]
-            stats["generated_prefix_registered"] = \
-                ps["generated_registered"]
-            # prefix-chain digest (PR 16): the top-K hottest resident
-            # chains as [truncated hash, depth-in-blocks] pairs, the
-            # bounded warmth signal the fleet router's prefix-aware
-            # dispatch matches prompts against. Rides every beat —
-            # bounded at paging.PREFIX_DIGEST_TOP_K entries, so the
-            # lease payload stays small at any pool size;
-            # digest_truncated is the honesty flag for what was cut.
-            dig = self._pool.prefix_digest()
-            stats["prefix_digest"] = dig["top"]
-            stats["prefix_digest_block_size"] = dig["block_size"]
-            stats["digest_truncated"] = dig["truncated"]
-        else:
-            stats["kv_blocks_total"] = 0
-            stats["kv_blocks_free"] = 0
-            stats["prefix_hit_rate"] = 0.0
-            stats["generated_prefix_hit_blocks"] = 0
-            stats["generated_prefix_registered"] = 0
-            # contiguous engines publish the zero schema — an empty
-            # digest, never an absent key (consumers need no presence
-            # checks, matching every other load_stats field)
-            stats["prefix_digest"] = []
-            stats["prefix_digest_block_size"] = 0
-            stats["digest_truncated"] = False
+        # block-pool view (PR 8): rides the fleet BEAT payload and
+        # /healthz so routers and operators see memory headroom, not
+        # just slot occupancy (an engine can be slot-free but
+        # block-bound, or the reverse)
+        ps = self._pool.stats()
+        stats["kv_blocks_total"] = ps["total"]
+        stats["kv_blocks_free"] = ps["free"]
+        stats["prefix_hit_rate"] = round(ps["hit_rate"], 4)
+        stats["generated_prefix_hit_blocks"] = ps["generated_hits"]
+        stats["generated_prefix_registered"] = \
+            ps["generated_registered"]
+        # prefix-chain digest (PR 16): the top-K hottest resident
+        # chains as [truncated hash, depth-in-blocks] pairs, the
+        # bounded warmth signal the fleet router's prefix-aware
+        # dispatch matches prompts against. Rides every beat —
+        # bounded at paging.PREFIX_DIGEST_TOP_K entries, so the
+        # lease payload stays small at any pool size;
+        # digest_truncated is the honesty flag for what was cut.
+        dig = self._pool.prefix_digest()
+        stats["prefix_digest"] = dig["top"]
+        stats["prefix_digest_block_size"] = dig["block_size"]
+        stats["digest_truncated"] = dig["truncated"]
         return stats
 
     def kv_cache_bytes(self):
-        """Resident KV-cache bytes: the block pool (paged — including
-        the scratch row, and the per-head scales an int8 pool carries
-        alongside its codes) or the contiguous per-slot regions, plus
-        the draft model's pool when speculating. The number the
-        ``bench.py serving_decode.paged`` / ``.kv_int8`` legs hold
-        fixed while scaling concurrency."""
+        """Resident KV-cache bytes: the block pool (including the
+        scratch row, and the per-head scales an int8 pool carries
+        alongside its codes), plus the draft model's pool when
+        speculating."""
         import jax
 
         caches = [self._cache]
@@ -1571,132 +1486,6 @@ class DecodeEngine(object):
                         "key_scale", "value_scale"):
                     total += leaf.size * leaf.dtype.itemsize
         return total
-
-    def _first_cache_leaves(self, *names):
-        """First cache leaf per name (one layer's pool/scale arrays) —
-        the live-shape source the measure_* probes run against. Keys
-        missing from the cache (e.g. scales on a float engine) map to
-        None."""
-        import jax
-
-        found = dict.fromkeys(names)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(
-                self._cache):
-            name = self._generation._leaf_name(path)
-            if name in found and found[name] is None:
-                found[name] = leaf
-        return found
-
-    def measure_attn(self, reps=3, depth=None):
-        """Time ONE decode-shaped call of this engine's attention
-        formulation (fused kernel or gather reference) at its pool
-        shapes with every slot ``depth`` tokens deep (default
-        ``total_len // 2``), and record the samples as the ``attn``
-        stage in ``self.timers`` — so the bench and profile stage
-        tables can attribute the kernel-vs-gather delta per step
-        through the same ``metrics_report`` helpers as every other
-        stage.
-
-        This is a standalone probe, not an in-jit split: the decode
-        step is one compiled program and XLA exposes no per-op timing,
-        so the honest attribution is to run the step's attention op by
-        itself (one layer's worth — multiply by ``num_layers`` for the
-        per-step total). ``depth`` is SYNTHETIC and stated rather than
-        read from the live cursors: an idle engine's released slots
-        park at cursor 0, which would time the fused path at its
-        1-block floor while the gather path still pays full table
-        width — a systematically skewed comparison. Pass the
-        workload's live depth for workload-matched numbers. The
-        compile is excluded (one unmeasured warm-up call). Returns
-        mean ms per call, or None on a contiguous engine (its
-        attention is not a paged op). Call while the engine is idle
-        — it reads the live pool leaves."""
-        if not self._paged:
-            return None
-        import importlib
-
-        import jax
-        import jax.numpy as jnp
-
-        pa = importlib.import_module(
-            "tensorflowonspark_tpu.ops.paged_attention")
-        leaves = self._first_cache_leaves(
-            "cached_key", "cached_value", "key_scale", "value_scale")
-        kp, vp = leaves["cached_key"], leaves["cached_value"]
-        ks, vs = leaves["key_scale"], leaves["value_scale"]
-        n = self.model.num_heads
-        d = kp.shape[2] // n    # pools are flat: [P, block, heads * dim]
-        depth = int(depth) if depth is not None else self.total_len // 2
-        depth = max(1, min(depth, self.total_len))
-        q = jnp.zeros((self.slots, 1, n, d), kp.dtype)
-        # synthetic-but-valid block mapping: each slot's table cycles
-        # the real pool rows (1..kv_blocks), every slot at ``depth``
-        bps = self._blocks_per_slot
-        tables = (np.arange(self.slots)[:, None] * bps
-                  + np.arange(bps)[None, :]) % self.kv_blocks + 1
-        tables = jnp.asarray(tables, jnp.int32)
-        pos = jnp.full((self.slots, 1), depth - 1, jnp.int32)
-        if self._attn_probe is None:
-            impl = "gather" if self.attn_impl == "gather" else None
-            if self._kv_quant:
-                # the int8 probe times the REAL fast path: int8 loads
-                # + in-formulation dequant against the live scales
-                self._attn_probe = jax.jit(
-                    lambda q, k, v, t, p, ksc, vsc: pa.paged_attention(
-                        q, k, v, t, p, impl=impl, k_scale=ksc,
-                        v_scale=vsc))
-            else:
-                self._attn_probe = jax.jit(
-                    lambda q, k, v, t, p: pa.paged_attention(
-                        q, k, v, t, p, impl=impl))
-        args = (q, kp, vp, tables, pos) + ((ks, vs)
-                                           if self._kv_quant else ())
-        self._attn_probe(*args).block_until_ready()
-        for _ in range(max(1, int(reps))):
-            with self.timers.timed("attn"):
-                self._attn_probe(*args).block_until_ready()
-        return self.timers.per_ms().get("attn")
-
-    def measure_dequant(self, reps=3):
-        """Time ONE whole-pool dequantize (codes x scales for K and V)
-        at the engine's live int8 pool shapes, recorded as the
-        ``dequant`` stage in ``self.timers`` — the honest attribution
-        of what the int8 fast path ADDS to a step, standing beside
-        what ``measure_attn`` shows it saves. Standalone probe for the
-        same reason as ``measure_attn``: the dequant lives inside the
-        fused kernel and XLA exposes no per-op timing. One layer's
-        pool per call; multiply by ``num_layers`` for a per-step
-        bound (the kernel only touches LIVE blocks, so this
-        whole-pool number is the worst case). Returns mean ms per
-        call, or None on a non-int8 engine."""
-        if not self._kv_quant:
-            return None
-        import jax
-        import jax.numpy as jnp
-
-        leaves = self._first_cache_leaves(
-            "cached_key", "cached_value", "key_scale", "value_scale")
-        kp, vp = leaves["cached_key"], leaves["cached_value"]
-        ks, vs = leaves["key_scale"], leaves["value_scale"]
-        if self._dequant_probe is None:
-            # BOTH pools: a step's attention dequantizes K and V, so a
-            # K-only probe would under-report the add-on by 2x. The
-            # codes are flat ([P, block, heads * dim]) and stay so: a
-            # head's scale is repeated over its lanes, since a view of
-            # the pool with heads apart would time a relayout instead
-            def dequant(codes, scales):
-                lanes = codes.shape[-1] // scales.shape[-1]
-                return codes.astype(jnp.float32) * jnp.repeat(
-                    scales, lanes, axis=-1)
-
-            self._dequant_probe = jax.jit(
-                lambda k, ksc, v, vsc: (dequant(k, ksc), dequant(v, vsc)))
-        jax.block_until_ready(self._dequant_probe(kp, ks, vp, vs))
-        for _ in range(max(1, int(reps))):
-            with self.timers.timed("dequant"):
-                jax.block_until_ready(
-                    self._dequant_probe(kp, ks, vp, vs))
-        return self.timers.per_ms().get("dequant")
 
     def outstanding(self):
         """Queued + in-flight request count (the number drain waits on)."""
@@ -1764,7 +1553,7 @@ class DecodeEngine(object):
 
     def compile_stats(self):
         """Live program counts for the engine's jitted fns (shared per
-        (model, sampling-config) via ``generation.slot_step_fns``, so
+        (model, sampling-config) via ``generation.paged_step_fns``, so
         the counts span every engine on that pair — the compile-bound
         contract the tests assert). ``_cache_size`` is private jax jit
         API; counts come back None if a jax upgrade drops it, so stats
@@ -1874,8 +1663,7 @@ class DecodeEngine(object):
                 given[s] = self._last[s]
         return self._generation.pack_step_feed(
             given, np.where(take, self._idx, 0),
-            np.where(take[:, None], self._tables, 0) if self._paged
-            else None)
+            np.where(take[:, None], self._tables, 0))
 
     def _ewma(self, prev, sample):
         return sample if prev is None \
@@ -2022,30 +1810,26 @@ class DecodeEngine(object):
             keys = list(buckets)
             winner = keys[self._qos_sched.select(keys)]
             head = buckets[winner][0]
-            cost = 1.0
-            if self._paged:
-                # blocked-winner memo: while the winner waits for
-                # blocks, re-walking its prefix chain every decode
-                # step is O(prompt) wasted on the scheduler thread.
-                # Keyed on the pool's MUTATION EPOCH — every event
-                # that could change the verdict bumps it, and with an
-                # unchanged epoch this scan's planned_blocks is
-                # provably 0, so the old verdict stands.
-                if self._head_block_memo == \
-                        (head, self._pool.epoch()):
-                    block_starved = True
-                    break
-                toks = head.prompt + head._tokens
-                shared, need, lru_shared, allocatable, \
-                    epoch = self._pool.plan_admission(toks)
-                if need + lru_shared + planned_blocks \
-                        > allocatable:
-                    self._head_block_memo = (head, epoch)
-                    block_starved = True
-                    break
-                self._head_block_memo = None
-                planned_blocks += need + lru_shared
-                cost = float(max(1, need + lru_shared))
+            # blocked-winner memo: while the winner waits for
+            # blocks, re-walking its prefix chain every decode
+            # step is O(prompt) wasted on the scheduler thread.
+            # Keyed on the pool's MUTATION EPOCH — every event
+            # that could change the verdict bumps it, and with an
+            # unchanged epoch this scan's planned_blocks is
+            # provably 0, so the old verdict stands.
+            if self._head_block_memo == (head, self._pool.epoch()):
+                block_starved = True
+                break
+            toks = head.prompt + head._tokens
+            shared, need, lru_shared, allocatable, \
+                epoch = self._pool.plan_admission(toks)
+            if need + lru_shared + planned_blocks > allocatable:
+                self._head_block_memo = (head, epoch)
+                block_starved = True
+                break
+            self._head_block_memo = None
+            planned_blocks += need + lru_shared
+            cost = float(max(1, need + lru_shared))
             s = free.pop(0)
             # occupy the slot AT pop time: every popped handle must be
             # findable by the failure paths (_fail_outstanding) even
@@ -2068,10 +1852,8 @@ class DecodeEngine(object):
                               else "admit_scans_blocked_slots")
         victims = []
         # class preemption rides PR 8's paged preemption machinery
-        # (continuation re-prefill of prompt + emitted tokens); a
-        # contiguous engine has no seamless re-entry, so it never
-        # preempts — strict class ordering still holds at admission
-        if buckets and self._paged and (block_starved or not free):
+        # (continuation re-prefill of prompt + emitted tokens)
+        if buckets and (block_starved or not free):
             # a head is still waiting; if its class is strictly
             # stronger than some in-flight sequence, that sequence
             # yields — weakest class first, youngest within the class
@@ -2126,7 +1908,6 @@ class DecodeEngine(object):
                     # QoS admission (PR 18): weighted-fair pick order
                     # replaces the FIFO head scan; the stage timer
                     # proves the scheduler stays off the hot path
-                    # (<50us/plan, pinned by scripts/profile_serving)
                     with self.timers.timed("qos_plan"):
                         admits, victims = self._plan_admission_locked()
                     self.counters.gauge("queue_depth", len(self._queue))
@@ -2155,12 +1936,11 @@ class DecodeEngine(object):
                 # for them, so the next admission scan can reuse them
                 with self.timers.timed("evict"):
                     self._evict_expired(time.monotonic())
-                if self._paged:
-                    # lazy block growth (and, under exhaustion,
-                    # youngest-first preemption) for every slot whose
-                    # NEXT write crosses a block boundary
-                    with self.timers.timed("grow_blocks"):
-                        self._grow_active_blocks()
+                # lazy block growth (and, under exhaustion,
+                # youngest-first preemption) for every slot whose
+                # NEXT write crosses a block boundary
+                with self.timers.timed("grow_blocks"):
+                    self._grow_active_blocks()
                 active = self._active_slots()
                 self.counters.gauge("slot_occupancy", len(active))
                 if not active:
@@ -2208,13 +1988,12 @@ class DecodeEngine(object):
                                  active=len(active), step=steps)
                 steps += 1
                 self.counters.inc("decode_steps")
-                if self._paged:
-                    # blocks held by in-flight sequences, summed over
-                    # the steps: kv_block_steps / decode_steps is the
-                    # mean occupancy of the pool the steps saw
-                    self.counters.inc(
-                        "kv_block_steps",
-                        self._pool.num_blocks - self._pool.allocatable())
+                # blocks held by in-flight sequences, summed over
+                # the steps: kv_block_steps / decode_steps is the
+                # mean occupancy of the pool the steps saw
+                self.counters.inc(
+                    "kv_block_steps",
+                    self._pool.num_blocks - self._pool.allocatable())
                 with self.timers.timed("host_schedule"):
                     if self._spec_k:
                         delivered = self._spec_deliver(active, drafts,
@@ -2277,13 +2056,12 @@ class DecodeEngine(object):
             self.counters.inc("decode_steps")
             self.counters.inc("steps_dispatched_ahead",
                               int(older is not None))
-            if self._paged:
-                # blocks held by in-flight sequences, summed over the
-                # steps: kv_block_steps / decode_steps is the mean
-                # occupancy of the pool the steps saw
-                self.counters.inc(
-                    "kv_block_steps",
-                    self._pool.num_blocks - self._pool.allocatable())
+            # blocks held by in-flight sequences, summed over the
+            # steps: kv_block_steps / decode_steps is the mean
+            # occupancy of the pool the steps saw
+            self.counters.inc(
+                "kv_block_steps",
+                self._pool.num_blocks - self._pool.allocatable())
         if older is None:
             return
         # the pace, read to read (dispatch to read after a pause):
@@ -2363,9 +2141,7 @@ class DecodeEngine(object):
         pointed at decode). Both writes ride the shared block tables
         at the shared cursors, so the draft pool mirrors the target
         pool position for position. Returns ``(drafts [S, k],
-        targets [S, k])`` host arrays. Per-half wall attribution
-        comes from :meth:`measure_spec`'s standalone probes — per-op
-        timing is invisible inside one program."""
+        targets [S, k])`` host arrays."""
         with self.timers.timed("spec_round"):
             with self.timers.timed("step_upload"):
                 feed = self._step_feed(jnp)
@@ -2378,52 +2154,6 @@ class DecodeEngine(object):
                 drafts = np.asarray(drafts)   # the per-round host sync
                 targets = np.asarray(targets)
         return drafts, targets
-
-    def measure_spec(self, reps=3, depth=None):
-        """Time the speculative round's two halves SEPARATELY — the
-        draft propose scan and the target verify apply — at the
-        engine's pool shapes with every slot ``depth`` tokens deep
-        (default ``total_len // 2``), recording ``draft`` and
-        ``verify`` stage samples in ``self.timers`` so bench/profile
-        stage tables attribute the round through the same
-        metrics_report helpers as every other stage. Same honest-
-        attribution rationale as :meth:`measure_attn`: the hot loop
-        runs ONE fused program and XLA exposes no per-op timing, so
-        each half runs standalone (non-donating jits over the very
-        bodies the fused round composes). Call while the engine is
-        idle — it reads the live cache pytrees. Returns
-        ``{"draft": ms, "verify": ms}`` or None on a non-speculative
-        engine."""
-        if not self._spec_k:
-            return None
-        import jax
-        import jax.numpy as jnp
-
-        k = self._spec_k
-        depth = int(depth) if depth is not None else self.total_len // 2
-        depth = max(1, min(depth, self.total_len - k))
-        bps = self._blocks_per_slot
-        tables = jnp.asarray(
-            (np.arange(self.slots)[:, None] * bps
-             + np.arange(bps)[None, :]) % self.kv_blocks + 1, jnp.int32)
-        idx = jnp.full((self.slots,), depth, jnp.int32)
-        last = jnp.zeros((self.slots,), jnp.int32)
-        feed = jnp.zeros((self.slots, k), jnp.int32)
-        key = jax.random.PRNGKey(0)
-        propose, verify = self._spec_probe_fns
-        propose(self._draft_params, self._draft_cache, last, idx,
-                tables, key)[1].block_until_ready()
-        verify(self.params, self._cache, feed, idx, tables,
-               key)[1].block_until_ready()
-        for _ in range(max(1, int(reps))):
-            with self.timers.timed("draft"):
-                propose(self._draft_params, self._draft_cache, last,
-                        idx, tables, key)[1].block_until_ready()
-            with self.timers.timed("verify"):
-                verify(self.params, self._cache, feed, idx, tables,
-                       key)[1].block_until_ready()
-        per = self.timers.per_ms()
-        return {"draft": per.get("draft"), "verify": per.get("verify")}
 
     def _spec_deliver(self, active, drafts, targets):
         """Host half: token-matching acceptance + per-token delivery.
@@ -2584,15 +2314,6 @@ class DecodeEngine(object):
         """Refresh the block-pool gauges (kv_blocks_free / total /
         cached) and roll the pool's monotonic tallies (hits / misses /
         LRU evictions) into the prefix counters."""
-        if not self._paged:
-            # the documented zero schema: a contiguous engine still
-            # EXPORTS the kv gauges (as zeros), so dashboards keyed on
-            # the catalog rows see data, not absence
-            for gauge in ("kv_blocks_total", "kv_blocks_free",
-                          "kv_blocks_cached", "prefix_digest_chains",
-                          "prefix_digest_truncated"):
-                self.counters.gauge(gauge, 0)
-            return
         stats = self._pool.stats()
         self.counters.gauge("kv_blocks_total", stats["total"])
         self.counters.gauge("kv_blocks_free", stats["free"])
@@ -2632,8 +2353,6 @@ class DecodeEngine(object):
         already re-allocated — blocks. Private blocks go back to the
         free list; registered prefix blocks decref into the LRU cache
         (still hittable, evicted only under pressure)."""
-        if not self._paged:
-            return
         if self._slot_blocks[slot]:
             self._pool.release(self._slot_blocks[slot])
             self._slot_blocks[slot] = []
@@ -2688,10 +2407,10 @@ class DecodeEngine(object):
         """Pack ``tokens``'s resident full-block KV chain into wire
         buffers — the prefill-tier half of a shipment. Returns
         ``(buffers, meta)`` (:func:`kvship.pack` output plus the header
-        it embeds) or ``None`` when nothing is resident (unpaged
-        engine, or the prompt spans no full block). The buffers carry
-        the pool rows AS STORED — int8 codes + per-head scales on a
-        quantized pool, no dequant round-trip — so physical ship cost
+        it embeds) or ``None`` when nothing is resident (the prompt
+        spans no full block, or its blocks were evicted). The buffers
+        carry the pool rows AS STORED — int8 codes + per-head scales on
+        a quantized pool, no dequant round-trip — so physical ship cost
         is exactly ``frames.frame_bytes(buffers)``. ``src_epoch`` is
         this replica's fencing epoch, stamped into the header so the
         receiver can refuse shipments from a fenced-out incarnation."""
@@ -2761,8 +2480,6 @@ class DecodeEngine(object):
 
     def _kv_export(self, tokens, src_epoch):
         """Scheduler-thread half of :meth:`export_prefix`."""
-        if not self._paged:
-            return None
         # walk-and-pin atomically: a concurrent drop_cache between the
         # walk and a separate acquire could free a block mid-export
         chain = self._pool.resident_chain(tokens, acquire=True)
@@ -2789,9 +2506,6 @@ class DecodeEngine(object):
 
     def _kv_import(self, meta, rows):
         """Scheduler-thread half of :meth:`import_prefix`."""
-        if not self._paged:
-            raise SpliceRejected(
-                "unpaged", "target engine has no block pool")
         bs = self.kv_block_size
         if int(meta.get("block_size") or 0) != bs:
             raise SpliceRejected(
@@ -3007,12 +2721,13 @@ class DecodeEngine(object):
                 self._slot_blocks[s].append(new_id)
             self._publish_kv_gauges()
 
-    def _admit_paged(self, slot, handle):
-        """Paged admission: point the slot's block table at any
-        resident shared-prefix blocks, allocate private blocks for the
-        rest, and prefill ONLY the un-shared tail (the warm-prefix TTFT
-        win — a resident prefix costs a table write, not a forward
-        pass). Also the preemption re-entry path: a requeued handle
+    def _admit(self, slot, handle):
+        """Prefill ``handle``'s prompt into ``slot`` and emit its first
+        token (a max_new_tokens=1 request completes right here): point
+        the slot's block table at any resident shared-prefix blocks,
+        allocate private blocks for the rest, and prefill ONLY the
+        un-shared tail (the warm-prefix TTFT win — a resident prefix
+        costs a table write, not a forward pass). Also the preemption re-entry path: a requeued handle
         re-prefills prompt + already-emitted tokens and resumes."""
         import jax.numpy as jnp
 
@@ -3147,49 +2862,6 @@ class DecodeEngine(object):
         self._deliver(slot, first)
         self.counters.inc("tokens")
 
-    def _admit(self, slot, handle):
-        """Prefill ``handle``'s prompt into ``slot`` and emit its first
-        token (a max_new_tokens=1 request completes right here)."""
-        import jax.numpy as jnp
-
-        if self._paged:
-            return self._admit_paged(slot, handle)
-        n = len(handle.prompt)
-        bucket = self._generation.bucket_for(n, self.buckets)
-        toks = np.zeros(bucket, np.int32)
-        toks[:n] = handle.prompt
-        # (the slot was occupied at pop time, so if this prefill dies
-        # the loop's failure path finds the handle in _slot_req instead
-        # of stranding its client on a timeout)
-        t0 = time.monotonic()
-        self.timers.add("queue_wait", t0 - handle.submitted)
-        self._hist_qwait.observe(t0 - handle.submitted)
-        self._hist_qwait_class.get(
-            handle.priority,
-            self._hist_qwait_class[qos.DEFAULT_PRIORITY]).observe(
-                t0 - handle.submitted)
-        self.flight.span("queue", handle.submitted, t0,
-                         trace=handle.trace, slot=slot)
-        handle._attr_spans.append(("queue", handle.submitted, t0))
-        with self.timers.timed("prefill"):
-            self._cache, first = self._prefill_fn(
-                self.params, self._cache, jnp.int32(slot),
-                jnp.asarray(toks), jnp.int32(n), self._next_key())
-            first = int(first)
-        t1 = time.monotonic()
-        self._prefill_ewma = self._ewma(self._prefill_ewma, t1 - t0)
-        self._qwait_ewma = self._ewma(self._qwait_ewma,
-                                      t0 - handle.submitted)
-        self.flight.span("prefill", t0, t1, trace=handle.trace,
-                         bucket=bucket, prompt_len=n)
-        handle._attr_spans.append(("prefill", t0, t1))
-        handle._decode_t0 = t1
-        self.counters.inc("prefills")
-        self._idx[slot] = n
-        self._last[slot] = first
-        self._deliver(slot, first)
-        self.counters.inc("tokens")
-
     def _deliver(self, slot, token, block=None):
         """Append one emitted token to the slot's request; complete and
         free the slot on EOS or length. Cursor discipline: ``_idx[slot]``
@@ -3227,11 +2899,10 @@ class DecodeEngine(object):
         done = (self.eos_token is not None and token == self.eos_token) \
             or len(handle._tokens) >= handle.max_new_tokens
         if done:
-            if self._paged:
-                # a sequence can finish with its last decode-filled
-                # block complete but never crossing another boundary —
-                # publish it before the slot releases its references
-                self._register_generated(slot, handle)
+            # a sequence can finish with its last decode-filled
+            # block complete but never crossing another boundary —
+            # publish it before the slot releases its references
+            self._register_generated(slot, handle)
             handle._finish()
             self._slot_req[slot] = None
             self._release_slot(slot)
@@ -3866,8 +3537,8 @@ class ModelServer(object):
         export = engine.export_prefix(
             prompt, src_epoch=payload.get("src_epoch"))
         if export is None:
-            # nothing resident to ship (sub-block prompt or unpaged
-            # engine) — the prefill itself still happened
+            # nothing resident to ship (sub-block prompt) — the
+            # prefill itself still happened
             return out
         buffers, meta = export
         out["blocks"] = len(meta["origins"])
@@ -4125,14 +3796,14 @@ class ModelServer(object):
             body["counts"] = snap["counts"]
             # block-pool headroom (PR 8): same pinned keys the fleet
             # BEAT payload carries, so an operator curl and a router
-            # decision read one schema (zeros on a contiguous engine).
+            # decision read one schema.
             # getattr: supervision fakes duck-type only healthy() +
             # counters, and a health probe must not 500 over a gauge
             load_stats = getattr(engine, "load_stats", None)
             if callable(load_stats):
                 load = load_stats()
                 for key in ("kv_blocks_total", "kv_blocks_free",
-                            "prefix_hit_rate", "attn_impl",
+                            "prefix_hit_rate",
                             "generated_prefix_hit_blocks",
                             "generated_prefix_registered",
                             "speculate_k", "spec_acceptance_rate",
@@ -4191,18 +3862,11 @@ class ModelServer(object):
             info += ('# TYPE tfos_serving_replica_info gauge\n'
                      'tfos_serving_replica_info{{replica_id="{}"}} 1\n'
                      .format(rid))
-        impl = getattr(engine, "attn_impl", None)
-        if impl is not None:
-            # same info pattern for the attention formulation (PR 11):
-            # which kernel serves this replica, joinable against its
-            # latency series during a fused-kernel rollout
-            info += ('# TYPE tfos_serving_attn_impl gauge\n'
-                     'tfos_serving_attn_impl{{impl="{}"}} 1\n'
-                     .format(impl))
         kv_dtype = getattr(engine, "kv_dtype", None)
         if kv_dtype is not None:
-            # and for the KV storage dtype (PR 15): which replicas run
-            # the int8 fast path during a quantization rollout
+            # same info pattern for the KV storage dtype (PR 15):
+            # which replicas run the int8 fast path during a
+            # quantization rollout
             info += ('# TYPE tfos_serving_kv_dtype gauge\n'
                      'tfos_serving_kv_dtype{{dtype="{}"}} 1\n'
                      .format(kv_dtype))

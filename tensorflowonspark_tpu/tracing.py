@@ -30,8 +30,8 @@ code; its own plumbing is unobservable. Here the framework exposes:
   loops: serving.DecodeEngine exports queue depth, slot occupancy,
   tokens-per-step, and the request-lifecycle tallies (``shed`` /
   ``cancelled`` / ``deadline_exceeded`` / ``engine_restarts``) through
-  one of these; bench.py / scripts/profile_serving.py read the
-  snapshots and ModelServer's /healthz serves them live.
+  one of these; the benchmark's runners read the snapshots and
+  ModelServer's /healthz serves them live.
 - :class:`EventLog` — timestamped named events for the supervision plane
   (supervisor.py): failure detected, attempt torn down, cluster
   reformed, checkpoint restored, first post-restore step. The MTTR
@@ -365,12 +365,7 @@ METRIC_FAMILIES = {
         ("counter", "", "in-flight requests preempted (blocks freed, "
                         "requeued for continuation) under pool "
                         "exhaustion"),
-    # -- fused paged attention + generated-prefix registration (PR 11) --
-    "tfos_serving_attn_impl":
-        ("gauge", "impl", "constant 1 carrying the engine's attention "
-                          "formulation (fused / gather / contiguous) — "
-                          "info-pattern join key for kernel-config "
-                          "rollouts across a fleet"),
+    # -- generated-prefix registration (PR 11) --
     "tfos_serving_generated_prefix_registered":
         ("counter", "", "decode-GENERATED full blocks published into "
                         "the prefix registry (multi-turn conversation "
@@ -720,7 +715,7 @@ METRIC_FAMILIES = {
         ("counter", "reason", "shipments this replica refused or "
                               "failed to splice, by bounded reason "
                               "(fenced / block_size / kv_dtype / "
-                              "pool_exhausted / malformed / unpaged / "
+                              "pool_exhausted / malformed / "
                               "engine) — 'fenced' growing means a "
                               "retired incarnation is still shipping"),
     # -- control-plane survivability (PR 19) --

@@ -77,11 +77,15 @@ def test_multichip_phase_on_four_virtual_devices():
 def test_serving_phase_tiny(interpreted_kernels):
     r = chip_smoke.serving_phase(5, platform="cpu", model=TINY_LM,
                                  prompt_lens=(5, 32, 70), new_tokens=6)
-    assert r["requests"] == 5 and r["attn_impl"] == "fused"
+    assert r["requests"] == 5 and "attn_impl" not in r
     assert r["prefix_hit_rate"] > 0
     assert r["compile"]["programs"] > 0
-    # prefill and decode programs of the default engine traced the kernel
-    assert interpreted_kernels["paged"] >= 2
+    # prefill and decode programs of the default engine traced the
+    # kernel, and so did both sides of the comparison at its shapes
+    assert interpreted_kernels["paged"] >= 4
+    versus = r["default_vs_gather"]
+    assert versus["q"] == [8, 1, 3, 16] and versus["pool"] == [65, 16, 48]
+    assert versus["rel_err"] <= versus["tol"]
     # the repo pins this parity at token level on the CPU
     assert r["parity"] == "tokens equal"
 

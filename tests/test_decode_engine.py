@@ -115,7 +115,7 @@ def test_compile_count_bounded_by_buckets(lm):
     max_new) signatures compiles one decode program per engine config
     plus at most one prefill program per touched bucket — while the
     old whole-generation path would compile once per signature."""
-    # a dedicated model config so generation.slot_step_fns' lru cache
+    # a dedicated model config so generation.paged_step_fns' lru cache
     # entry (and its program counts) belongs to this test alone
     train = DecoderLM(vocab=V, hidden=H, num_heads=NH, num_layers=1,
                       max_len=64, decode=False)
@@ -262,9 +262,7 @@ def test_engine_failure_fails_clients_not_hangs(lm):
 # A token engine dispatches step n+1 before it reads step n (the next
 # input is the device's own output), so what the host knows lags the
 # cursor by one step. Everything a client can see must be as if it did
-# not: paged and contiguous, the same cases.
-
-ENGINES = {"paged": dict(kv_block_size=8), "contiguous": dict(kv_block_size=0)}
+# not.
 
 
 def _counts(eng):
@@ -277,8 +275,7 @@ def _disarm_chaos():
     chaos.disarm()
 
 
-@pytest.mark.parametrize("kind", sorted(ENGINES))
-def test_streams_of_unequal_lengths_are_solo_token_by_token(lm, kind):
+def test_streams_of_unequal_lengths_are_solo_token_by_token(lm):
     """Concurrent requests of unequal lengths, each consumed from
     ``stream()`` by a thread of its own while steps are in flight: every
     stream is the solo rollout's tokens one by one, whole and in order,
@@ -293,7 +290,7 @@ def test_streams_of_unequal_lengths_are_solo_token_by_token(lm, kind):
         for tok in handle.stream(timeout=300):
             got[i].append(tok)
 
-    with serving.DecodeEngine(dec, params, slots=3, **ENGINES[kind]) as eng:
+    with serving.DecodeEngine(dec, params, slots=3, kv_block_size=8) as eng:
         threads = [threading.Thread(target=consume,
                                     args=(i, eng.submit(p, mn)))
                    for i, (p, mn) in enumerate(reqs)]
@@ -311,8 +308,7 @@ def test_streams_of_unequal_lengths_are_solo_token_by_token(lm, kind):
     assert counts["decode_tokens"] == sum(mn - 1 for _, mn in reqs)
 
 
-@pytest.mark.parametrize("kind", sorted(ENGINES))
-def test_eos_with_a_step_in_flight_ends_there_and_frees_the_slot(lm, kind):
+def test_eos_with_a_step_in_flight_ends_there_and_frees_the_slot(lm):
     """A request that ends on ``eos_token`` has a row in the step
     dispatched before its EOS was read: it ends AT the EOS, the row is
     dropped and counted, and the request admitted into the freed slot
@@ -330,7 +326,7 @@ def test_eos_with_a_step_in_flight_ends_there_and_frees_the_slot(lm, kind):
             gen = gen[:gen.index(eos) + 1]
         want.append(p + gen)
     with serving.DecodeEngine(dec, params, slots=1, eos_token=eos,
-                              **ENGINES[kind]) as eng:
+                              kv_block_size=8) as eng:
         handles = [eng.submit(p, mn) for p, mn in reqs]  # queued behind
         got = [h.result(300) for h in handles]
         counts = _counts(eng)
@@ -346,15 +342,14 @@ def test_eos_with_a_step_in_flight_ends_there_and_frees_the_slot(lm, kind):
 
 
 @pytest.mark.parametrize("how", ["cancel", "deadline"])
-@pytest.mark.parametrize("kind", sorted(ENGINES))
-def test_eviction_with_a_step_in_flight_drops_its_row(lm, kind, how):
+def test_eviction_with_a_step_in_flight_drops_its_row(lm, how):
     """A request cancelled, or past its deadline, while a step holds a
     row of it: the row's token is nobody's (counted), the victim gets
     its error, and the neighbour's stream is its solo rollout."""
     dec, params = lm
     probe_prompt, probe_new = [3, 1, 4, 1], 14
     want = _solo(dec, params, probe_prompt, probe_new)
-    with serving.DecodeEngine(dec, params, slots=2, **ENGINES[kind]) as eng:
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8) as eng:
         # programs compiled before any clock matters, and no shedding
         # on the evidence of a compile
         eng.submit([1, 2], 3).result(300)
@@ -384,8 +379,7 @@ def test_eviction_with_a_step_in_flight_drops_its_row(lm, kind, how):
 
 
 @pytest.mark.parametrize("how", ["drain", "stop"])
-@pytest.mark.parametrize("kind", sorted(ENGINES))
-def test_drain_and_stop_with_a_step_in_flight_strand_no_client(lm, kind, how):
+def test_drain_and_stop_with_a_step_in_flight_strand_no_client(lm, how):
     """``drain()`` with steps in flight finishes every admitted request
     whole. ``stop()`` first lands the step in flight (its tokens are
     delivered, a last token would complete its request) and then fails
@@ -393,7 +387,7 @@ def test_drain_and_stop_with_a_step_in_flight_strand_no_client(lm, kind, how):
     dec, params = lm
     reqs = _mixed_requests(np.random.RandomState(13), 5, lo_n=8, hi_n=30)
     want = [_solo(dec, params, p, mn) for p, mn in reqs]
-    eng = serving.DecodeEngine(dec, params, slots=2, **ENGINES[kind])
+    eng = serving.DecodeEngine(dec, params, slots=2, kv_block_size=8)
     try:
         if how == "stop":
             # the first step boundary held open: stop() arrives before
@@ -424,8 +418,7 @@ def test_drain_and_stop_with_a_step_in_flight_strand_no_client(lm, kind, how):
         eng.stop()
 
 
-@pytest.mark.parametrize("kind", sorted(ENGINES))
-def test_sampled_request_alone_draws_as_a_serial_loop_does(lm, kind):
+def test_sampled_request_alone_draws_as_a_serial_loop_does(lm):
     """temperature > 0, one request alone: the engine splits its key
     once per prefill and once per dispatched step, in that order, and
     feeds the device's own draw back without reading it first; the
@@ -436,29 +429,23 @@ def test_sampled_request_alone_draws_as_a_serial_loop_does(lm, kind):
     kw = dict(temperature=0.9, top_k=8)
     with serving.DecodeEngine(dec, params, slots=slots,
                               rng=jax.random.PRNGKey(5), **kw,
-                              **ENGINES[kind]) as eng:
+                              kv_block_size=8) as eng:
         got = eng.submit(prompt, max_new).result(300)
-        model, paged = eng._model, eng._paged
-        bps = eng._blocks_per_slot if paged else 0
+        model, bps = eng._model, eng._blocks_per_slot
         assert _counts(eng)["steps_dispatched_ahead"] == max_new - 2
 
-    fns = generation.paged_step_fns if paged else generation.slot_step_fns
-    prefill, step = fns(model, 0.9, 8, None)
+    prefill, step = generation.paged_step_fns(model, 0.9, 8, None)
     cache = generation.init_cache(model, slots, MAXLEN)
     key = jax.random.PRNGKey(5)
     toks = np.zeros(8, np.int32)
     toks[:len(prompt)] = prompt
     key, sub = jax.random.split(key)
     tables = np.zeros((slots, bps), np.int32)
-    if paged:
-        # the blocks a fresh pool hands out first: 1, 2, ... in order
-        tables[0] = np.arange(1, bps + 1)
-        cache, first = prefill(params, cache, jnp.asarray(tables[0]),
-                               jnp.asarray(toks), jnp.int32(len(prompt)),
-                               jnp.int32(0), sub)
-    else:
-        cache, first = prefill(params, cache, jnp.int32(0),
-                               jnp.asarray(toks), jnp.int32(len(prompt)), sub)
+    # the blocks a fresh pool hands out first: 1, 2, ... in order
+    tables[0] = np.arange(1, bps + 1)
+    cache, first = prefill(params, cache, jnp.asarray(tables[0]),
+                           jnp.asarray(toks), jnp.int32(len(prompt)),
+                           jnp.int32(0), sub)
     want, idx = [int(first)], np.zeros(slots, np.int32)
     idx[0] = len(prompt)
     for _ in range(max_new - 1):
@@ -466,8 +453,7 @@ def test_sampled_request_alone_draws_as_a_serial_loop_does(lm, kind):
         given = np.array([want[-1]] + [-1] * (slots - 1), np.int32)
         cache, picked = step(
             params, cache, jnp.zeros(slots, jnp.int32),
-            generation.pack_step_feed(given, idx, tables if paged else None),
-            sub)
+            generation.pack_step_feed(given, idx, tables), sub)
         want.append(int(np.asarray(picked)[0]))
         idx[0] += 1
     assert got == prompt + want

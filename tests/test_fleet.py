@@ -279,7 +279,7 @@ def test_digest_match_deepest_resident_chain():
     assert fleet.digest_match(view, prompt[:17]) == 1
     assert fleet.digest_match(view, prompt[:16]) == 0  # all tail
     assert fleet.digest_match(view, [9] * 50) == 0     # different chain
-    # zero schema (contiguous replica) and malformed entries are cold
+    # a view with no digest and malformed entries are cold
     assert fleet.digest_match(_view("b"), prompt) == 0
     broken = _digest_view("c", chains=[(prompt, 1)])
     broken["prefix_digest"] = [["x"], None, ["h", "deep"]]

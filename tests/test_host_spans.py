@@ -194,12 +194,10 @@ def _serve(eng, n=3, max_new=6):
     return [h.result(300) for h in handles]
 
 
-@pytest.mark.parametrize("paged", [True, False])
-def test_engine_loop_is_split_into_named_stages(lm, paged):
+def test_engine_loop_is_split_into_named_stages(lm):
     dec, params = lm
-    kw = dict(kv_block_size=8, kv_blocks=12) if paged \
-        else dict(kv_block_size=0)
-    with serving.DecodeEngine(dec, params, slots=2, **kw) as eng:
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                              kv_blocks=12) as eng:
         _serve(eng)
         # the loop parks once the last request is done; a park counts
         # when it ends, so wake it with one more request
@@ -209,8 +207,7 @@ def test_engine_loop_is_split_into_named_stages(lm, paged):
         counts = eng.counters.snapshot()["counts"]
     stages = {"park", "qos_plan", "admit", "prefill", "evict",
               "decode_step", "step_upload", "step_dispatch", "step_sync",
-              "host_schedule", "queue_wait"}
-    stages |= {"grow_blocks", "block_alloc"} if paged else set()
+              "host_schedule", "queue_wait", "grow_blocks", "block_alloc"}
     assert stages <= set(n), stages - set(n)
     # one queue wait and one admission per request (nothing preempted)
     assert n["queue_wait"] == n["admit"] == n["prefill"] == 4
@@ -232,11 +229,8 @@ def test_engine_loop_is_split_into_named_stages(lm, paged):
     assert sec["decode_step"] - parts < 0.001 * steps
     # admit holds its prefill
     assert sec["prefill"] <= sec["admit"]
-    if paged:
-        assert sec["block_alloc"] <= sec["admit"] + sec["grow_blocks"]
-        assert 1.0 <= counts["kv_block_steps"] / steps <= 12.0
-    else:
-        assert "kv_block_steps" not in counts and "grow_blocks" not in n
+    assert sec["block_alloc"] <= sec["admit"] + sec["grow_blocks"]
+    assert 1.0 <= counts["kv_block_steps"] / steps <= 12.0
 
 
 def test_engine_stages_are_spans_on_the_scheduler_thread(lm, tmp_path):
@@ -291,8 +285,7 @@ def test_speculative_round_holds_the_three_step_parts(lm):
         <= sec["spec_round"]
 
 
-@pytest.mark.parametrize("kind", ["paged", "contiguous", "speculative",
-                                  "blocks"])
+@pytest.mark.parametrize("kind", ["paged", "speculative", "blocks"])
 def test_a_step_is_dispatched_ahead_where_the_device_feeds_itself(lm, kind):
     """``steps_dispatched_ahead`` over ``decode_steps``: above 0.8 on a
     busy token engine, whose next input is the device's own output; 0
@@ -311,7 +304,6 @@ def test_a_step_is_dispatched_ahead_where_the_device_feeds_itself(lm, kind):
         dec = sdar_moe.SdarMoeLM(**tiny, dtype=jnp.float32, decode=True)
         params = ref.init_params(jax.random.PRNGKey(1), tiny)
     kw = {"paged": dict(kv_block_size=8, kv_blocks=24),
-          "contiguous": dict(kv_block_size=0),
           "speculative": dict(kv_block_size=8, kv_blocks=24, speculate_k=3),
           "blocks": dict(kv_block_size=8, kv_blocks=24)}[kind]
     with serving.DecodeEngine(dec, params, slots=3, **kw) as eng:
@@ -322,7 +314,7 @@ def test_a_step_is_dispatched_ahead_where_the_device_feeds_itself(lm, kind):
         else n["spec_round"]
     assert steps >= 20
     ahead = counts["steps_dispatched_ahead"]
-    if kind in ("paged", "contiguous"):
+    if kind == "paged":
         assert ahead / steps > 0.8
         assert n["decode_step"] == 2 * steps - ahead
     else:

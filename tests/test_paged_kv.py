@@ -3,8 +3,8 @@
 The tentpole's whole contract is that paging is INVISIBLE to every
 request: block tables, lazy growth, prefix sharing, LRU eviction, and
 even mid-flight preemption may only change WHERE K/V bytes live, never
-what tokens come out. Pinned here as the three-way bitwise equality
-(paged engine == pre-paged contiguous engine == solo ``generate``) at
+what tokens come out. Pinned here as the bitwise equality of the
+paged engine and solo ``generate`` (the contiguous cache) at
 temperature=0, warm-prefix == cold-prefix twins, and bitwise
 continuation across a preemption. Plus the accounting contracts:
 admission honesty under block pressure (shed, don't 504), and the
@@ -194,13 +194,13 @@ def test_pool_register_first_writer_wins():
     assert pool.stats()["cached"] == 1
 
 
-# -- the three-way bitwise pin ------------------------------------------
+# -- the bitwise pin ----------------------------------------------------
 
 
-def test_three_way_bitwise_paged_contiguous_solo(lm):
+def test_paged_engine_is_bitwise_solo(lm):
     """THE acceptance pin: mixed-length requests through the paged
-    engine, the pre-paged contiguous engine, and solo ``generate`` all
-    emit exactly the same tokens at temperature=0."""
+    engine and solo ``generate`` (the contiguous cache) emit exactly
+    the same tokens at temperature=0."""
     dec, params = lm
     rng = np.random.RandomState(0)
     reqs = []
@@ -209,16 +209,10 @@ def test_three_way_bitwise_paged_contiguous_solo(lm):
         reqs.append((p, int(rng.randint(1, 10))))
     want = [_solo(dec, params, p, mn) for p, mn in reqs]
     with serving.DecodeEngine(dec, params, slots=2) as eng:
-        assert eng._paged  # paged is the default engine
+        assert eng.kv_block_size == 16  # the default engine's pool
         paged = [h.result(300) for h in
                  [eng.submit(p, mn) for p, mn in reqs]]
-    with serving.DecodeEngine(dec, params, slots=2,
-                              kv_block_size=0) as eng:
-        assert not eng._paged
-        contig = [h.result(300) for h in
-                  [eng.submit(p, mn) for p, mn in reqs]]
     assert paged == want
-    assert contig == want
 
 
 def test_warm_prefix_bitwise_and_hit_counters(lm):
@@ -313,11 +307,11 @@ def test_preemption_continuation_bitwise(lm):
     assert pool.live_refs() == {} and pool.allocatable() == 5
 
 
-def test_paged_outperforms_contiguous_capacity(lm):
+def test_paged_pool_is_smaller_than_full_length_slots(lm):
     """The memory story: 6 sequences whose worst case is 18 blocks all
-    serve correctly through an 8-block pool (the contiguous layout
-    would need 6 full-length slots), and the paged pool at that budget
-    is smaller than the contiguous cache it replaces."""
+    serve correctly through an 8-block pool, which is smaller than the
+    6 full-length rows a contiguous cache (``generate``'s, at batch 6)
+    holds for them."""
     dec, params = lm
     rng = np.random.RandomState(6)
     reqs = [(rng.randint(0, V, size=9).tolist(), 15) for _ in range(6)]
@@ -328,9 +322,12 @@ def test_paged_outperforms_contiguous_capacity(lm):
         got = [h.result(600) for h in
                [eng.submit(p, mn) for p, mn in reqs]]
     assert got == want
-    with serving.DecodeEngine(dec, params, slots=6,
-                              kv_block_size=0) as eng:
-        contig_bytes = eng.kv_cache_bytes()
+    contig_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for path, leaf in
+        jax.tree_util.tree_leaves_with_path(
+            generation.init_cache(dec, 6, MAXLEN))
+        if generation._leaf_name(path) in ("cached_key", "cached_value"))
+    assert contig_bytes == 2 * L * 6 * MAXLEN * H * 4
     # 9 blocks of 8 tokens resident (incl. scratch) vs 6 x 64 rows
     assert paged_bytes < contig_bytes / 4
 
@@ -377,17 +374,58 @@ def test_validate_rejects_request_larger_than_pool(lm):
         assert len(eng.submit([1, 2], 4).result(300)) == 6
 
 
-def test_contiguous_mode_rejects_kv_blocks_and_reports_zeroes(lm):
+def test_kv_block_size_zero_is_refused_with_and_without_kv_blocks(lm):
     dec, params = lm
-    with pytest.raises(ValueError, match="paged"):
-        serving.DecodeEngine(dec, params, slots=1, kv_block_size=0,
-                             kv_blocks=4)
-    with serving.DecodeEngine(dec, params, slots=1,
-                              kv_block_size=0) as eng:
-        stats = eng.load_stats()
-        assert stats["kv_blocks_total"] == 0
-        assert stats["kv_blocks_free"] == 0
-        assert stats["prefix_hit_rate"] == 0.0
+    for kw in (dict(), dict(kv_blocks=4)):
+        with pytest.raises(ValueError, match="paged block pool only"):
+            serving.DecodeEngine(dec, params, slots=1, kv_block_size=0,
+                                 **kw)
+
+
+class _NoPagedFields(object):
+    """A decode model of a family that never got the paged fields."""
+
+    max_len = MAXLEN
+
+
+@pytest.mark.parametrize("how", ["kv_block_size=0", "model"])
+def test_engine_without_a_block_pool_is_refused_with_one_message(lm, how):
+    """There is no contiguous engine mode, asked for by the option or
+    by a model that cannot be re-speced for a pool: both are refused,
+    before a thread starts, with the message that names the path that
+    does run on the contiguous cache."""
+    dec, params = lm
+    args = (dec, dict(kv_block_size=0)) if how == "kv_block_size=0" \
+        else (_NoPagedFields(), dict())
+    before = threading.active_count()
+    with pytest.raises(ValueError) as err:
+        serving.DecodeEngine(args[0], params, slots=1, **args[1])
+    text = str(err.value)
+    assert "paged block pool only" in text
+    assert "generation.generate is the contiguous-cache path" in text
+    assert type(args[0]).__name__ in text
+    assert threading.active_count() == before
+
+
+def test_attn_impl_is_no_option_and_no_reported_field(lm):
+    """One formulation serves every engine, so there is nothing to
+    select and nothing to report: pinned so that neither the option nor
+    a field that can read one value drifts back."""
+    dec, params = lm
+    with pytest.raises(TypeError, match="attn_impl"):
+        serving.DecodeEngine(dec, params, slots=1, attn_impl="fused")
+    with pytest.raises(TypeError, match="attn_impl"):
+        dec.clone(attn_impl="fused")
+    with serving.DecodeEngine(dec, params, slots=1) as eng:
+        assert not hasattr(eng, "attn_impl")
+        assert "attn_impl" not in eng._spawn_args
+        assert "attn_impl" not in eng.load_stats()
+        server = serving.ModelServer(None, engine=eng, name="m")
+        code, body = server.healthz()
+        assert code == 200 and "attn_impl" not in body
+        assert body["generated_prefix_hit_blocks"] == 0
+        assert "attn_impl" not in server.metrics_text()
+        server.engine = None  # the engine is this test's to stop
 
 
 def test_solo_generate_rejects_paged_model(lm):
@@ -418,16 +456,17 @@ def test_healthz_and_load_stats_carry_block_pool(lm):
         server.engine = None  # the engine is this test's to stop
 
 
-# -- fused paged-attention kernel + attn_impl knob (PR 11) --------------
+# -- fused paged-attention kernel (PR 11) -------------------------------
 
 
-def test_fused_equals_gather_equals_solo_under_pressure(lm):
-    """THE PR 11 parity pin: the same workload — mixed lengths, a
-    shared prefix (prefix-cached admissions), and a pool small enough
-    to force preemption-continuation — through a FUSED engine and a
-    GATHER engine emits exactly the tokens solo ``generate`` does at
-    temperature=0. The two formulations differ only in float
-    accumulation order, so the token streams must be identical."""
+def test_fused_equals_solo_under_pressure(lm):
+    """THE PR 11 parity pin: a workload of mixed lengths, a shared
+    prefix (prefix-cached admissions), and a pool small enough to force
+    preemption-continuation, through the engine (attention straight off
+    the block table) emits exactly the tokens solo ``generate`` does at
+    temperature=0. The two differ only in float accumulation order, so
+    the token streams must be identical (the fused formulation against
+    the gather one is tests/test_paged_attention.py's, at the op)."""
     dec, params = lm
     rng = np.random.RandomState(21)
     shared = rng.randint(0, V, size=16).tolist()  # 2 full 8-blocks
@@ -436,21 +475,17 @@ def test_fused_equals_gather_equals_solo_under_pressure(lm):
             (shared + rng.randint(0, V, size=5).tolist(), 11),
             (rng.randint(0, V, size=5).tolist(), 10)]
     want = [_solo(dec, params, p, mn) for p, mn in reqs]
-    got = {}
-    for impl in ("fused", "gather"):
-        # 5 blocks cannot hold two grown sequences: preemption fires
-        # (the same engine config as the preemption-continuation test,
-        # so the fused leg reuses its compiled programs)
-        with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
-                                  kv_blocks=5, attn_impl=impl) as eng:
-            assert eng.attn_impl == impl
-            assert eng.load_stats()["attn_impl"] == impl
-            got[impl] = [h.result(300) for h in
-                         [eng.submit(p, mn) for p, mn in reqs]]
-            counts = _counts(eng)
-        assert counts.get("prefix_hit_blocks", 0) >= 2, impl
-    assert got["fused"] == want
-    assert got["gather"] == want
+    # 5 blocks cannot hold two grown sequences: preemption fires
+    # (the same engine config as the preemption-continuation test,
+    # so this one reuses its compiled programs)
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                              kv_blocks=5) as eng:
+        got = [h.result(300) for h in
+               [eng.submit(p, mn) for p, mn in reqs]]
+        counts = _counts(eng)
+    assert counts.get("prefix_hit_blocks", 0) >= 2
+    assert counts.get("preemptions", 0) >= 1
+    assert got == want
 
 
 def test_scratch_isolation_through_fused_path(lm):
@@ -580,50 +615,6 @@ def test_generated_registration_gated_by_prefix_cache(lm):
         assert eng.load_stats()["generated_prefix_registered"] == 0
 
 
-def test_attn_impl_knob_validation_and_schema(lm):
-    """The knob's contract: paged engines accept fused/gather and
-    reject junk; contiguous engines reject the knob and report the
-    'contiguous' schema; /healthz and /metrics carry the config."""
-    dec, params = lm
-    with pytest.raises(ValueError, match="attn_impl"):
-        serving.DecodeEngine(dec, params, slots=2, attn_impl="banana")
-    with pytest.raises(ValueError, match="paged"):
-        serving.DecodeEngine(dec, params, slots=2, kv_block_size=0,
-                             attn_impl="fused")
-    with serving.DecodeEngine(dec, params, slots=1,
-                              kv_block_size=0) as eng:
-        assert eng.load_stats()["attn_impl"] == "contiguous"
-        assert eng.measure_attn() is None
-    with serving.DecodeEngine(dec, params, slots=2) as eng:
-        assert eng.attn_impl == "fused"  # the paged default
-        server = serving.ModelServer(None, engine=eng, name="m")
-        code, body = server.healthz()
-        assert code == 200 and body["attn_impl"] == "fused"
-        assert body["generated_prefix_hit_blocks"] == 0
-        text = server.metrics_text()
-        assert 'tfos_serving_attn_impl{impl="fused"} 1' in text
-        # the attn stage probe records through the shared timers
-        assert eng.measure_attn() is not None
-        assert "attn" in eng.timers.per_ms()
-        server.engine = None  # the engine is this test's to stop
-
-
-def test_respawn_preserves_attn_impl(lm):
-    dec, params = lm
-    eng = serving.DecodeEngine(dec, params, slots=1,
-                               attn_impl="gather")
-    try:
-        eng.stop()
-        fresh = eng.respawn()
-        try:
-            assert fresh.attn_impl == "gather"
-            assert fresh.load_stats()["attn_impl"] == "gather"
-        finally:
-            fresh.stop()
-    finally:
-        eng.stop()
-
-
 @pytest.mark.chaos
 @pytest.mark.slow
 def test_leak_churn_cancel_disconnect_evict_drain(lm):
@@ -729,22 +720,6 @@ def test_prefix_digest_top_k_truncation_honest():
     assert d["truncated"] is True
     small = pool.prefix_digest(top_k=5)
     assert len(small["top"]) == 5 and small["truncated"] is True
-
-
-def test_prefix_digest_zero_schema_contiguous_engine(lm):
-    """A contiguous (kv_block_size=0) engine's load_stats carries the
-    zero digest schema — same keys, empty content — so a router can
-    treat paged and contiguous replicas uniformly."""
-    dec, params = lm
-    with serving.DecodeEngine(dec, params, slots=1,
-                              kv_block_size=0) as eng:
-        stats = eng.load_stats()
-        assert stats["prefix_digest"] == []
-        assert stats["prefix_digest_block_size"] == 0
-        assert stats["digest_truncated"] is False
-        gauges = eng.counters.snapshot()["gauges"]
-        assert gauges["prefix_digest_chains"] == 0
-        assert gauges["prefix_digest_truncated"] == 0
 
 
 def test_prefix_digest_includes_generated_chains(lm):

@@ -461,8 +461,8 @@ def test_concurrent_multitenant_admission_race_free(lm):
 
 def test_qos_plan_stays_cheap(lm):
     """The whole admission plan is timed as stage ``qos_plan``; its
-    budget is <50us/plan (scripts/profile_serving.py prints the real
-    number) — asserted here LOOSELY (1-core CI box, timer overhead)."""
+    budget is <50us/plan — asserted here LOOSELY (1-core CI box,
+    timer overhead)."""
     dec, params = lm
     with serving.DecodeEngine(dec, params, slots=2) as eng:
         hs = [eng.submit([1 + i, 2], 4,

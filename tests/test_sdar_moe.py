@@ -299,8 +299,6 @@ def test_unmask_rule_quota_threshold_and_ties():
     (dict(eos_token=5), "eos_token"),
     (dict(speculate_k=2), "speculate_k"),
     (dict(kv_dtype="int8"), "kv_dtype"),
-    (dict(kv_block_size=0, kv_blocks=None), "paged cache only"),
-    (dict(attn_impl="gather"), "attn_impl"),
     (dict(tier="prefill"), "tier"),
     (dict(total_len=62, buckets=[8, 16]), "must divide"),
 ])

@@ -223,9 +223,6 @@ def test_spec_validation(lm):
     dec, params = lm
     with pytest.raises(ValueError, match="speculate_k"):
         serving.DecodeEngine(dec, params, slots=1, speculate_k=1)
-    with pytest.raises(ValueError, match="paged"):
-        serving.DecodeEngine(dec, params, slots=1, kv_block_size=0,
-                             speculate_k=2)
     with pytest.raises(ValueError, match="draft_layers"):
         serving.DecodeEngine(dec, params, slots=1, draft_layers=1)
     with pytest.raises(ValueError, match="draft_layers"):
@@ -237,9 +234,6 @@ def test_kv_dtype_validation(lm):
     dec, params = lm
     with pytest.raises(ValueError, match="kv_dtype"):
         serving.DecodeEngine(dec, params, slots=1, kv_dtype="int4")
-    with pytest.raises(ValueError, match="paged"):
-        serving.DecodeEngine(dec, params, slots=1, kv_block_size=0,
-                             kv_dtype="int8")
     # fp32 aliases are the off switch, not an error
     with serving.DecodeEngine(dec, params, slots=1,
                               kv_dtype="fp32") as eng:
@@ -273,11 +267,6 @@ def test_schema_through_load_stats_healthz_metrics(lm):
         text = server.metrics_text()
         assert 'tfos_serving_kv_dtype{dtype="int8"} 1' in text
         server.engine = None  # the engine is this test's to stop
-    # contiguous engines carry the same keys (zero schema)
-    with serving.DecodeEngine(dec, params, slots=1,
-                              kv_block_size=0) as eng:
-        load = eng.load_stats()
-        assert load["speculate_k"] == 0 and load["kv_dtype"] == "float32"
 
 
 def test_respawn_preserves_spec_and_kv_dtype(lm):
@@ -296,23 +285,6 @@ def test_respawn_preserves_spec_and_kv_dtype(lm):
             fresh.stop()
     finally:
         eng.stop()
-
-
-def test_measure_spec_and_dequant_probes(lm):
-    """The standalone stage probes record through the shared timers
-    (the profile/bench attribution path) and refuse on engines the
-    stage doesn't exist for."""
-    dec, params = lm
-    with serving.DecodeEngine(dec, params, slots=2, speculate_k=2,
-                              kv_dtype="int8") as eng:
-        spec_ms = eng.measure_spec()
-        assert spec_ms["draft"] > 0 and spec_ms["verify"] > 0
-        assert eng.measure_dequant() > 0
-        per = eng.timers.per_ms()
-        assert "draft" in per and "verify" in per and "dequant" in per
-    with serving.DecodeEngine(dec, params, slots=2) as eng:
-        assert eng.measure_spec() is None
-        assert eng.measure_dequant() is None
 
 
 def test_estimate_admission_scales_with_acceptance(lm):
@@ -334,8 +306,7 @@ def test_estimate_admission_scales_with_acceptance(lm):
 
 
 def test_fleet_view_carries_spec_and_kv_dtype(lm):
-    """The heterogeneous-rollout pin (the PR 11 attn_impl pattern):
-    a speculative int8 replica's BEAT payload surfaces speculate_k /
+    """The heterogeneous-rollout pin: a speculative int8 replica's BEAT payload surfaces speculate_k /
     spec_acceptance_rate / kv_dtype through the router's
     replica_views and its /healthz per-replica body."""
     from tensorflowonspark_tpu import fleet

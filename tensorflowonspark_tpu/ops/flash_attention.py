@@ -299,7 +299,7 @@ def _check_blocks(s_q, s_k, block_q, block_k):
 
 
 def grid_params():
-    """Compiler parameters of every kernel grid in ``ops``: two
+    """Compiler parameters of the three flash kernels' grids: two
     independent axes, then the axis the VMEM accumulators carry over."""
     from jax.experimental.pallas import tpu as pltpu
 

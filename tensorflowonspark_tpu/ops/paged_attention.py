@@ -17,18 +17,28 @@ ops/flash_attention.py), and visiting only the blocks a row actually
 occupies — per-step traffic scales with LIVE tokens. Three
 implementations share one contract:
 
-- ``impl="pallas"`` — the TPU kernel. Grid ``(B, query tiles,
-  table_width)`` under a ``PrefetchScalarGridSpec``: the block table
-  rides scalar prefetch and the K/V BlockSpec *index maps* read it, so
-  the pipeline DMAs exactly the pool block each grid step attends —
-  paged attention as an index-mapping problem, no gather
-  materialization. A block is one pool row with ALL its heads
+- ``impl="pallas"`` — the TPU kernel. Its grid is a WORK LIST
+  (:func:`_work_list`, PR 34): one axis whose bound, a traced scalar,
+  is the number of live (row, query tile, block) triples, and nothing
+  else is stepped over. The list — each step's (row, tile) pair, its
+  slot in the row's table, whether it is the pair's first or last
+  step — is built on the device inside the same jitted program from
+  the positions alone, and rides scalar prefetch with the block table
+  under a ``PrefetchScalarGridSpec``; the BlockSpec *index maps* read
+  it, so the pipeline DMAs exactly the queries and the pool block each
+  step attends — paged attention as an index-mapping problem, no
+  gather materialization. A block is one pool row with ALL its heads
   (``[block_size, heads * head_dim]``); the kernel loops the heads,
   reading each as a static lane slice of the block.
-  Dead table slots (past a row's live length) clamp their index map to
-  the row's last live block: consecutive equal indices make Pallas
-  skip the copy, so DMA traffic tracks live blocks, and a ``pl.when``
-  guard skips their compute.
+  Why a list and not a grid over ``(rows, query tiles, table width)``
+  with the dead slots skipped, which this was until PR 34: a skipped
+  step moved no bytes and computed nothing but was still TAKEN, at
+  0.09–0.11 us against 0.8 us for a live one (PERF.md §6, PR 34: a
+  probe on the chip), and a serving step's tables are mostly dead: 8%
+  of 16 x 32 slots walked in the GPT-2 large chat cell, 13% of 32 x 80
+  in the SDAR cell, so half to three fifths of a call went on steps
+  that did nothing (97 -> 42 us and 532 -> 268 us a call at the cells'
+  depths). A table that is full takes the steps it took before.
 - ``impl="blockwise"`` — the same recurrence in pure ``lax`` for
   the CPU backend (tier-1): ONE ``fori_loop`` with a *traced*
   bound (the batch's deepest live block count) whose body visits one
@@ -100,7 +110,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu.ops.flash_attention import grid_params, on_tpu
+from tensorflowonspark_tpu.ops.flash_attention import on_tpu
 
 
 def quantize_kv(x):
@@ -237,73 +247,95 @@ def _blockwise(q, k_pool, v_pool, block_table, pos, scale,
     return (acc / l_safe[..., None]).astype(q.dtype)
 
 
+def _work_list(nblk, width):
+    """The kernel's grid, step by step: ``nblk [P]`` (blocks each (row,
+    query tile) pair can see, at least 1) -> ``(steps, pair, code)``.
+    The pairs in order, each for its ``nblk`` steps: step ``s`` works
+    for pair ``pair[s]`` on slot ``code[s] >> 2`` of its row's table,
+    bit 1 of ``code[s]`` set on a pair's first step and bit 0 on its
+    last. ``steps = sum(nblk)`` (traced) is the grid's bound; the lists
+    have the static length ``P * width + 1``, and the entries past the
+    live ones repeat the last live step. They are never taken, but the
+    pipeline looks ONE entry ahead of the step it runs (on the chip a
+    full table's last step read past a list of ``P * width`` and halted
+    the core), so there is always one, and it is in bounds. A
+    comparison of every step with every pair's end, no gather and no
+    loop: 5,120 x 64 at the widest call of today's cells, and the same
+    for every layer of a program, so XLA computes it once."""
+    ends = jnp.cumsum(nblk, dtype=jnp.int32)
+    s = jnp.minimum(
+        jnp.arange(nblk.shape[0] * width + 1, dtype=jnp.int32), ends[-1] - 1)
+    before = s[:, None] >= ends[None, :]    # [T, P]: pairs done by step s
+    pair = jnp.sum(before, axis=1, dtype=jnp.int32)
+    slot = s - jnp.sum(jnp.where(before, nblk[None, :], 0), axis=1,
+                       dtype=jnp.int32)
+    last = jnp.any(s[:, None] + 1 == ends[None, :], axis=1)
+    return ends[-1], pair, slot * 4 + (slot == 0) * 2 + last
+
+
 def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
-    """One (batch row, q tile, table slot j) program: fold pool block
-    ``table[row, j]`` — every head of it — into the online-softmax
-    accumulators; emit on the last table slot. The K/V BlockSpec index
-    maps already routed the RIGHT pool block here (and clamped dead
-    slots to the last live block, skipping their copy), so the kernel
-    only guards compute. A block arrives flat, ``[rows, N * D]``, so
-    the per-head recurrence is a static loop over ``num_heads`` reading
-    each head's ``[rows, D]`` lanes out of the token-major block.
-    ``quantized`` adds per-head scale refs riding the SAME index maps
-    as K/V; the codes are unpacked in VMEM after the (int8-sized) copy
-    — the bandwidth the fast path saves is exactly the bytes the DMA
-    no longer moves."""
+    """One step of the work list (:func:`_work_list`): fold ONE live
+    pool block — every head of it — into the online-softmax
+    accumulators of its (row, q tile) pair; zero them on the pair's
+    first step, emit on its last. The index maps already routed the
+    pair's queries and the RIGHT pool block here. A block arrives flat,
+    ``[rows, N * D]``, so the per-head recurrence is a static loop over
+    ``num_heads`` reading each head's ``[rows, D]`` lanes out of the
+    token-major block. ``quantized`` adds per-head scale refs riding
+    the SAME index map as K/V; the codes are unpacked in VMEM after
+    the (int8-sized) copy — the bandwidth the fast path saves is
+    exactly the bytes the DMA no longer moves."""
     from jax.experimental import pallas as pl
 
     if quantized:
-        (table_ref, nblk_ref, q_ref, pos_ref, k_ref, v_ref, ks_ref,
-         vs_ref, o_ref, acc_ref, m_ref, l_ref) = refs
+        (table_ref, pair_ref, code_ref, q_ref, pos_ref, k_ref, v_ref,
+         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref) = refs
     else:
-        (table_ref, nblk_ref, q_ref, pos_ref, k_ref, v_ref,
+        (table_ref, pair_ref, code_ref, q_ref, pos_ref, k_ref, v_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
-    j = pl.program_id(2)
-    nblk = nblk_ref[pl.program_id(0), pl.program_id(1)]
+    code = code_ref[pl.program_id(0)]
+    j = code >> 2
     block_q, head_dim = q_ref.shape[1], q_ref.shape[3]
 
-    @pl.when(j == 0)
+    @pl.when((code & 2) != 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j < nblk)
-    def _accumulate():
-        kpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_size), 1)
-        vis = kpos <= pos_ref[0]                            # [bq, bs]
+    kpos = j * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_size), 1)
+    vis = kpos <= pos_ref[0]                                # [bq, bs]
+    if quantized:
+        # a head's scales as a ROW [1, bs], laid on its scores and
+        # its probabilities: q.(c*s) = (q.c)*s, p@(c*s) = (p*s)@c.
+        # Scaling K and V themselves by a [bs, 1] column cut out of
+        # the lanes of the scale block cost more than the attention
+        ks_t, vs_t = ks_ref[0].T, vs_ref[0].T               # [N, bs]
+    for h in range(num_heads):
+        q = q_ref[0, :, h, :].astype(jnp.float32)           # [bq, D]
+        lanes = slice(h * head_dim, (h + 1) * head_dim)
+        kb = k_ref[0, :, lanes].astype(jnp.float32)         # [bs, D]
+        vb = v_ref[0, :, lanes].astype(jnp.float32)
+        sc = jax.lax.dot_general(
+            q, kb, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
         if quantized:
-            # a head's scales as a ROW [1, bs], laid on its scores and
-            # its probabilities: q.(c*s) = (q.c)*s, p@(c*s) = (p*s)@c.
-            # Scaling K and V themselves by a [bs, 1] column cut out of
-            # the lanes of the scale block cost more than the attention
-            ks_t, vs_t = ks_ref[0].T, vs_ref[0].T           # [N, bs]
-        for h in range(num_heads):
-            q = q_ref[0, :, h, :].astype(jnp.float32)       # [bq, D]
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            kb = k_ref[0, :, lanes].astype(jnp.float32)     # [bs, D]
-            vb = v_ref[0, :, lanes].astype(jnp.float32)
-            sc = jax.lax.dot_general(
-                q, kb, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if quantized:
-                sc = sc * ks_t[h:h + 1]
-            sc = jnp.where(vis, sc, -jnp.inf)
-            m = m_ref[h]                                    # [bq, 1]
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-            safe_m = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-            p = jnp.where(jnp.isneginf(sc), 0.0, jnp.exp(sc - safe_m))
-            corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - safe_m))
-            m_ref[h] = m_new
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1,
-                                                 keepdims=True)
-            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p * vs_t[h:h + 1] if quantized else p, vb,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            sc = sc * ks_t[h:h + 1]
+        sc = jnp.where(vis, sc, -jnp.inf)
+        m = m_ref[h]                                        # [bq, 1]
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        safe_m = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        p = jnp.where(jnp.isneginf(sc), 0.0, jnp.exp(sc - safe_m))
+        corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - safe_m))
+        m_ref[h] = m_new
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p * vs_t[h:h + 1] if quantized else p, vb,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when((code & 1) != 0)
     def _emit():
         for h in range(num_heads):
             l = l_ref[h]
@@ -319,13 +351,16 @@ _BLOCK_Q = 128
 
 def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
             k_scale=None, v_scale=None):
-    """The TPU kernel: block table as scalar prefetch, K/V index maps
-    read it, dead slots clamp to the last live block (copy skipped).
-    int8 pools bring their ``[P, bs, N]`` scales along on the same
-    index maps; the kernel dequantizes in VMEM. Query rows are padded
-    to a whole number of ``block_q`` tiles (pad rows sit at position 0
-    and are sliced off the result); each tile visits only the blocks
-    ITS deepest query can see."""
+    """The TPU kernel over a work list: a ONE-axis grid whose bound is
+    the number of live (row, q tile, block) triples, a traced scalar,
+    with the lists and the block table as scalar prefetch for the
+    index maps (module docstring: a grid over every table slot spent
+    most of its time on steps that computed nothing). int8 pools
+    bring their ``[P, bs, N]`` scales along on the K/V index map; the
+    kernel dequantizes in VMEM. Query rows are padded to a whole
+    number of ``block_q`` tiles (pad rows sit at position 0 and are
+    sliced off the result); each tile visits only the blocks ITS
+    deepest query can see."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -334,45 +369,44 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
     mb = block_table.shape[1]
     block_q = min(_BLOCK_Q, -(-s_q // 8) * 8)
     pad = -s_q % block_q
-    pos = jnp.pad(pos.astype(jnp.int32), ((0, 0), (0, pad)))
-    q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
     nq = (s_q + pad) // block_q
-    table = block_table.astype(jnp.int32)
-    nblk = _nblocks(pos.reshape(b, nq, block_q), bs_blk, mb)  # [B, nq]
+    # a (row, q tile) pair is one row of q, pos, the table and the output
+    pos = jnp.pad(pos.astype(jnp.int32), ((0, 0), (0, pad))) \
+        .reshape(b * nq, block_q)
+    q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))) \
+        .reshape(b * nq, block_q, n, d)
+    table = jnp.repeat(block_table.astype(jnp.int32), nq, axis=0)
+    steps, pair, code = _work_list(_nblocks(pos, bs_blk, mb), mb)
     quantized = k_scale is not None
 
-    def q_index(row, i, j, table_ref, nblk_ref):
-        return (row, i, 0, 0)
+    def pair_index(s, table_ref, pair_ref, code_ref):
+        return (pair_ref[s], 0, 0, 0)
 
-    def pool_index(row, i, j, table_ref, nblk_ref):
-        live = jnp.minimum(j, nblk_ref[row, i] - 1)
-        return (table_ref[row, live], 0, 0)
+    def pool_index(s, table_ref, pair_ref, code_ref):
+        return (table_ref[pair_ref[s], code_ref[s] >> 2], 0, 0)
 
-    q_spec = pl.BlockSpec((1, block_q, n, d), q_index)
     pool_spec = pl.BlockSpec((1, bs_blk, n * d), pool_index)
     in_specs = [
-        q_spec,
+        pl.BlockSpec((1, block_q, n, d), pair_index),
         pl.BlockSpec((1, block_q, 1),
-                     lambda row, i, j, t, nb: (row, i, 0)),
+                     lambda s, t, pr, c: (pr[s], 0, 0)),
         pool_spec,
         pool_spec,
     ]
-    inputs = [table, nblk, q, pos[..., None], k_pool, v_pool]
+    inputs = [table, pair, code, q, pos[..., None], k_pool, v_pool]
     if quantized:
-        # the scales ride the exact pool-block routing K/V use (same
-        # dead-slot clamp, so their copy is skipped together)
+        # the scales ride the exact pool-block routing K/V use
         in_specs += [pl.BlockSpec((1, bs_blk, n), pool_index)] * 2
         inputs += [k_scale.astype(jnp.float32),
                    v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nq, mb),
+        num_scalar_prefetch=3,
+        grid=(steps,),
         in_specs=in_specs,
         # head-major output: each head's [block_q, D] result stores
         # dense (Mosaic has no packed-dtype store into one head's
         # sublane of a token-major tile); transposed back below
-        out_specs=pl.BlockSpec((1, n, block_q, d),
-                               lambda row, i, j, t, nb: (row, 0, i, 0)),
+        out_specs=pl.BlockSpec((1, n, block_q, d), pair_index),
         scratch_shapes=[
             pltpu.VMEM((n, block_q, d), jnp.float32),   # acc
             pltpu.VMEM((n, block_q, 1), jnp.float32),   # running max
@@ -385,12 +419,15 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n, s_q + pad, d), q.dtype),
-        compiler_params=grid_params(),
+        out_shape=jax.ShapeDtypeStruct((b * nq, n, block_q, d), q.dtype),
+        # the accumulators carry from a step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
     )(*inputs)
-    return jnp.transpose(out, (0, 2, 1, 3))[:, :s_q]
+    return out.reshape(b, nq, n, block_q, d).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, nq * block_q, n, d)[:, :s_q]
 
 
 def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,

@@ -997,6 +997,8 @@ class DecodeEngine(object):
         # start: an engine that never runs ahead reads 0, not absent
         self.counters.inc("steps_dispatched_ahead", 0)
         self.counters.inc("tokens_dropped_in_flight", 0)
+        self.counters.inc("attn_grid_steps", 0)
+        self.counters.inc("attn_table_slots", 0)
         # host-authoritative block tables: row s mirrors
         # _slot_blocks[s] padded with scratch (0). A freed slot's
         # row resets to scratch AND its cursor to 0, so the idle
@@ -1625,6 +1627,7 @@ class DecodeEngine(object):
         if self._block_len:
             # one array, handed over as numpy: the call transfers
             # it itself, with no ``jnp.asarray`` round trip before it
+            self._count_attn_grid(self._idx, self._block_len)
             return [self._generation.pack_block_feed(
                 self._block_feed(), self._idx, self._tables)]
         return [jnp.asarray(self._last), jnp.asarray(self._idx),
@@ -1661,9 +1664,25 @@ class DecodeEngine(object):
         for s in rows:
             if not self._in_flight(s):
                 given[s] = self._last[s]
+        cursors = np.where(take, self._idx, 0)
+        self._count_attn_grid(cursors, 1)
         return self._generation.pack_step_feed(
-            given, np.where(take, self._idx, 0),
-            np.where(take[:, None], self._tables, 0))
+            given, cursors, np.where(take[:, None], self._tables, 0))
+
+    def _count_attn_grid(self, cursors, span):
+        """What the paged kernel's grid takes in one call of the step
+        being fed (ops/paged_attention.py: a work list of live blocks),
+        beside the table slots a grid over every slot would: a slot at
+        ``cursors[s]`` sees the blocks up to the last of the ``span``
+        positions it feeds, an idle one at cursor 0 its one block.
+        ``attn_grid_steps`` over ``attn_table_slots`` is the share of
+        the tables that is walked (one call's; every layer's is the
+        same)."""
+        width = self._tables.shape[1]
+        live = np.minimum(
+            (cursors + span - 1) // self.kv_block_size + 1, width)
+        self.counters.inc("attn_grid_steps", int(live.sum()))
+        self.counters.inc("attn_table_slots", live.size * width)
 
     def _ewma(self, prev, sample):
         return sample if prev is None \

@@ -322,6 +322,16 @@ METRIC_FAMILIES = {
         ("counter", "", "tokens of a step in flight that nobody got: "
                         "the request ended on EOS, was cancelled or "
                         "evicted after the dispatch"),
+    "tfos_serving_attn_grid_steps":
+        ("counter", "", "grid steps one call of the paged attention "
+                        "kernel takes, summed over decode and block "
+                        "steps: the live KV blocks of every row, 1 "
+                        "for an idle slot"),
+    "tfos_serving_attn_table_slots":
+        ("counter", "", "block-table slots (slots x table width) of "
+                        "the same steps (tfos_serving_attn_grid_steps "
+                        "over it: the share of the tables the kernel "
+                        "walks)"),
     "tfos_serving_admit_scans_blocked_slots":
         ("counter", "", "admission scans that left a queued request "
                         "waiting because no slot was free"),

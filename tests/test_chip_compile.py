@@ -145,10 +145,12 @@ def test_paged_attention_compiles_for_v5e(one_chip, name):
         return pa.paged_attention(q, k, v, table, pos, impl="pallas",
                                   interpret=False, k_scale=ks, v_scale=vs)
 
-    compiled = _compile(paged, sds((rows, s_q, n, d), dtype), pool, pool,
-                        sds((rows, mb), jnp.int32),
-                        sds((rows, s_q), jnp.int32), scale, scale)
-    assert _has_kernel(compiled)
+    lowered = jax.jit(paged).lower(
+        sds((rows, s_q, n, d), dtype), pool, pool, sds((rows, mb), jnp.int32),
+        sds((rows, s_q), jnp.int32), scale, scale)
+    # the name the kernel's per-layer metrics pick its events by
+    assert lowered.as_text().count('kernel_name = "paged_attention"') == 1
+    assert _has_kernel(lowered.compile())
 
 
 def _on(sharding, tree):
@@ -367,6 +369,28 @@ def test_paged_pool_keeps_one_layout_for_v5e(one_chip, kernel_on_cpu_backend,
     assert mem.alias_size_in_bytes >= 2 * layers * pool_bytes
 
 
+def test_work_list_is_computed_once_a_program_for_v5e(one_chip,
+                                                      kernel_on_cpu_backend):
+    """The kernel's grid bound and its lists (``_work_list``) follow
+    the step's tables and cursors alone, which every layer shares:
+    the compiled decode step computes them once, and each layer's call
+    takes the very same four values ahead of its own queries."""
+    model, params, cache, sds = _paged_engine_shapes(
+        one_chip, dict(GPT2_LARGE_2L, num_layers=3), LARGE_SLOTS,
+        LARGE_TOTAL, 512)
+    entry = _lower_engine_program(
+        "paged_decode_step", model, params, cache, sds, LARGE_SLOTS,
+        LARGE_TOTAL).compile().as_text().split("\nENTRY ", 1)[1]
+    calls = re.findall(
+        r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        entry)
+    assert len(calls) == 3, entry
+    # bound, table, pair, code; then q, pos and the layer's pools
+    shared = {tuple(re.findall(r"%[\w.-]+", c)[:4]) for c in calls}
+    own = {tuple(re.findall(r"%[\w.-]+", c)[4:]) for c in calls}
+    assert len(shared) == 1 and len(own) == 3, calls
+
+
 def test_token_select_adds_no_operation_over_a_pool(one_chip,
                                                     kernel_on_cpu_backend):
     """The engine's step takes its input tokens from the device (the
@@ -465,9 +489,12 @@ def test_paged_attention_with_grouped_heads_compiles_for_v5e(one_chip, s_q):
 
     pool = sds((4097, KV_BLOCK, SDAR["kv_heads"] * SDAR["head_dim"]),
                jnp.bfloat16)
-    compiled = _compile(
-        lambda *a: pa.paged_attention(*a, impl="pallas", interpret=False),
+    lowered = jax.jit(
+        lambda *a: pa.paged_attention(*a, impl="pallas", interpret=False)
+    ).lower(
         sds((rows, s_q, SDAR["heads"], SDAR["head_dim"]), jnp.bfloat16),
         pool, pool, sds((rows, mb), jnp.int32), sds((rows, s_q), jnp.int32))
+    assert lowered.as_text().count('kernel_name = "paged_attention"') == 1
+    compiled = lowered.compile()
     assert _has_kernel(compiled)
     assert "paged_attention" in compiled.as_text()

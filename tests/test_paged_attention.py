@@ -294,3 +294,142 @@ def test_jit_and_traced_operands():
     ref = pa.paged_attention(q, kp, vp, table, pos, impl="gather")
     np.testing.assert_allclose(np.asarray(fn(q, kp, vp, table, pos)),
                                np.asarray(ref), atol=2e-6, rtol=2e-6)
+
+
+# -- the kernel's grid is a work list of live blocks (PR 34) ------------
+
+WORK_LISTS = {
+    # nblk [rows, q tiles], table width
+    "ragged_rows_of_one_and_a_full_row": ([[1], [3], [1], [4], [2]], 4),
+    "two_tiles_a_row": ([[2, 1, 3], [1, 1, 4]], 4),
+    "every_row_idle": ([[1], [1], [1]], 5),
+    "every_row_full": ([[4, 4], [4, 4]], 4),
+    "one_pair": ([[7]], 80),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WORK_LISTS))
+def test_work_list_is_the_live_blocks_of_each_pair_in_order(name):
+    """``_work_list`` alone: ``sum(nblk)`` steps; each (row, q tile)
+    pair's steps contiguous, the pairs in order; a pair's slots
+    ``0..nblk-1``; bit 1 on its first step, bit 0 on its last (both on
+    the one step of an idle row). The entries past the live ones, one
+    at the least (the chip's pipeline looks an entry ahead of the step
+    it runs), are never taken and repeat the last live step, so they
+    stay in bounds."""
+    nblk, width = WORK_LISTS[name]
+    flat = np.asarray(nblk, np.int32).reshape(-1)
+    steps, pair, code = jax.jit(pa._work_list, static_argnums=1)(
+        jnp.asarray(flat), width)
+    steps, pair, code = int(steps), np.asarray(pair), np.asarray(code)
+    assert pair.dtype == code.dtype == np.int32
+    assert pair.shape == code.shape == (flat.size * width + 1,)
+    assert steps < pair.size
+    assert steps == flat.sum()
+    want_pair = np.repeat(np.arange(flat.size), flat)
+    want_slot = np.concatenate([np.arange(n) for n in flat])
+    np.testing.assert_array_equal(pair[:steps], want_pair)
+    np.testing.assert_array_equal(code[:steps] >> 2, want_slot)
+    np.testing.assert_array_equal(code[:steps] & 2 != 0, want_slot == 0)
+    np.testing.assert_array_equal(code[:steps] & 1 != 0,
+                                  want_slot == flat[want_pair] - 1)
+    assert np.all(pair[steps:] == pair[steps - 1])
+    assert np.all(code[steps:] == code[steps - 1])
+
+
+def _mixed_batch(kind, seed=19, s_q=1, n=4, kv=4, d=16, bs=8, mb=6):
+    """Three rows as a serving step mixes them: an idle slot (cursor 0
+    over the scratch table, row 0 of the pool), a row inside its first
+    block, and a row that fills its table to the last position."""
+    rng = np.random.RandomState(seed)
+    dtype = jnp.bfloat16 if kind == "bf16_gqa" else jnp.float32
+    pool = 2 * mb + 1
+    q = jnp.asarray(rng.randn(3, s_q, n, d), dtype)
+    kp = jnp.asarray(rng.randn(pool, bs, kv * d), dtype)
+    vp = jnp.asarray(rng.randn(pool, bs, kv * d), dtype)
+    table = np.zeros((3, mb), np.int32)
+    table[1:] = 1 + rng.permutation(2 * mb).reshape(2, mb)
+    start = np.array([0, bs - s_q - 1, mb * bs - s_q])
+    pos = start[:, None] + np.arange(s_q)[None, :]
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(pos, jnp.int32)
+
+
+@pytest.mark.parametrize("kind", ["f32", "bf16_gqa", "int8"])
+def test_pallas_walks_an_idle_a_one_block_and_a_full_row(kind):
+    """The kernel (interpreter) against ``gather`` on a batch whose
+    rows take 1, 1 and ``table width`` steps: float32 with equal heads,
+    bfloat16 with 4 K/V heads under 32 query heads at 4 positions a
+    row (the block step's shape), and int8 pools. The bfloat16 case is
+    held to the oracle on the same values in float32: the kernel sums
+    in float32 and rounds its output once."""
+    if kind == "bf16_gqa":
+        q, kp, vp, table, pos = _mixed_batch(kind, s_q=4, n=32, kv=4)
+        want = pa.paged_attention(
+            q.astype(jnp.float32), kp.astype(jnp.float32),
+            vp.astype(jnp.float32), table, pos, impl="gather")
+        tol = dict(atol=1e-2, rtol=1e-2)
+    else:
+        q, kp, vp, table, pos = _mixed_batch(kind, s_q=1)
+        tol = dict(atol=2e-6, rtol=2e-6)
+    scales = {}
+    if kind == "int8":
+        (kp, sk), (vp, sv) = (_quantize_pool(p, q) for p in (kp, vp))
+        scales = dict(k_scale=sk, v_scale=sv)
+    if kind != "bf16_gqa":
+        want = pa.paged_attention(q, kp, vp, table, pos, impl="gather",
+                                  **scales)
+    got = pa.paged_attention(q, kp, vp, table, pos, impl="pallas",
+                             interpret=True, **scales)
+    assert got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol)
+    # the steps it took: one for the idle row, one for the row in its
+    # first block, the whole table for the full row
+    nblk = pa._nblocks(pos, kp.shape[1], table.shape[1])
+    assert nblk.tolist() == [1, 1, table.shape[1]]
+
+
+@pytest.mark.parametrize("starts", [(10, 0), (40, 20)])
+def test_prefill_tiles_of_one_row_see_different_depths(starts):
+    """160 query rows are two tiles of 128 (the second padded): each
+    tile walks the blocks ITS deepest query sees, so the two pairs of a
+    row take different numbers of steps, and the rows differ too."""
+    s_q, bs, mb, n, d = 160, 16, 13, 2, 16
+    rng = np.random.RandomState(23)
+    q = jnp.asarray(rng.randn(2, s_q, n, d), jnp.float32)
+    kp = jnp.asarray(rng.randn(2 * mb + 1, bs, n * d), jnp.float32)
+    vp = jnp.asarray(rng.randn(2 * mb + 1, bs, n * d), jnp.float32)
+    table = jnp.asarray(1 + rng.permutation(2 * mb).reshape(2, mb),
+                        jnp.int32)
+    pos = jnp.asarray(np.asarray(starts)[:, None] + np.arange(s_q)[None, :],
+                      jnp.int32)
+    depth = pa._nblocks(
+        jnp.pad(pos, ((0, 0), (0, 96))).reshape(2, 2, 128), bs, mb)
+    assert len(set(np.asarray(depth).reshape(-1).tolist())) == 4, depth
+    assert int(depth.max()) <= mb
+    want = pa.paged_attention(q, kp, vp, table, pos, impl="gather")
+    got = pa.paged_attention(q, kp, vp, table, pos, impl="pallas",
+                             interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
+
+
+def test_pallas_takes_as_many_steps_as_blocks_are_live(monkeypatch):
+    """The grid's one bound is ``sum(nblk)``: counted where the kernel
+    is launched, on a batch whose three rows see 1, 2 and 4 of 4 table
+    slots."""
+    from jax.experimental import pallas as pl
+
+    seen = []
+    call = pl.pallas_call
+
+    def spy(kernel, *, grid_spec, **kw):
+        seen.append(grid_spec.grid)
+        return call(kernel, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    q, kp, vp, table, _ = _case(2)
+    pos = jnp.asarray([[0], [9], [31]], jnp.int32)
+    pa.paged_attention(q, kp, vp, table, pos, impl="pallas", interpret=True)
+    (grid,) = seen
+    assert len(grid) == 1 and int(grid[0]) == 1 + 2 + 4

@@ -606,6 +606,26 @@ def test_finish_by_length_with_its_last_token_in_flight_grows_no_block(lm):
     assert counts["kv_block_steps"] == 2 * 8
 
 
+def test_attn_grid_steps_count_live_blocks_and_idle_rows(lm):
+    """What the paged kernel's grid takes, counted where the step's
+    feed is built: 8 prompt tokens and 9 new ones on one of two slots
+    are 8 steps at cursors 8..15, each seeing 2 blocks of 8, beside an
+    idle slot's one step; a grid over every table slot would take
+    ``slots x table width`` a step."""
+    dec, params = lm
+    prompt = np.random.RandomState(37).randint(0, V, size=8).tolist()
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                              prefix_cache=False) as eng:
+        assert _counts(eng)["attn_grid_steps"] == 0
+        eng.submit(prompt, 9).result(300)
+        counts = _counts(eng)
+        width = eng._tables.shape[1]
+    assert counts["decode_steps"] == 8
+    assert counts["attn_grid_steps"] == 8 * (2 + 1)
+    assert counts["attn_table_slots"] == 8 * 2 * width
+    assert width == MAXLEN // 8
+
+
 def test_generated_registration_gated_by_prefix_cache(lm):
     dec, params = lm
     with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,

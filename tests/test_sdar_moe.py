@@ -217,6 +217,32 @@ def test_engine_with_rows_in_different_phases_is_each_request_alone(steps):
         >= counts["expert_rows"]
 
 
+def test_attn_grid_steps_of_a_block_step_see_to_the_blocks_end():
+    """A block step's row sees to the last position of the block it
+    denoises, so its share of the paged kernel's grid is the KV blocks
+    up to there at every pass and at the commit; the two idle slots
+    take one step each. 9 prompt tokens leave the cursor at 8 (two
+    whole blocks of 4 prefilled), then blocks at 8, 12, 16: KV blocks
+    of 8 tokens, so 2, 2 and 3 of them."""
+    _, cast = _weights(4)
+    model = _model()
+    prompt = np.random.RandomState(3).randint(0, 96, size=9).tolist()
+    with _engine(model, cast) as eng:
+        handle = eng.submit(prompt, 11)
+        handle.result(120)
+        counts = eng.counters.snapshot()["counts"]
+        slots, width = eng._tables.shape
+    assert handle.deliveries == [3, 4, 4]
+    steps, at, want = 0, 0, 0
+    for cursor, n in zip((8, 12, 16), handle.deliveries):
+        passes = max(handle.unmask_passes[at:at + n]) + 2
+        steps, at = steps + passes, at + n
+        want += passes * ((cursor + TINY["block_len"] - 1) // 8 + 1)
+    assert counts["decode_steps"] == steps
+    assert counts["attn_grid_steps"] == want + steps * (slots - 1)
+    assert counts["attn_table_slots"] == steps * slots * width
+
+
 def test_preempted_request_re_enters_and_equals_the_request_alone():
     """A pool too small for three long requests: the youngest is
     preempted under exhaustion and re-enters through the common paged

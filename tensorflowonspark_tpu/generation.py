@@ -221,8 +221,49 @@ def _leaf_name(path):
 def _fed_tokens(picked, feed):
     """Each row's input token of a token step: the host's where it gave
     one (``feed[:, 0] >= 0``), else the device's own pick of the step
-    before. Inside the step program: no dispatch of its own."""
+    before (the first ``S`` of its answer: :func:`with_routed`). Inside
+    the step program: no dispatch of its own."""
+    if picked.shape[0] != feed.shape[0]:
+        picked = picked[:feed.shape[0]]
     return jnp.where(feed[:, 0] >= 0, feed[:, 0], picked)
+
+
+def answer_len(model, tokens, positions):
+    """Length of what a token program of ``model`` answers for a call
+    over ``positions`` positions that picks ``tokens`` tokens (a step:
+    one a slot of each; a prefill: one token, a bucket of positions):
+    the tokens, and behind them ``experts_per_tok`` expert ids a
+    position for each of the model's ``routed_layers`` (none for a model
+    without experts: the tokens alone)."""
+    layers = int(getattr(model, "routed_layers", 0))
+    return tokens + layers * positions * (
+        int(model.experts_per_tok) if layers else 0)
+
+
+def with_routed(picked, updated):
+    """What a token program answers: the tokens ``picked`` and, in the
+    SAME int32 array (one copy to the host, a turn late with the
+    tokens), the experts the router sent each position of the call to,
+    ``[layers, positions, experts_per_tok]`` raveled, where the model
+    sows them (:func:`_expert_ids`). A model that sows nothing answers
+    the tokens alone: the program it always was."""
+    sown = updated.get("intermediates", {})
+    if not jax.tree.leaves(sown):
+        return picked
+    return jnp.concatenate([picked.reshape(-1),
+                            _expert_ids(sown).astype(jnp.int32).ravel()])
+
+
+def split_routed(answer, tokens, positions, top_k):
+    """``(tokens [tokens], expert_ids [layers, positions, top_k] or
+    None)`` of a token program's answer (host side, numpy; views): the
+    inverse of :func:`with_routed`."""
+    import numpy as np
+
+    answer = np.asarray(answer).reshape(-1)
+    if answer.size == tokens:
+        return answer, None
+    return answer[:tokens], answer[tokens:].reshape(-1, positions, top_k)
 
 
 def pack_step_feed(given, idx, tables):
@@ -255,9 +296,34 @@ def pack_step_feed(given, idx, tables):
 # every program ``<lambda>``.
 
 
+#: flax cache leaves that are per-row BLOCK TABLES, one name per kind
+#: of cache (models/decoder.PagedKV: a window layer's is its own). The
+#: host hands the kinds' tables over side by side in ONE array, in this
+#: order (paging.CacheKinds.tables), each as wide as its leaves.
+TABLE_LEAVES = ("block_table", "window_table")
+
+
+def _tables_by_kind(cache, tables):
+    """``{table leaf name: its columns of tables}``. A cache of one
+    kind takes ``tables`` whole, the program it always was."""
+    widths = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        if _leaf_name(path) in TABLE_LEAVES:
+            widths[_leaf_name(path)] = leaf.shape[-1]
+    if len(widths) < 2:
+        return dict.fromkeys(widths, tables)
+    out, at = {}, 0
+    for name in TABLE_LEAVES:
+        if name in widths:
+            out[name] = tables[..., at:at + widths[name]]
+            at += widths[name]
+    return out
+
+
 def _set_paged_leaves(cache, idx, tables):
-    """Cache pytree with cursor leaves replaced by ``idx`` and
-    ``block_table`` leaves by ``tables``: the host scheduler is the
+    """Cache pytree with cursor leaves replaced by ``idx`` and the
+    block-table leaves by ``tables`` (each kind's by its own columns,
+    :data:`TABLE_LEAVES`): the host scheduler is the
     authority on both position AND block mapping, every call.
 
     A freed slot must NOT keep advancing its cursor while it idles, and
@@ -274,12 +340,14 @@ def _set_paged_leaves(cache, idx, tables):
     neighbors never see it. Eviction therefore cannot perturb
     concurrent sequences, which is why cancelled-neighbor outputs stay
     bitwise-identical (tests/test_serving_lifecycle.py pins this)."""
+    by_kind = _tables_by_kind(cache, tables)
+
     def repl(path, leaf):
         name = _leaf_name(path)
         if name in _CURSOR_LEAVES:
             return idx.astype(leaf.dtype)
-        if name == "block_table":
-            return tables.astype(leaf.dtype)
+        if name in by_kind:
+            return by_kind[name].astype(leaf.dtype)
         return leaf
     return jax.tree_util.tree_map_with_path(repl, cache)
 
@@ -290,13 +358,14 @@ def _slot_view(cache, table_row, start):
     are (they are batch-independent)."""
     table_row = jnp.asarray(table_row, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
+    by_kind = _tables_by_kind(cache, table_row)
 
     def view(path, leaf):
         name = _leaf_name(path)
         if name in _CURSOR_LEAVES:
             return jnp.full((1,), start, leaf.dtype)
-        if name == "block_table":
-            return table_row[None, :].astype(leaf.dtype)
+        if name in by_kind:
+            return by_kind[name][None, :].astype(leaf.dtype)
         return leaf
 
     return jax.tree_util.tree_map_with_path(view, cache)
@@ -308,7 +377,7 @@ def _merge_pools(cache, updated):
     keep their (host-overwritten-anyway) storage so the cache pytree's
     shapes never change."""
     def merge(path, big, new):
-        if _leaf_name(path) in _CURSOR_LEAVES + ("block_table",):
+        if _leaf_name(path) in _CURSOR_LEAVES + TABLE_LEAVES:
             return big
         return new
 
@@ -334,15 +403,25 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
     place and no other slot's blocks are touched. Returns
     ``(cache', first_token)`` with the first generated token picked
     from the logits at the last real tail position (so a warm
-    ``max_new_tokens=1`` request costs one tiny-bucket forward)."""
+    ``max_new_tokens=1`` request costs one tiny-bucket forward). A
+    model that ``takes_last`` runs its head on that one position and
+    not on the bucket; one that sows its routed experts answers them
+    behind the token (:func:`with_routed`)."""
     tail_len = jnp.asarray(tail_len, jnp.int32)
-    logits, upd = model.apply(
-        {"params": params, "cache": _slot_view(cache, table_row, start)},
-        tokens[None, :], mutable=["cache"])
-    cap = jax.lax.dynamic_index_in_dim(
-        logits, tail_len - 1, axis=1, keepdims=False)
+    variables = {"params": params,
+                 "cache": _slot_view(cache, table_row, start)}
+    if getattr(model, "takes_last", False):
+        cap, upd = model.apply(variables, tokens[None, :],
+                               last=(tail_len - 1)[None],
+                               mutable=["cache", "intermediates"])
+        cap = cap[:, 0]
+    else:
+        logits, upd = model.apply(variables, tokens[None, :],
+                                  mutable=["cache"])
+        cap = jax.lax.dynamic_index_in_dim(
+            logits, tail_len - 1, axis=1, keepdims=False)
     first = _pick_tokens(cap, rng, temperature, top_k, top_p)[0]
-    return _merge_pools(cache, upd["cache"]), first
+    return _merge_pools(cache, upd["cache"]), with_routed(first, upd)
 
 
 def paged_decode_step(model, params, cache, tokens, idx, tables,
@@ -354,14 +433,18 @@ def paged_decode_step(model, params, cache, tokens, idx, tables,
     block-table row (the scheduler's host-side copies — see
     :func:`_set_paged_leaves`). Every slot computes (static shapes);
     the scheduler simply ignores emissions from slots it knows are free.
-    Returns ``(cache', next_tokens [S])``."""
+    Returns ``(cache', next_tokens [S])``, with the routed experts of
+    every slot's position behind the tokens where the model sows them
+    (:func:`with_routed`)."""
     cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
                               jnp.asarray(tables, jnp.int32))
+    mutable = ["cache", "intermediates"] \
+        if getattr(model, "routed_layers", 0) else ["cache"]
     logits, upd = model.apply(
         {"params": params, "cache": cache}, tokens[:, None],
-        mutable=["cache"])
+        mutable=mutable)
     picked = _pick_tokens(logits[:, -1, :], rng, temperature, top_k, top_p)
-    return upd["cache"], picked
+    return upd["cache"], with_routed(picked, upd)
 
 
 # the jitted program of paged_step_fns carries the name
@@ -387,7 +470,9 @@ def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     still on the device, and ``feed`` the host's part
     (:func:`pack_step_feed`: tokens, cursors, then the block tables in
     the one array), so a step can be dispatched before the one before
-    it has been read."""
+    it has been read. A model with ``routed_layers`` answers the routed
+    experts behind the tokens (:func:`with_routed`, as long as
+    :func:`answer_len` says), and the next step reads the first ``S``."""
     def paged_prefill(params, cache, table_row, tokens, tail_len, start,
                       key):
         return paged_prefill_into_slot(
@@ -552,6 +637,28 @@ def unmask(conf, masked, quota, threshold):
 _POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
 
 
+def pool_leaves(cache):
+    """``[(path, leaf)]`` of a paged cache's pool storage, in tree
+    order: the one place that says which leaves those are (block
+    shipping, the engine's byte counts and its ``kv_dtype``)."""
+    return [(path, leaf) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(cache)
+            if _leaf_name(path) in _POOL_LEAVES]
+
+
+def pool_leaves_by_table(cache):
+    """``{table leaf name: [pool leaves]}``: each kind's pools, a kind
+    being named by the table leaf that sits beside them in the same
+    attention module (:data:`TABLE_LEAVES`)."""
+    table_of = {path[:-1]: _leaf_name(path) for path, _
+                in jax.tree_util.tree_leaves_with_path(cache)
+                if _leaf_name(path) in TABLE_LEAVES}
+    out = {}
+    for path, leaf in pool_leaves(cache):
+        out.setdefault(table_of[path[:-1]], []).append(leaf)
+    return out
+
+
 def _path_key(path):
     """Stable string key of one cache-leaf path (e.g.
     ``block_0/attn/cached_key``) — the wire name a shipped row set is
@@ -572,11 +679,8 @@ def gather_block_rows(cache, block_ids):
     import numpy as np
 
     ids = np.asarray(list(block_ids), np.int32)
-    out = []
-    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        if _leaf_name(path) in _POOL_LEAVES:
-            out.append((_path_key(path), np.asarray(leaf)[ids]))
-    return out
+    return [(_path_key(path), np.asarray(leaf)[ids])
+            for path, leaf in pool_leaves(cache)]
 
 
 def scatter_block_rows(cache, block_ids, rows):

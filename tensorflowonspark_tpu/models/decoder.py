@@ -68,7 +68,7 @@ class PagedKV(object):
     """One attention module's paged K/V cache: the ONE place the pool,
     the block table and the write cursor are declared, written and
     attended through, shared by every decoder family (``DecoderLM``
-    here, ``models/sdar_moe.py``).
+    here, ``models/sdar_moe.py``, ``models/mellum_moe.py``).
 
     Built inside a module's ``__call__`` it declares, in ``mod``'s
     ``cache`` collection, the flat pools ``cached_key``/``cached_value``
@@ -79,16 +79,25 @@ class PagedKV(object):
     total_len; entry 0, the scratch block, everywhere until the host
     allocator assigns real blocks) and the per-row ``cache_index [B]``.
     ``initialized`` is False on that creation pass (shapes only).
+
+    ``window`` makes it the cache of a WINDOW layer: a query sees the
+    last ``window`` positions only, so the host keeps only the blocks
+    that reach back that far and parks scratch in the table entries
+    behind them. Such a layer is another KIND of cache with a pool of
+    its own size, and its table is declared under another name,
+    ``window_table``: the leaf's name is what tells the host's feed
+    which kind's table goes where (generation.TABLE_LEAVES).
     """
 
     def __init__(self, mod, b, s, kv_heads, head_dim, dtype, block_size,
-                 blocks, quantized=False):
+                 blocks, quantized=False, window=None):
         if blocks < 2:
             raise ValueError(
                 "paged decode needs kv_blocks >= 2 (row 0 is the scratch "
                 "block), got {}".format(blocks))
         self.initialized = mod.has_variable("cache", "cached_key")
         self.block_size, self.quantized = block_size, quantized
+        self.window = window
         shape = (blocks, block_size, kv_heads * head_dim)
         store = jnp.int8 if quantized else dtype
         self.key = mod.variable("cache", "cached_key", jnp.zeros, shape,
@@ -107,7 +116,7 @@ class PagedKV(object):
                 "cache", "value_scale", jnp.ones, shape[:2] + (kv_heads,),
                 jnp.float32)
         self.table = mod.variable(
-            "cache", "block_table",
+            "cache", "block_table" if window is None else "window_table",
             lambda: jnp.zeros((b, -(-s // block_size)), jnp.int32))
         # Per-ROW write cursor [B], not a scalar: each batch row is an
         # independent sequence (a serving "slot") at its own depth.
@@ -120,11 +129,24 @@ class PagedKV(object):
 
     def attend(self, q, k, v, pos, visible=None):
         """Write ``k``/``v`` ``[B, s, kv_heads, D]`` at ``pos`` through
-        the block table, advance the cursor by ``s``, and attend ``q
-        [B, s, heads, D]`` through the table: query ``i`` of row ``b``
-        sees every key position ``<= visible[b, i]`` (``pos`` itself
-        when None: causal), streaming the row's LIVE blocks through an
-        online softmax."""
+        the block table (:meth:`write`), and attend ``q [B, s, heads,
+        D]`` through the table: query ``i`` of row ``b`` sees every key
+        position ``<= visible[b, i]`` (``pos`` itself when None:
+        causal) and, with a window, ``> visible[b, i] - window``,
+        streaming the row's LIVE blocks through an online softmax."""
+        pa, pk, pv, ksc, vsc = self.write(k, v, pos)
+        return pa.paged_attention(
+            q, pk, pv, self.table.value, pos if visible is None else visible,
+            scale=q.shape[-1] ** -0.5, impl=None,
+            k_scale=ksc, v_scale=vsc, window=self.window)
+
+    def write(self, k, v, pos):
+        """Write ``k``/``v`` ``[B, s, kv_heads, D]`` at ``pos`` through
+        the block table and advance the cursor by ``s``. A position
+        whose table entry is scratch (pad rows past the sequence, a
+        window layer's positions that no later query will see) lands in
+        scratch. Alone, it is the cache's part of a call that attends
+        its own K and V (a prefill from position 0)."""
         import importlib
 
         pa = importlib.import_module(
@@ -160,10 +182,7 @@ class PagedKV(object):
         self.key.value = pk
         self.value.value = pv
         self.index.value = self.index.value + s
-        return pa.paged_attention(
-            q, pk, pv, table, pos if visible is None else visible,
-            scale=q.shape[-1] ** -0.5, impl=None,
-            k_scale=ksc, v_scale=vsc)
+        return pa, pk, pv, ksc, vsc
 
 
 class CausalSelfAttention(nn.Module):

@@ -58,14 +58,19 @@ def rmsnorm(x, scale, eps):
             * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, pos, theta):
+def rope(x, pos, theta, inv_freq=None, factor=None):
     """Rotate-half RoPE: ``x [B, S, heads, D]`` at ``pos [B, S]``,
-    angles in float32."""
+    angles in float32. ``inv_freq [D/2]`` replaces the plain
+    ``theta ** (-2i / D)`` and ``factor`` multiplies cos and sin (a
+    scaled RoPE: models/mellum_moe.py's YaRN layers)."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[..., None] * inv          # [B, S, D/2]
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, :, None, :]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, :, None, :]
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     x32 = x.astype(jnp.float32)
     half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
     return (x32 * cos + half * sin).astype(x.dtype)

@@ -73,6 +73,17 @@ width over ``D`` is the number of K/V heads, query head ``h`` reads K/V
 head ``h // group``, and the group is folded into query rows before any
 formulation runs (:func:`paged_attention`).
 
+A WINDOW (``window=w``; models/mellum_moe.py's sliding layers): query
+``i`` sees key ``j`` iff ``0 <= pos_i - j < w``. The row's table is the
+same logical one (entry ``p // block_size`` for position ``p``), the
+blocks behind the window being whatever the host parked there (scratch,
+once it has given them back). No formulation walks them: before any of
+them runs the row's table and positions are shifted so that the first
+block its queries can still see is block 0 (:func:`_window_view`), the
+work list and the loops count from there, and the one thing a
+formulation adds is the mask's lower edge. ``window=None`` traces the
+program it always traced.
+
 Pool layout (PR 30): the pools are FLAT, heads and head_dim in one
 minor axis, because of what the chip does with anything else. The TPU
 tiles an array's two minor dimensions ``(8, 128)``; a ``[.., N, 64]``
@@ -144,8 +155,21 @@ def _nblocks(pos, block_size, table_width):
                        // block_size, table_width)
 
 
+def _window_view(block_table, pos, block_size, window):
+    """``(table', pos')`` for a windowed call: each row's table rolled
+    left so that entry 0 is the first block ANY of its queries still
+    sees, and its positions counted from that block's first token. What
+    lies past the row's last block after the roll is never visited
+    (``_nblocks`` counts from the shifted positions)."""
+    mb = block_table.shape[1]
+    first = jnp.maximum(jnp.min(pos, axis=-1) - window + 1, 0) // block_size
+    slots = jnp.minimum(first[:, None] + jnp.arange(mb)[None, :], mb - 1)
+    return (jnp.take_along_axis(block_table, slots, axis=1),
+            pos - (first * block_size)[:, None])
+
+
 def _gather(q, k_pool, v_pool, block_table, pos, scale, k_scale=None,
-            v_scale=None):
+            v_scale=None, window=None):
     """PR 8's XLA formulation, verbatim: materialize the logical
     ``[B, L, N, D]`` view through the table, one softmax over it
     (int8 pools dequantize into the materialized view — the reference
@@ -166,6 +190,8 @@ def _gather(q, k_pool, v_pool, block_table, pos, scale, k_scale=None,
     logits = logits * scale
     visible = (jnp.arange(L)[None, None, :]
                <= pos[:, :, None])                   # [B, s, L]
+    if window is not None:
+        visible &= jnp.arange(L)[None, None, :] > pos[:, :, None] - window
     logits = jnp.where(visible[:, None, :, :], logits,
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
@@ -187,7 +213,7 @@ _STATIC_TRIP_MAX_BLOCKS = 8
 
 
 def _blockwise(q, k_pool, v_pool, block_table, pos, scale,
-               k_scale=None, v_scale=None):
+               k_scale=None, v_scale=None, window=None):
     """Online-softmax over each row's live blocks, pure ``lax``: the
     CPU tier-1 formulation of the fused kernel (and the fallback for
     any non-TPU backend). ONE ``fori_loop`` — iteration ``j`` gathers
@@ -228,6 +254,8 @@ def _blockwise(q, k_pool, v_pool, block_table, pos, scale,
         kpos = jj[:, None] * bs_blk + jnp.arange(bs_blk)[None, :]
         vis = (kpos[:, None, :] <= pos[:, :, None]) \
             & (j < nblk)[:, None, None]          # [B, s, bs]
+        if window is not None:
+            vis &= kpos[:, None, :] > pos[:, :, None] - window
         sc = jnp.where(vis[:, :, None, :], sc, -jnp.inf)
         m_blk = jnp.max(sc, axis=-1)             # [B, s, N]
         m_new = jnp.maximum(m, m_blk)
@@ -273,7 +301,8 @@ def _work_list(nblk, width):
     return ends[-1], pair, slot * 4 + (slot == 0) * 2 + last
 
 
-def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
+def _paged_kernel(*refs, scale, block_size, num_heads, quantized,
+                  window=None):
     """One step of the work list (:func:`_work_list`): fold ONE live
     pool block — every head of it — into the online-softmax
     accumulators of its (row, q tile) pair; zero them on the pair's
@@ -306,6 +335,8 @@ def _paged_kernel(*refs, scale, block_size, num_heads, quantized):
     kpos = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_size), 1)
     vis = kpos <= pos_ref[0]                                # [bq, bs]
+    if window is not None:
+        vis &= kpos > pos_ref[0] - window
     if quantized:
         # a head's scales as a ROW [1, bs], laid on its scores and
         # its probabilities: q.(c*s) = (q.c)*s, p@(c*s) = (p*s)@c.
@@ -350,7 +381,7 @@ _BLOCK_Q = 128
 
 
 def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
-            k_scale=None, v_scale=None):
+            k_scale=None, v_scale=None, window=None):
     """The TPU kernel over a work list: a ONE-axis grid whose bound is
     the number of live (row, q tile, block) triples, a traced scalar,
     with the lists and the block table as scalar prefetch for the
@@ -415,7 +446,7 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
     )
     kernel = functools.partial(_paged_kernel, scale=scale,
                                block_size=bs_blk, num_heads=n,
-                               quantized=quantized)
+                               quantized=quantized, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -432,7 +463,7 @@ def _pallas(q, k_pool, v_pool, block_table, pos, scale, interpret,
 
 def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
                     impl=None, interpret=None, force_pallas=False,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, window=None):
     """Attend ``q`` against paged K/V through ``block_table``.
 
     ``pos [B, S_q]`` is each query's logical position (it sees key
@@ -450,7 +481,9 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
     ``k_scale``/``v_scale``
     (``[P, block_size, heads]`` float32, both or neither) mark the
     pools as int8 codes and dequantize them inside the chosen
-    formulation — see the module docstring's int8-KV section."""
+    formulation — see the module docstring's int8-KV section.
+    ``window`` bounds what a query sees from below (module docstring,
+    "A WINDOW"): key positions ``> pos - window`` only."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     pos = jnp.asarray(pos, jnp.int32)
     block_table = jnp.asarray(block_table, jnp.int32)
@@ -481,22 +514,26 @@ def paged_attention(q, k_pool, v_pool, block_table, pos, scale=None,
         out = paged_attention(
             q, k_pool, v_pool, block_table, jnp.repeat(pos, group, axis=1),
             scale=scale, impl=impl, interpret=interpret,
-            force_pallas=force_pallas)
+            force_pallas=force_pallas, window=window)
         return out.reshape(b, s_q, group, kv_heads, d) \
             .transpose(0, 1, 3, 2, 4).reshape(b, s_q, n, d)
     if impl in (None, "auto"):
         impl = "pallas" if (force_pallas or on_tpu()) else "blockwise"
     if impl == "gather":
         return _gather(q, k_pool, v_pool, block_table, pos, scale,
-                       k_scale=k_scale, v_scale=v_scale)
+                       k_scale=k_scale, v_scale=v_scale, window=window)
+    if window is not None:
+        block_table, pos = _window_view(block_table, pos, k_pool.shape[1],
+                                        int(window))
     if impl == "blockwise":
         return _blockwise(q, k_pool, v_pool, block_table, pos, scale,
-                          k_scale=k_scale, v_scale=v_scale)
+                          k_scale=k_scale, v_scale=v_scale, window=window)
     if impl == "pallas":
         if interpret is None:
             interpret = not on_tpu()
         return _pallas(q, k_pool, v_pool, block_table, pos, scale,
-                       interpret, k_scale=k_scale, v_scale=v_scale)
+                       interpret, k_scale=k_scale, v_scale=v_scale,
+                       window=window)
     raise ValueError(
         "unknown paged-attention impl {!r}; expected one of "
         "None/'auto', 'pallas', 'blockwise', 'gather'".format(impl))

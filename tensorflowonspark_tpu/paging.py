@@ -515,3 +515,190 @@ class BlockPool(object):
                     self._lru.move_to_end(bid)
                 else:
                     self._free.append(bid)
+
+
+# -- caches by layer kind (PR 36) ---------------------------------------
+#
+# A model whose layers are not all of one kind keeps more than one kind
+# of cache a sequence: a FULL layer holds ``ceil(len / block_size)``
+# blocks, a WINDOW layer only the blocks that reach back ``window``
+# positions from the newest one. Each kind has a pool of its own size
+# and a table of its own in every slot; what a slot holds, needs and
+# gives back is asked of the kinds together, below. Further kinds (a
+# recurrent state a slot, a latent row a token) are further classes
+# with these methods; nothing in the engine asks which kind it holds.
+
+
+class FullKind(object):
+    """The cache of layers that keep every position: a sequence holds
+    blocks ``0 .. ceil(len / block_size) - 1``. The one kind whose
+    blocks can be shared by prefix (the pool's registry)."""
+
+    name, window = "full", None
+    #: the cache leaf that holds this kind's table on the device
+    #: (generation.TABLE_LEAVES); its pools sit beside it
+    table_leaf = "block_table"
+
+    def __init__(self, pool, tables):
+        self.pool, self.tables = pool, tables
+        self.width = tables.shape[1]
+        #: per slot the ids held, in block order, and the index of the
+        #: first (0 here, always)
+        self.blocks = [[] for _ in range(tables.shape[0])]
+        self.first = [0] * tables.shape[0]
+        self.block_bytes = 0    # set from the cache's leaves
+
+    def first_seen(self, cursor):
+        """Index of the first block a step that writes ``cursor`` (and
+        every later one) still reads."""
+        return 0
+
+    def need(self, n_tokens):
+        """Blocks a sequence holds once ``n_tokens`` are written."""
+        return self.pool.blocks_for(n_tokens) \
+            - self.first_seen(n_tokens)
+
+    def lacks(self, slot, upto):
+        """Blocks the slot still needs to hold block index ``upto``."""
+        return min(upto + 1, self.width) - self.first[slot] \
+            - len(self.blocks[slot])
+
+    def place(self, slot, ids, first=0):
+        """The slot holds ``ids`` from block index ``first`` on."""
+        self.blocks[slot], self.first[slot] = list(ids), first
+        row = self.tables[slot]
+        row[:] = 0
+        row[first:first + len(ids)] = ids
+
+    def admit(self, slot, n_tokens):
+        """Allocate what :meth:`need` says; raises PoolExhausted
+        holding nothing."""
+        first = self.first_seen(n_tokens)
+        self.place(slot, self.pool.alloc(self.need(n_tokens)), first)
+
+    def grow(self, slot, upto):
+        """Hold every block up to index ``upto`` (clamped to the
+        table): one ``alloc`` a missing block, so PoolExhausted leaves
+        the slot as far as it got. Returns the blocks added."""
+        added = 0
+        while self.lacks(slot, upto) > 0:
+            new_id = self.pool.alloc(1)[0]
+            self.tables[slot][self.first[slot]
+                              + len(self.blocks[slot])] = new_id
+            self.blocks[slot].append(new_id)
+            added += 1
+        return added
+
+    def trim(self, slot, cursor):
+        """Give back the blocks no step from ``cursor`` on reads.
+        Returns how many (a full layer's: none, ever)."""
+        drop = self.first_seen(cursor) - self.first[slot]
+        if drop <= 0 or not self.blocks[slot]:
+            return 0
+        gone = self.blocks[slot][:drop]
+        self.pool.release(gone)
+        self.blocks[slot] = self.blocks[slot][len(gone):]
+        self.tables[slot][self.first[slot]:self.first[slot] + len(gone)] = 0
+        self.first[slot] += len(gone)
+        return len(gone)
+
+    def release(self, slot):
+        if self.blocks[slot]:
+            self.pool.release(self.blocks[slot])
+        self.blocks[slot], self.first[slot] = [], 0
+        self.tables[slot][:] = 0
+
+    def in_use(self):
+        return self.pool.num_blocks - self.pool.allocatable()
+
+    def grid_steps(self, cursors, span):
+        """Table slots the attention kernel walks for rows at
+        ``cursors`` feeding ``span`` positions each (an idle row at
+        cursor 0: its one block)."""
+        return np.minimum(
+            (cursors + span - 1) // self.pool.block_size + 1, self.width)
+
+
+class WindowKind(FullKind):
+    """The cache of layers whose query at ``i`` sees ``i - window <
+    j <= i``: a sequence holds the blocks from the one with position
+    ``cursor - window + 1`` on, at most ``ceil((window - 1) /
+    block_size) + 1`` of them, and the blocks behind are given back
+    (:meth:`trim`) in the turn the window leaves them."""
+
+    name, table_leaf = "window", "window_table"
+
+    def __init__(self, pool, tables, window):
+        FullKind.__init__(self, pool, tables)
+        self.window = int(window)
+
+    def first_seen(self, cursor):
+        return np.maximum(cursor - self.window + 1, 0) \
+            // self.pool.block_size
+
+    @staticmethod
+    def most_a_slot(window, block_size):
+        """Blocks that hold ``window`` positions wherever they start."""
+        return -(-(int(window) - 1) // int(block_size)) + 1
+
+    def grid_steps(self, cursors, span):
+        last = np.minimum((cursors + span - 1) // self.pool.block_size,
+                          self.width - 1)
+        return last - np.minimum(self.first_seen(cursors), last) + 1
+
+
+class CacheKinds(object):
+    """Every kind of cache an engine's slots hold, under one roof: one
+    ``tables [slots, sum of widths]`` array (each kind's columns side
+    by side in generation.TABLE_LEAVES order: the step's feed takes it
+    whole) and the kinds, the full one first. ``kinds`` names the
+    others and their parameters (a model's ``cache_kinds``:
+    ``{"window": positions}``); ``blocks`` is the full kind's pool
+    size. The window kind's is every slot's window
+    (:meth:`WindowKind.most_a_slot`): a slot that is free holds none of
+    it, so that pool never runs short and admission counts the full
+    kind alone."""
+
+    def __init__(self, slots, width, block_size, blocks, kinds=None,
+                 kv_dtype="float32"):
+        kinds = dict(kinds or {})
+        unknown = sorted(set(kinds) - {"window"})
+        if unknown:
+            raise ValueError("unknown cache kind(s) {}".format(unknown))
+        n = 1 + len(kinds)
+        self.tables = np.zeros((slots, n * width), np.int32)
+        self.full = FullKind(
+            BlockPool(blocks, block_size, kv_dtype=kv_dtype),
+            self.tables[:, :width])
+        self.kinds = [self.full]
+        if "window" in kinds:
+            n_blocks = slots * min(width, WindowKind.most_a_slot(
+                kinds["window"], block_size))
+            self.kinds.append(WindowKind(
+                BlockPool(n_blocks, block_size, kv_dtype=kv_dtype),
+                self.tables[:, width:2 * width], kinds["window"]))
+
+    def __iter__(self):
+        return iter(self.kinds)
+
+    def others(self):
+        """The kinds beside the full one."""
+        return self.kinds[1:]
+
+    def set_block_bytes(self, leaves_by_table):
+        """Bytes ONE block of each kind costs over all its layers, read
+        off the cache's own pool leaves (``{table leaf name: [leaves
+        [blocks, block_size, ...]]}``, generation.pool_leaves_by_table):
+        whatever a token costs there, K and V of every head at the
+        pool's dtype or an int8 pool's scales beside its codes, is in
+        their shapes."""
+        for k in self.kinds:
+            k.block_bytes = sum(
+                int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
+                for leaf in leaves_by_table.get(k.table_leaf, ()))
+
+    def bytes_per_token(self):
+        """``{kind: bytes a cached token costs}`` over the kind's
+        layers."""
+        return {k.name: k.block_bytes // k.pool.block_size
+                for k in self.kinds}

@@ -598,7 +598,8 @@ class DecodeEngine(object):
         sequence outgrowing the pool preempts the youngest admission,
         which resumes seamlessly when blocks free).
       prefix_cache: share resident prefix blocks across requests
-        (default True). Full blocks of every prompt are
+        (default: on wherever the model's caches can be shared, which
+        True insists on). Full blocks of every prompt are
         registered under their exact token chain at admission, and
         full blocks DECODE fills are registered as the sequence grows
         (PR 11: generated-prefix registration) — so a multi-turn
@@ -663,7 +664,7 @@ class DecodeEngine(object):
                  eos_token=None, rng=None, counters=None, timers=None,
                  max_queue=1024, metrics=None, flight=None,
                  replica_id=None, kv_block_size=None, kv_blocks=None,
-                 prefix_cache=True, speculate_k=None,
+                 prefix_cache=None, speculate_k=None,
                  draft_layers=None, kv_dtype=None, tier=None,
                  qos_policy=None):
         import jax
@@ -841,6 +842,17 @@ class DecodeEngine(object):
             # so no block of such a sequence is ever registered for
             # sharing
             prefix_cache = False
+        #: the kinds of cache a slot holds beside the full one, read
+        #: off the MODEL as ``block_len`` is (models/mellum_moe.py:
+        #: ``{"window": positions}``); none for a model whose layers
+        #: all keep every position
+        kinds = dict(getattr(model, "cache_kinds", None) or {})
+        if kinds:
+            self._check_cache_kinds(
+                model, kinds, prefix_cache=prefix_cache,
+                speculate_k=speculate_k, kv_dtype=kv_dtype, tier=tier)
+        if prefix_cache is None:
+            prefix_cache = not kinds
         norm_top_k = None if top_k is None else int(top_k)
         norm_top_p = None if top_p is None else float(top_p)
         # int8 KV knob (PR 15): None / "fp32" / "float32" keep the
@@ -879,9 +891,19 @@ class DecodeEngine(object):
             raise ValueError("kv_blocks must be >= 1, got {}".format(
                 self.kv_blocks))
         self.prefix_cache = bool(prefix_cache)
-        self._pool = paging.BlockPool(
-            self.kv_blocks, self.kv_block_size,
+        #: every kind of cache the slots hold (paging.CacheKinds): what
+        #: a sequence needs, holds and gives back is asked of the kinds
+        #: together. ``_pool``, ``_slot_blocks`` and the first columns
+        #: of ``_tables`` are the FULL kind's, the one whose blocks are
+        #: shared by prefix, shipped and mirrored by a draft, and the
+        #: one ``kv_blocks`` sizes: a window kind's pool holds every
+        #: slot's window and never runs short.
+        self._kv = paging.CacheKinds(
+            self.slots, self._blocks_per_slot, self.kv_block_size,
+            self.kv_blocks, kinds,
             kv_dtype="int8" if self._kv_quant else "float32")
+        self._pool = self._kv.full.pool
+        self._other_kinds = self._kv.others()
         self._last_prefix_evictions = 0
         self._last_prefix_hits = 0
         self._last_prefix_misses = 0
@@ -893,6 +915,9 @@ class DecodeEngine(object):
         self._head_block_memo = None
         clone_kw = dict(kv_block_size=self.kv_block_size,
                         kv_blocks=self.kv_blocks + 1)
+        for kind in self._other_kinds:
+            clone_kw["kv_{}_blocks".format(kind.name)] = \
+                kind.pool.num_blocks + 1
         if self._kv_quant:
             clone_kw["kv_dtype"] = "int8"
         try:
@@ -990,7 +1015,12 @@ class DecodeEngine(object):
         # next step's input, per slot the request it was dispatched for
         # (None: the row was idle) and when. A token engine keeps at
         # most one; block-stepping and speculative engines none, ever.
-        self._picked = jnp.zeros((self.slots,), jnp.int32)
+        # (behind the tokens, the routed experts of a model that sows
+        # them: generation.with_routed)
+        self._picked = jnp.zeros(
+            (generation.answer_len(model, self.slots, self.slots),),
+            jnp.int32)
+        self._top_k = int(getattr(model, "experts_per_tok", 0))
         self._flight = None   # or (picked, [request or None] * S, t0)
         self._read_at = 0.0   # when a step's tokens were last read
         # how often it engages and what it cost, exported from the
@@ -999,14 +1029,17 @@ class DecodeEngine(object):
         self.counters.inc("tokens_dropped_in_flight", 0)
         self.counters.inc("attn_grid_steps", 0)
         self.counters.inc("attn_table_slots", 0)
+        for kind in self._other_kinds:
+            for name in ("kv_{}_block_steps", "kv_{}_blocks_given_back",
+                         "attn_{}_grid_steps", "attn_{}_table_slots"):
+                self.counters.inc(name.format(kind.name), 0)
         # host-authoritative block tables: row s mirrors
         # _slot_blocks[s] padded with scratch (0). A freed slot's
         # row resets to scratch AND its cursor to 0, so the idle
         # slot's per-step write lands in the scratch block instead
         # of whatever its released blocks became.
-        self._slot_blocks = [[] for _ in range(self.slots)]
-        self._tables = np.zeros(
-            (self.slots, self._blocks_per_slot), np.int32)
+        self._slot_blocks = self._kv.full.blocks
+        self._tables = self._kv.tables
         self._admit_seq = itertools.count()
         self._slot_seq = [0] * self.slots
         # generated-prefix registration cursor (PR 11): how many
@@ -1034,9 +1067,10 @@ class DecodeEngine(object):
         #: ("int8" on the quantized fast path, the compute dtype name
         #: otherwise; one source of truth: the live cache leaves)
         self.kv_dtype = next(
-            (str(leaf.dtype) for path, leaf in
-             jax.tree_util.tree_leaves_with_path(self._cache)
-             if generation._leaf_name(path) == "cached_key"), "none")
+            (str(leaf.dtype) for _, leaf
+             in generation.pool_leaves(self._cache)), "none")
+        self._kv.set_block_bytes(
+            generation.pool_leaves_by_table(self._cache))
         if self._spec_k:
             # the draft's own cache pytree (draft_layers/num_layers of
             # the target's KV bytes); tables and cursors stay host-
@@ -1080,6 +1114,30 @@ class DecodeEngine(object):
             raise ValueError(
                 "{} generates by diffusion over blocks of {}: {}".format(
                     type(model).__name__, b, why))
+
+    @staticmethod
+    def _check_cache_kinds(model, kinds, prefix_cache, speculate_k,
+                           kv_dtype, tier):
+        """Refuse, with the reason, what an engine does not do yet for
+        a model whose layers keep more than one kind of cache
+        (docs/serving.md, "Two kinds of cache")."""
+        why = None
+        if prefix_cache:
+            why = "a prefill attends its own K and V from position 0 " \
+                  "and a window layer keeps no block to share: no " \
+                  "prefix_cache"
+        elif speculate_k is not None:
+            why = "a call of several positions is a prefill from " \
+                  "position 0, not a verify: no speculate_k"
+        elif kv_dtype not in (None, "fp32", "float32"):
+            why = "its pools are stored at the model's dtype: no kv_dtype"
+        elif tier != "mixed":
+            why = "kvship frames one kind of block: no tier={!r}".format(
+                tier)
+        if why:
+            raise ValueError("{} keeps {} caches a slot ({}): {}".format(
+                type(model).__name__, 1 + len(kinds),
+                ", ".join(["full"] + sorted(kinds)), why))
 
     # -- client API ------------------------------------------------------
 
@@ -1475,19 +1533,17 @@ class DecodeEngine(object):
         scratch row, and the per-head scales an int8 pool carries
         alongside its codes), plus the draft model's pool when
         speculating."""
-        import jax
-
         caches = [self._cache]
         if self._spec_k:
             caches.append(self._draft_cache)
-        total = 0
-        for cache in caches:
-            for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-                if self._generation._leaf_name(path) in (
-                        "cached_key", "cached_value",
-                        "key_scale", "value_scale"):
-                    total += leaf.size * leaf.dtype.itemsize
-        return total
+        return sum(leaf.size * leaf.dtype.itemsize for cache in caches
+                   for _, leaf in self._generation.pool_leaves(cache))
+
+    def kv_bytes_per_token(self):
+        """``{kind: bytes one cached token costs over the kind's
+        layers}``, by the pool leaves' own shapes
+        (paging.CacheKinds.set_block_bytes)."""
+        return self._kv.bytes_per_token()
 
     def outstanding(self):
         """Queued + in-flight request count (the number drain waits on)."""
@@ -1572,6 +1628,49 @@ class DecodeEngine(object):
             # ONE-program-per-engine-config contract
             stats["spec_round_programs"] = n_programs(self._round_fn)
         return stats
+
+    def precompile(self):
+        """Compile every prefill bucket's program and the decode step
+        side by side, before the first request: a cold start then waits
+        for the compiler's threads to get through them together and not
+        for one program after another (eight programs of a sparse-expert
+        model on 13 cores: 76 s for 121, PERF.md PR 36; from a warm
+        persistent cache they load no faster than one by one, and no
+        slower). The first calls find them compiled: the lowering made
+        here is the one a call makes. A token engine's only (no block
+        stepping, no speculation: their programs take other arguments);
+        call it while nothing is queued or in flight, since it reads the
+        shapes of the engine's own cache. Returns the programs
+        compiled."""
+        import concurrent.futures
+
+        import jax
+        import jax.numpy as jnp
+
+        if self._block_len or self._spec_k:
+            raise ValueError(
+                "precompile() knows a token engine's programs only (no "
+                "block stepping, no speculate_k)")
+        if self.outstanding():
+            raise RuntimeError("precompile() needs an idle engine")
+
+        def int32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        # the largest bucket first: it is the longest to compile
+        calls = [(self._prefill_fn, (
+            self.params, self._cache, int32(self._tables.shape[1]),
+            int32(b), int32(), int32(), self._key))
+            for b in sorted(self.buckets, reverse=True)]
+        calls.append((self._decode_fn, (
+            self.params, self._cache, self._picked,
+            self._generation.pack_step_feed(
+                np.full(self.slots, -1, np.int32),
+                np.zeros(self.slots, np.int32),
+                np.zeros_like(self._tables)), self._key)))
+        with concurrent.futures.ThreadPoolExecutor(len(calls)) as pool:
+            list(pool.map(lambda c: c[0].lower(*c[1]).compile(), calls))
+        return len(calls)
 
     def stop(self):
         """Stop the scheduler; queued and in-flight requests fail fast
@@ -1676,13 +1775,15 @@ class DecodeEngine(object):
         ``cursors[s]`` sees the blocks up to the last of the ``span``
         positions it feeds, an idle one at cursor 0 its one block.
         ``attn_grid_steps`` over ``attn_table_slots`` is the share of
-        the tables that is walked (one call's; every layer's is the
-        same)."""
-        width = self._tables.shape[1]
-        live = np.minimum(
-            (cursors + span - 1) // self.kv_block_size + 1, width)
-        self.counters.inc("attn_grid_steps", int(live.sum()))
-        self.counters.inc("attn_table_slots", live.size * width)
+        the tables that is walked (one call's; every layer's of a kind
+        is the same, and a kind beside the full one counts under its
+        own name: ``attn_window_grid_steps``)."""
+        for kind in self._kv:
+            live = kind.grid_steps(cursors, span)
+            of = "attn_" if kind is self._kv.full \
+                else "attn_{}_".format(kind.name)
+            self.counters.inc(of + "grid_steps", int(live.sum()))
+            self.counters.inc(of + "table_slots", live.size * kind.width)
 
     def _ewma(self, prev, sample):
         return sample if prev is None \
@@ -1955,6 +2056,15 @@ class DecodeEngine(object):
                 # for them, so the next admission scan can reuse them
                 with self.timers.timed("evict"):
                     self._evict_expired(time.monotonic())
+                if self._other_kinds:
+                    # what a window has left goes back to its pool in
+                    # this very turn, a stage of its own, BEFORE the
+                    # turn's growth: where a block is left and the next
+                    # begun in one turn (window = 1 mod block size) a
+                    # slot never holds more than its window's blocks,
+                    # which is all its pool has for it
+                    with self.timers.timed("trim_blocks"):
+                        self._trim_active_blocks()
                 # lazy block growth (and, under exhaustion,
                 # youngest-first preemption) for every slot whose
                 # NEXT write crosses a block boundary
@@ -2066,7 +2176,8 @@ class DecodeEngine(object):
                 self._flight = (self._picked, owners, t0)
             if older is not None:
                 with self.timers.timed("step_sync"):
-                    toks = np.asarray(older[0])
+                    toks, routed = self._generation.split_routed(
+                        older[0], self.slots, self.slots, self._top_k)
         t1 = time.monotonic()
         # engine-row span (tid 0): this turn's share of the loop
         self.flight.span("decode_step", t0, t1, active=len(rows),
@@ -2081,6 +2192,9 @@ class DecodeEngine(object):
             self.counters.inc(
                 "kv_block_steps",
                 self._pool.num_blocks - self._pool.allocatable())
+            for kind in self._other_kinds:
+                self.counters.inc("kv_{}_block_steps".format(kind.name),
+                                  kind.in_use())
         if older is None:
             return
         # the pace, read to read (dispatch to read after a pause):
@@ -2091,6 +2205,11 @@ class DecodeEngine(object):
         self._step_ewma = self._ewma(self._step_ewma, pace)
         self._hist_step.observe(pace)
         with self.timers.timed("host_schedule"):
+            if routed is not None:
+                # the rows the step was dispatched for: an idle slot's
+                # row is routed and multiplied too, and counted nowhere
+                self._count_expert_rows(
+                    routed, np.array([h is not None for h in older[1]]))
             delivered = 0
             for s, handle in enumerate(older[1]):
                 if handle is None:
@@ -2337,6 +2456,11 @@ class DecodeEngine(object):
         self.counters.gauge("kv_blocks_total", stats["total"])
         self.counters.gauge("kv_blocks_free", stats["free"])
         self.counters.gauge("kv_blocks_cached", stats["cached"])
+        for kind in self._other_kinds:
+            self.counters.gauge("kv_{}_blocks_total".format(kind.name),
+                                kind.pool.num_blocks)
+            self.counters.gauge("kv_{}_blocks_free".format(kind.name),
+                                kind.pool.allocatable())
         # digest exposition (PR 16): how many chains the beat-carried
         # digest currently publishes, and whether the top-K bound cut
         # anything (the truncation-honesty flag, scrapeable)
@@ -2372,10 +2496,8 @@ class DecodeEngine(object):
         already re-allocated — blocks. Private blocks go back to the
         free list; registered prefix blocks decref into the LRU cache
         (still hittable, evicted only under pressure)."""
-        if self._slot_blocks[slot]:
-            self._pool.release(self._slot_blocks[slot])
-            self._slot_blocks[slot] = []
-        self._tables[slot][:] = 0
+        for kind in self._kv:
+            kind.release(slot)
         self._idx[slot] = 0
         self._slot_registered[slot] = 0
         self._publish_kv_gauges()
@@ -2459,6 +2581,11 @@ class DecodeEngine(object):
             raise ValueError(
                 "an engine that steps by blocks neither ships nor adopts "
                 "K/V blocks (no block of it is registered for sharing)")
+        if self._other_kinds:
+            raise ValueError(
+                "an engine whose slots hold {} kinds of cache neither "
+                "ships nor adopts K/V blocks (kvship frames one kind)"
+                .format(len(self._kv.kinds)))
         job["done"] = threading.Event()
         job["error"] = None
         job["result"] = None
@@ -2716,28 +2843,43 @@ class DecodeEngine(object):
             cover = min(look,
                         max(1, handle.max_new_tokens
                             - len(handle._tokens) - self._in_flight(s)))
-            need = min((int(self._idx[s]) + cover - 1) // bs + 1,
-                       self._blocks_per_slot)
-            if len(self._slot_blocks[s]) >= need:
+            upto = (int(self._idx[s]) + cover - 1) // bs
+            kinds = [k for k in self._kv if k.lacks(s, upto) > 0]
+            if not kinds:
                 continue
-            while self._slot_req[s] is not None \
-                    and len(self._slot_blocks[s]) < need:
-                try:
-                    with self.timers.timed("block_alloc"):
-                        new_id = self._pool.alloc(1)[0]
-                except paging.PoolExhausted:
-                    victim = max(
-                        self._active_slots(),
-                        key=lambda v: (
-                            qos.priority_rank(
-                                self._slot_req[v].priority),
-                            self._slot_seq[v]))
-                    # preempting s itself clears its slot_req and
-                    # ends the while
-                    self._preempt(victim)
-                    continue
-                self._tables[s][len(self._slot_blocks[s])] = new_id
-                self._slot_blocks[s].append(new_id)
+            for kind in kinds:
+                while self._slot_req[s] is not None \
+                        and kind.lacks(s, upto) > 0:
+                    try:
+                        with self.timers.timed("block_alloc"):
+                            kind.grow(s, upto)
+                    except paging.PoolExhausted:
+                        victim = max(
+                            self._active_slots(),
+                            key=lambda v: (
+                                qos.priority_rank(
+                                    self._slot_req[v].priority),
+                                self._slot_seq[v]))
+                        # preempting s itself clears its slot_req and
+                        # ends the while
+                        self._preempt(victim)
+            self._publish_kv_gauges()
+
+    def _trim_active_blocks(self):
+        """Give back, for every active slot, the blocks that no step
+        from its cursor on reads (paging.WindowKind.trim: the blocks a
+        window has left). The step in flight may still read one of
+        them: whatever is written there next is a LATER program's, and
+        the device runs them in order."""
+        given_back = {}
+        for s in self._active_slots():
+            for kind in self._other_kinds:
+                n = kind.trim(s, int(self._idx[s]))
+                if n:
+                    given_back[kind.name] = given_back.get(kind.name, 0) + n
+        for name, n in given_back.items():
+            self.counters.inc("kv_{}_blocks_given_back".format(name), n)
+        if given_back:
             self._publish_kv_gauges()
 
     def _admit(self, slot, handle):
@@ -2776,11 +2918,14 @@ class DecodeEngine(object):
             except paging.PoolExhausted:
                 self._pool.release(shared)
                 raise
+            # the other kinds hold what the decode will still see of
+            # the prompt (a window layer: its last window), out of a
+            # pool that has it for every slot
+            for kind in self._other_kinds:
+                kind.admit(slot, n)
         ids = list(shared) + new_ids
-        self._slot_blocks[slot] = ids
-        row = self._tables[slot]
-        row[:] = 0
-        row[:len(ids)] = ids
+        self._kv.full.place(slot, ids)
+        row = self._tables[slot]    # every kind's columns, side by side
         self._slot_seq[slot] = next(self._admit_seq)
         # a block-stepping model prefills the sequence's WHOLE blocks
         # and samples nothing; what is left over starts its first block
@@ -2826,7 +2971,12 @@ class DecodeEngine(object):
                     self.params, self._cache, jnp.asarray(row),
                     jnp.asarray(toks), jnp.int32(len(tail)),
                     jnp.int32(start), self._next_key())
-                first = int(first)
+                first, routed = self._generation.split_routed(
+                    first, 1, bucket, self._top_k)
+                first = int(first[0])
+                if routed is not None:
+                    self._count_expert_rows(
+                        routed, np.arange(bucket) < len(tail))
             elif tail:
                 self._cache, routed = self._prefill_fn(
                     self.params, self._cache, jnp.asarray(row),

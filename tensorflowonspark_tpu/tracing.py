@@ -332,6 +332,41 @@ METRIC_FAMILIES = {
                         "the same steps (tfos_serving_attn_grid_steps "
                         "over it: the share of the tables the kernel "
                         "walks)"),
+    "tfos_serving_kv_window_block_steps":
+        ("counter", "", "blocks of the WINDOW layers' cache held by "
+                        "in-flight sequences, summed at every decode "
+                        "step, for a model with such layers (over "
+                        "tfos_serving_kv_block_steps: what the window "
+                        "layers keep of what they would with nothing "
+                        "given back)"),
+    "tfos_serving_kv_window_blocks_given_back":
+        ("counter", "", "blocks a window left behind and the engine "
+                        "returned to the window pool mid-sequence "
+                        "(the trim_blocks stage)"),
+    "tfos_serving_attn_window_grid_steps":
+        ("counter", "", "tfos_serving_attn_grid_steps for a window "
+                        "layer's call: the blocks from the one the "
+                        "window reaches back to, 1 for an idle slot"),
+    "tfos_serving_attn_window_table_slots":
+        ("counter", "", "tfos_serving_attn_table_slots for the window "
+                        "layers' table"),
+    "tfos_serving_expert_calls":
+        ("counter", "", "expert-layer calls counted (layers x steps "
+                        "and prefills), for a model with sparse experts "
+                        "(block steps, token steps and prefills alike)"),
+    "tfos_serving_expert_rows":
+        ("counter", "", "rows (positions x experts a token) the router "
+                        "sent to experts in those calls, over the "
+                        "positions that belong to a request"),
+    "tfos_serving_expert_rows_max":
+        ("counter", "", "per call the fullest expert's rows, summed "
+                        "(over tfos_serving_expert_rows_mean: the peak "
+                        "load)"),
+    "tfos_serving_expert_rows_mean":
+        ("counter", "", "per call the mean rows an expert, summed"),
+    "tfos_serving_experts_touched":
+        ("counter", "", "per call the experts with at least one row, "
+                        "summed: what the grouped product had to read"),
     "tfos_serving_admit_scans_blocked_slots":
         ("counter", "", "admission scans that left a queued request "
                         "waiting because no slot was free"),
@@ -359,6 +394,11 @@ METRIC_FAMILIES = {
     "tfos_serving_kv_blocks_free":
         ("gauge", "", "KV blocks obtainable right now (free list + "
                       "evictable prefix-cached)"),
+    "tfos_serving_kv_window_blocks_total":
+        ("gauge", "", "usable blocks in the pool of the window layers' "
+                      "cache (a model with such layers only)"),
+    "tfos_serving_kv_window_blocks_free":
+        ("gauge", "", "blocks of that pool obtainable right now"),
     "tfos_serving_kv_blocks_cached":
         ("gauge", "", "refcount-0 blocks retained by the prefix cache "
                       "(evictable subset of kv_blocks_free)"),
@@ -426,8 +466,10 @@ METRIC_FAMILIES = {
                              "children's: park (idle for want of "
                              "work) / qos_plan / preempt / kv_job / "
                              "admit (holds prefix_lookup, block_alloc, "
-                             "prefill) / evict / grow_blocks (holds "
-                             "block_alloc) / decode_step (holds "
+                             "prefill) / evict / trim_blocks (a model "
+                             "with window layers: their blocks given "
+                             "back) / grow_blocks (holds block_alloc) "
+                             "/ decode_step (holds "
                              "step_upload, step_dispatch, step_sync) / "
                              "host_schedule, and queue_wait (submit to "
                              "first admission, one sample a request); "

@@ -498,3 +498,74 @@ def test_paged_attention_with_grouped_heads_compiles_for_v5e(one_chip, s_q):
     compiled = lowered.compile()
     assert _has_kernel(compiled)
     assert "paged_attention" in compiled.as_text()
+
+
+# -- Mellum2-12B-A2.5B's kernels at its published widths -----------------
+
+#: hidden 2304, 32 query / 4 K/V heads of 128, 64 experts of 896 with 8
+#: a position, a window of 1,024; the engine's step is 32 slots of one
+#: position over 16,896-token tables in 128-token blocks
+#: (benchmarks/configs/mellum2-12b-a2.5b)
+MELLUM = dict(hidden=2304, heads=32, kv_heads=4, head_dim=128, experts=64,
+              moe_hidden=896, top_k=8, slots=32, total=16896, block=128,
+              window=1024)
+
+MELLUM_GMM_SHAPES = {
+    # (positions, K, N): the step's 32 positions into and out of the
+    # experts, and the 16,384-token prefill bucket
+    "step_up": (MELLUM["slots"], MELLUM["hidden"], MELLUM["moe_hidden"]),
+    "step_down": (MELLUM["slots"], MELLUM["moe_hidden"], MELLUM["hidden"]),
+    "prefill_16384_up": (16384, MELLUM["hidden"], MELLUM["moe_hidden"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MELLUM_GMM_SHAPES))
+def test_expert_gmm_at_mellum_widths_compiles_for_v5e(one_chip, name):
+    """64 experts of 2304 x 896 held: one weight matrix is 4.1 MB,
+    double-buffered in VMEM beside a tile of rows."""
+    from tensorflowonspark_tpu.ops import expert_gmm
+
+    positions, kdim, ndim = MELLUM_GMM_SHAPES[name]
+    held, m = MELLUM["experts"], positions * MELLUM["top_k"]
+    tm = expert_gmm.row_tile(m, held)
+    rows = -(-(m + held * (tm - 1)) // tm) * tm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def gmm(lhs, rhs, tile_expert, live):
+        return expert_gmm.expert_gmm(
+            lhs, rhs, {"tile_expert": tile_expert, "live": live}, tm,
+            impl="pallas", interpret=False)
+
+    compiled = _compile(gmm, sds((rows, kdim), jnp.bfloat16),
+                        sds((held, kdim, ndim), jnp.bfloat16),
+                        sds((rows // tm,), jnp.int32), sds((), jnp.int32))
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("window", [None, MELLUM["window"]],
+                         ids=["full_layer", "window_layer"])
+def test_paged_attention_of_a_mellum_step_compiles_for_v5e(one_chip, window):
+    """The decode step's call of either layer kind: 32 rows of one
+    position, group 8, over a bfloat16 pool of 128-token blocks and a
+    table 132 wide; the window layer's pool holds 9 blocks a slot and
+    its call carries the mask's lower edge."""
+    mb = MELLUM["total"] // MELLUM["block"]
+    blocks = MELLUM["slots"] * (mb if window is None else 9) + 1
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((blocks, MELLUM["block"],
+                MELLUM["kv_heads"] * MELLUM["head_dim"]), jnp.bfloat16)
+    lowered = jax.jit(
+        lambda *a: pa.paged_attention(*a, impl="pallas", interpret=False,
+                                      window=window)
+    ).lower(
+        sds((MELLUM["slots"], 1, MELLUM["heads"], MELLUM["head_dim"]),
+            jnp.bfloat16),
+        pool, pool, sds((MELLUM["slots"], mb), jnp.int32),
+        sds((MELLUM["slots"], 1), jnp.int32))
+    assert lowered.as_text().count('kernel_name = "paged_attention"') == 1
+    assert _has_kernel(lowered.compile())

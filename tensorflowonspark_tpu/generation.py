@@ -288,49 +288,127 @@ def pack_step_feed(given, idx, tables):
 # mid-sequence continuation branch reads the shared prefix K/V through
 # the table).
 #
-# The jitted wrappers donate the engine cache, so the scheduler's
-# steady-state loop updates the pool in place instead of copying it.
-# Every engine program is the jit of a NAMED function: a trace's host
-# spans then read ``PjitFunction(paged_prefill)`` and the device's
-# ``XLA Modules`` line ``jit_paged_prefill``, where a lambda leaves
-# every program ``<lambda>``.
+# WHAT CROSSES THE JIT BOUNDARY (PR 37). A call of a jitted program
+# costs the host by the buffers it hands over and, more, by those it
+# takes back, whatever they hold, and a token engine's gap is that call
+# where the device's step is shorter (GPT-2 large on a v5e, PERF.md
+# PR 37: 6.1 ms a call with 730 buffers in and 146 out, 73 of them small
+# arrays allocated anew each step, over a device step of 4.5 ms; 2.2 ms
+# with the pools alone crossing). So the cache crosses as its POOL
+# leaves only (:func:`init_pools`: K and V a layer, an int8 pool's
+# scales beside them), donated and answered in place. The cursor and
+# table leaves of the flax ``cache`` collection never cross: the host
+# is the authority on both, so each program builds them inside its
+# trace from what it is fed (:func:`_set_paged_leaves`,
+# :func:`_slot_view`; which leaves a model's cache has is asked of the
+# model, :func:`_cache_shapes`) and answers none of them. A step
+# allocates its tokens' array and nothing else.
+#
+# The PARAMETERS cross as the tree they are, a buffer a leaf. Stacking
+# the layers' like leaves into one array each (sliced again inside the
+# trace) takes the same call to 0.8 ms and was measured and left out:
+# the compiler stages a whole parameter through the chip's fast memory
+# beside the compute and reads a slice of a stack in place, so the
+# device's step went 4.48 -> 6.23 ms with the matrices stacked and
+# 4.48 -> 4.54 with the biases and norms alone (4-5 us a slice read),
+# and the device's step is the gap once the call is under it.
+#
+# The jitted wrappers donate the pools, so the scheduler's steady-state
+# loop updates them in place instead of copying them. Every engine
+# program is the jit of a NAMED function: a trace's host spans then
+# read ``PjitFunction(paged_prefill)`` and the device's ``XLA Modules``
+# line ``jit_paged_prefill``, where a lambda leaves every program
+# ``<lambda>``.
 
 
 #: flax cache leaves that are per-row BLOCK TABLES, one name per kind
 #: of cache (models/decoder.PagedKV: a window layer's is its own). The
 #: host hands the kinds' tables over side by side in ONE array, in this
-#: order (paging.CacheKinds.tables), each as wide as its leaves.
+#: order (paging.CacheKinds.tables), each the same width.
 TABLE_LEAVES = ("block_table", "window_table")
 
+#: flax cache leaves that are per-BLOCK pool storage: what of a paged
+#: cache lives on the device between calls, and its shippable content
+#: (everything else is per-slot host-owned state: cursors and block
+#: tables cross no boundary)
+_POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
 
-def _tables_by_kind(cache, tables):
-    """``{table leaf name: its columns of tables}``. A cache of one
-    kind takes ``tables`` whole, the program it always was."""
-    widths = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        if _leaf_name(path) in TABLE_LEAVES:
-            widths[_leaf_name(path)] = leaf.shape[-1]
-    if len(widths) < 2:
-        return dict.fromkeys(widths, tables)
-    out, at = {}, 0
-    for name in TABLE_LEAVES:
-        if name in widths:
-            out[name] = tables[..., at:at + widths[name]]
-            at += widths[name]
+
+@functools.lru_cache(maxsize=32)
+def _cache_shapes(model):
+    """The ``cache`` collection of a paged ``model`` as shapes, by a
+    shape-only ``init`` over one block of positions: which leaves there
+    are, where, and of what dtype. The pools' shapes are the model's
+    own; a cursor's or a table's follow the call and mean nothing
+    here."""
+    dummy = jnp.zeros((1, model.kv_block_size), jnp.int32)
+    return jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), dummy))["cache"]
+
+
+def init_pools(model):
+    """Fresh pools of a paged ``model``: its cache collection with the
+    pool leaves only, zeros. What an engine keeps on the device and
+    every paged program takes, donated, and answers."""
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        _pools_of(_cache_shapes(model)))
+
+
+def _pools_of(cache):
+    """``cache`` (a nested mapping) with its pool leaves only."""
+    out = {}
+    for name, sub in cache.items():
+        if hasattr(sub, "items"):
+            sub = _pools_of(sub)
+            if sub:
+                out[name] = sub
+        elif name in _POOL_LEAVES:
+            out[name] = sub
     return out
 
 
-def _set_paged_leaves(cache, idx, tables):
-    """Cache pytree with cursor leaves replaced by ``idx`` and the
-    block-table leaves by ``tables`` (each kind's by its own columns,
-    :data:`TABLE_LEAVES`): the host scheduler is the
-    authority on both position AND block mapping, every call.
+def _fed_cache(model, cache, cursor, tables):
+    """The flax ``cache`` collection of ``model`` for one call: the pool
+    leaves of ``cache`` (whatever else it holds is not read), every
+    cursor leaf ``cursor`` and every table leaf its kind's columns of
+    ``tables [B, kinds x width]``, each cast to the leaf's dtype."""
+    shapes = _cache_shapes(model)
+    names = {_leaf_name(path) for path, _
+             in jax.tree_util.tree_leaves_with_path(shapes)}
+    kinds = [name for name in TABLE_LEAVES if name in names]
+    width = tables.shape[-1] // len(kinds)
+    by_kind = {name: tables[..., n * width:(n + 1) * width]
+               for n, name in enumerate(kinds)}
+
+    def build(shapes, cache):
+        out = {}
+        for name, sub in shapes.items():
+            if hasattr(sub, "items"):
+                out[name] = build(sub, cache[name])
+            elif name in _CURSOR_LEAVES:
+                out[name] = cursor.astype(sub.dtype)
+            elif name in by_kind:
+                out[name] = by_kind[name].astype(sub.dtype)
+            else:
+                out[name] = cache[name]
+        return out
+
+    return build(shapes, cache)
+
+
+def _set_paged_leaves(model, cache, idx, tables):
+    """The cache collection of a step over every slot: the pools of
+    ``cache``, the cursor leaves ``idx [S]`` and the block-table leaves
+    ``tables [S, kinds x width]`` (each kind's its own columns,
+    :data:`TABLE_LEAVES`): the host scheduler is the authority on both
+    position AND block mapping, every call, so neither is kept on the
+    device.
 
     A freed slot must NOT keep advancing its cursor while it idles, and
-    a re-admitted slot restarts at its new length. Overwriting the
-    cursors before each step makes the device cache's own increments
-    advisory, so an inactive slot (cursor 0, every table entry the
-    scratch block) just re-writes one scratch position in place.
+    a re-admitted slot restarts at its new length. Feeding the cursors
+    to each step makes the model's own increments advisory, so an
+    inactive slot (cursor 0, every table entry the scratch block) just
+    re-writes one scratch position in place.
 
     This same discipline is what makes MID-FLIGHT EVICTION (PR 4:
     cancel / deadline, serving.DecodeEngine._evict_expired) free: an
@@ -340,48 +418,17 @@ def _set_paged_leaves(cache, idx, tables):
     neighbors never see it. Eviction therefore cannot perturb
     concurrent sequences, which is why cancelled-neighbor outputs stay
     bitwise-identical (tests/test_serving_lifecycle.py pins this)."""
-    by_kind = _tables_by_kind(cache, tables)
-
-    def repl(path, leaf):
-        name = _leaf_name(path)
-        if name in _CURSOR_LEAVES:
-            return idx.astype(leaf.dtype)
-        if name in by_kind:
-            return by_kind[name].astype(leaf.dtype)
-        return leaf
-    return jax.tree_util.tree_map_with_path(repl, cache)
+    return _fed_cache(model, cache, jnp.asarray(idx, jnp.int32),
+                      jnp.asarray(tables, jnp.int32))
 
 
-def _slot_view(cache, table_row, start):
+def _slot_view(model, cache, table_row, start):
     """The batch-1 view of ONE slot on the shared pool: cursor leaves at
     ``start``, block-table leaves the slot's row, pool leaves as they
     are (they are batch-independent)."""
-    table_row = jnp.asarray(table_row, jnp.int32)
-    start = jnp.asarray(start, jnp.int32)
-    by_kind = _tables_by_kind(cache, table_row)
-
-    def view(path, leaf):
-        name = _leaf_name(path)
-        if name in _CURSOR_LEAVES:
-            return jnp.full((1,), start, leaf.dtype)
-        if name in by_kind:
-            return by_kind[name][None, :].astype(leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(view, cache)
-
-
-def _merge_pools(cache, updated):
-    """``cache`` with the pool leaves of ``updated`` (a slot view after
-    its call): the engine-shaped [S] cursor and [S, MB] table leaves
-    keep their (host-overwritten-anyway) storage so the cache pytree's
-    shapes never change."""
-    def merge(path, big, new):
-        if _leaf_name(path) in _CURSOR_LEAVES + TABLE_LEAVES:
-            return big
-        return new
-
-    return jax.tree_util.tree_map_with_path(merge, cache, updated)
+    return _fed_cache(
+        model, cache, jnp.full((1,), jnp.asarray(start, jnp.int32)),
+        jnp.asarray(table_row, jnp.int32)[None, :])
 
 
 def paged_prefill_into_slot(model, params, cache, table_row, tokens,
@@ -409,7 +456,7 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
     behind the token (:func:`with_routed`)."""
     tail_len = jnp.asarray(tail_len, jnp.int32)
     variables = {"params": params,
-                 "cache": _slot_view(cache, table_row, start)}
+                 "cache": _slot_view(model, cache, table_row, start)}
     if getattr(model, "takes_last", False):
         cap, upd = model.apply(variables, tokens[None, :],
                                last=(tail_len - 1)[None],
@@ -421,7 +468,7 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
         cap = jax.lax.dynamic_index_in_dim(
             logits, tail_len - 1, axis=1, keepdims=False)
     first = _pick_tokens(cap, rng, temperature, top_k, top_p)[0]
-    return _merge_pools(cache, upd["cache"]), with_routed(first, upd)
+    return _pools_of(upd["cache"]), with_routed(first, upd)
 
 
 def paged_decode_step(model, params, cache, tokens, idx, tables,
@@ -436,15 +483,14 @@ def paged_decode_step(model, params, cache, tokens, idx, tables,
     Returns ``(cache', next_tokens [S])``, with the routed experts of
     every slot's position behind the tokens where the model sows them
     (:func:`with_routed`)."""
-    cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
-                              jnp.asarray(tables, jnp.int32))
     mutable = ["cache", "intermediates"] \
         if getattr(model, "routed_layers", 0) else ["cache"]
     logits, upd = model.apply(
-        {"params": params, "cache": cache}, tokens[:, None],
-        mutable=mutable)
+        {"params": params,
+         "cache": _set_paged_leaves(model, cache, idx, tables)},
+        tokens[:, None], mutable=mutable)
     picked = _pick_tokens(logits[:, -1, :], rng, temperature, top_k, top_p)
-    return upd["cache"], with_routed(picked, upd)
+    return _pools_of(upd["cache"]), with_routed(picked, upd)
 
 
 # the jitted program of paged_step_fns carries the name
@@ -456,7 +502,9 @@ _paged_decode_step = paged_decode_step
 @functools.lru_cache(maxsize=32)
 def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     """(jitted paged prefill, jitted paged decode) for one paged model
-    + sampling config, cache-donating, reused across engines.
+    + sampling config, cache-donating, reused across engines. Each
+    takes the cache as its pools (:func:`init_pools`; of a whole cache
+    collection the pool leaves are read) and answers the pools.
 
     Compile-count contract (asserted in tests): ONE decode program per
     (slots, total_len) engine config, one prefill program per TAIL
@@ -519,10 +567,10 @@ def paged_block_prefill(model, params, cache, table_row, tokens, start):
     position ``start`` into the blocks ``table_row`` maps. No head, no
     token: returns ``(cache', expert_ids [layers, bucket, k])``."""
     _, upd = model.apply(
-        {"params": params, "cache": _slot_view(cache, table_row, start)},
+        {"params": params,
+         "cache": _slot_view(model, cache, table_row, start)},
         tokens[None, :], head=False, mutable=["cache", "intermediates"])
-    return _merge_pools(cache, upd["cache"]), \
-        _expert_ids(upd["intermediates"])
+    return _pools_of(upd["cache"]), _expert_ids(upd["intermediates"])
 
 
 def paged_block_step(model, params, cache, tokens, idx, tables):
@@ -531,15 +579,15 @@ def paged_block_step(model, params, cache, tokens, idx, tables):
     ``(cache', (best [S, B] int32, confidence [S, B] float32,
     expert_ids [layers, S * B, k] int32))``: the most probable token of
     each position and its probability."""
-    cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
-                              jnp.asarray(tables, jnp.int32))
     logits, upd = model.apply(
-        {"params": params, "cache": cache}, tokens,
-        mutable=["cache", "intermediates"])
+        {"params": params,
+         "cache": _set_paged_leaves(model, cache, idx, tables)},
+        tokens, mutable=["cache", "intermediates"])
     top = jnp.max(logits, axis=-1)
     conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
-    return upd["cache"], (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                          conf, _expert_ids(upd["intermediates"]))
+    return _pools_of(upd["cache"]), (
+        jnp.argmax(logits, axis=-1).astype(jnp.int32), conf,
+        _expert_ids(upd["intermediates"]))
 
 
 _paged_block_prefill, _paged_block_step = paged_block_prefill, \
@@ -550,8 +598,8 @@ _paged_block_prefill, _paged_block_step = paged_block_prefill, \
 def paged_block_fns(model):
     """(jitted block prefill, jitted block step) for one block-stepping
     model, cache-donating - the sibling of :func:`paged_step_fns`, same
-    compile-count contract (one step program, one prefill per bucket)
-    and the same naming of programs.
+    compile-count contract (one step program, one prefill per bucket),
+    the same naming of programs and the same form of the cache.
 
     The step runs between every two host decisions with the device
     waiting on both sides, and every array that crosses is a round
@@ -631,12 +679,6 @@ def unmask(conf, masked, quota, threshold):
 # structural mismatch (different layer count, missing scales) fails
 # loudly instead of splicing K into V.
 
-#: flax cache leaves that are per-BLOCK pool storage — the shippable
-#: content of a paged cache (everything else is per-slot host-owned
-#: state: cursors and block tables never ship)
-_POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
-
-
 def pool_leaves(cache):
     """``[(path, leaf)]`` of a paged cache's pool storage, in tree
     order: the one place that says which leaves those are (block
@@ -646,16 +688,17 @@ def pool_leaves(cache):
             if _leaf_name(path) in _POOL_LEAVES]
 
 
-def pool_leaves_by_table(cache):
+def pool_leaves_by_table(model, cache):
     """``{table leaf name: [pool leaves]}``: each kind's pools, a kind
-    being named by the table leaf that sits beside them in the same
-    attention module (:data:`TABLE_LEAVES`)."""
-    table_of = {path[:-1]: _leaf_name(path) for path, _
-                in jax.tree_util.tree_leaves_with_path(cache)
+    being named by the table leaf that ``model`` declares beside them
+    in the same attention module (:data:`TABLE_LEAVES`; the pools
+    themselves carry no table)."""
+    table_of = {_path_key(path[:-1]): _leaf_name(path) for path, _
+                in jax.tree_util.tree_leaves_with_path(_cache_shapes(model))
                 if _leaf_name(path) in TABLE_LEAVES}
     out = {}
     for path, leaf in pool_leaves(cache):
-        out.setdefault(table_of[path[:-1]], []).append(leaf)
+        out.setdefault(table_of[_path_key(path[:-1])], []).append(leaf)
     return out
 
 
@@ -774,8 +817,9 @@ def paged_propose_tokens(model, params, cache, last, idx, tables, k,
     the proposals are ``d_1..d_k``)."""
     import jax
 
-    cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
-                              jnp.asarray(tables, jnp.int32))
+    # the cursors advance inside the scan, so it carries the whole
+    # collection; the pools alone leave the program
+    cache = _set_paged_leaves(model, cache, idx, tables)
     if rng is None:
         rng = jax.random.PRNGKey(0)
     keys = jax.random.split(rng, k)
@@ -790,7 +834,7 @@ def paged_propose_tokens(model, params, cache, last, idx, tables, k,
         return (upd["cache"], picked), picked
 
     (cache, _), drafts = jax.lax.scan(body, (cache, last), keys)
-    return cache, drafts.T  # [k, S] -> [S, k]
+    return _pools_of(cache), drafts.T  # [k, S] -> [S, k]
 
 
 def paged_verify_step(model, params, cache, tokens, idx, tables,
@@ -804,14 +848,14 @@ def paged_verify_step(model, params, cache, tokens, idx, tables,
     the caller's (host-side) token match: ``d_{j+1}`` stands iff it
     equals ``picks[:, j]``, and ``picks[:, a]`` is the correction
     token when the match chain breaks at ``a``."""
-    cache = _set_paged_leaves(cache, jnp.asarray(idx, jnp.int32),
-                              jnp.asarray(tables, jnp.int32))
     logits, upd = model.apply(
-        {"params": params, "cache": cache}, tokens, mutable=["cache"])
+        {"params": params,
+         "cache": _set_paged_leaves(model, cache, idx, tables)},
+        tokens, mutable=["cache"])
     s, k, v = logits.shape
     picked = _pick_tokens(logits.reshape(s * k, v), rng, temperature,
                           top_k, top_p)
-    return upd["cache"], picked.reshape(s, k)
+    return _pools_of(upd["cache"]), picked.reshape(s, k)
 
 
 def paged_spec_round(model, draft_model, params, draft_params, cache,
@@ -846,10 +890,10 @@ def paged_spec_round(model, draft_model, params, draft_params, cache,
 def speculative_step_fns(model, draft_model, k, temperature=0.0,
                          top_k=None, top_p=None):
     """The jitted FUSED round fn for one (target, draft, k, sampling)
-    tuple, cache-donating, reused across engines — the speculative
-    sibling of :func:`paged_step_fns`. Compile-count contract: ONE
-    round program per engine config (k is static; the fn is
-    fixed-shape over all S slots). Call signature:
+    tuple, cache-donating (both models' pools), reused across engines —
+    the speculative sibling of :func:`paged_step_fns`. Compile-count
+    contract: ONE round program per engine config (k is static; the
+    fn is fixed-shape over all S slots). Call signature:
     ``fn(params, draft_params, cache, draft_cache, last, idx, tables,
     key) -> (cache', draft_cache', drafts, targets)``."""
     import jax

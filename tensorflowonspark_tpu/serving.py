@@ -89,6 +89,15 @@ _PAGED_ONLY = (
     "generation.generate is the contiguous-cache path")
 
 
+def _int32_shape(*shape):
+    """An int32 argument of an engine program as its shape alone (what
+    a program is lowered with before the host has built the array)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
 class Retriable(RuntimeError):
     """The request failed for a TRANSIENT serving-side reason — shed at
     admission, engine draining, or the engine mid-restart. The client
@@ -1061,7 +1070,9 @@ class DecodeEngine(object):
             self._blk_given = np.zeros(self.slots, np.int32)
             self._blk_live = np.zeros(self.slots, np.int32)
             self._blk_pass = np.zeros(self.slots, np.int32)
-        self._cache = generation.init_cache(model, self.slots, total_len)
+        # the cache on the device: the POOLS only (cursors and tables
+        # are this thread's numpy and reach a program inside its feed)
+        self._cache = generation.init_pools(model)
         #: resolved pool storage dtype — the pinned schema string
         #: load_stats / /healthz / the fleet BEAT payload carry
         #: ("int8" on the quantized fast path, the compute dtype name
@@ -1070,13 +1081,12 @@ class DecodeEngine(object):
             (str(leaf.dtype) for _, leaf
              in generation.pool_leaves(self._cache)), "none")
         self._kv.set_block_bytes(
-            generation.pool_leaves_by_table(self._cache))
+            generation.pool_leaves_by_table(model, self._cache))
         if self._spec_k:
-            # the draft's own cache pytree (draft_layers/num_layers of
-            # the target's KV bytes); tables and cursors stay host-
-            # shared, so this is pool storage only
-            self._draft_cache = generation.init_cache(
-                self._draft_model, self.slots, total_len)
+            # the draft's own pools (draft_layers/num_layers of the
+            # target's KV bytes); tables and cursors are host-shared
+            self._draft_cache = generation.init_pools(self._draft_model)
+        self._buffers_counted = False  # the loop's: _admit, once
         self._publish_kv_gauges()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="tfos-decode-engine")
@@ -1621,13 +1631,51 @@ class DecodeEngine(object):
             return size() if callable(size) else None
         stats = {"decode_programs": n_programs(self._decode_fn),
                  "prefill_programs": n_programs(self._prefill_fn),
-                 "buckets": len(self.buckets)}
+                 "buckets": len(self.buckets),
+                 "decode_call_buffers": self._step_call_buffers()}
         if self._spec_k:
             # a speculative engine's loop runs the fused round instead
             # of the plain decode fn (decode_programs stays 0); same
             # ONE-program-per-engine-config contract
             stats["spec_round_programs"] = n_programs(self._round_fn)
         return stats
+
+    def _step_call(self):
+        """``(jitted fn, arguments)`` of one call of this engine's step
+        program (a token step, a block step or a speculative round) as
+        the loop makes it: the engine's own parameters and pools, the
+        feed as shapes."""
+        int32 = _int32_shape
+        s, width = self._tables.shape
+        if self._spec_k:
+            return self._round_fn, (
+                self.params, self._draft_params, self._cache,
+                self._draft_cache, int32(s), int32(s), int32(s, width),
+                self._key)
+        if self._block_len:
+            return self._decode_fn, (
+                self.params, self._cache,
+                int32(s, self._block_len + 1 + width))
+        return self._decode_fn, (self.params, self._cache, self._picked,
+                                 int32(s, 2 + width), self._key)
+
+    def _step_call_buffers(self):
+        """Buffers the host hands over plus buffers it takes back in
+        one call of the step program, read off the program's lowering
+        (the trace is the one the call makes, or made): what the
+        dispatch of a step costs the host goes by this count
+        (generation.py, "What crosses the jit boundary"). The loop
+        exports it once as the gauge ``decode_call_buffers``
+        (:meth:`_admit`), and from then on that is what is read."""
+        import jax
+
+        known = self.counters.snapshot()["gauges"].get("decode_call_buffers")
+        if known is not None:
+            return known
+        fn, args = self._step_call()
+        lowered = fn.lower(*args)
+        return len(jax.tree.leaves(lowered.args_info)) \
+            + len(jax.tree.leaves(lowered.out_info))
 
     def precompile(self):
         """Compile every prefill bucket's program and the decode step
@@ -1644,9 +1692,6 @@ class DecodeEngine(object):
         compiled."""
         import concurrent.futures
 
-        import jax
-        import jax.numpy as jnp
-
         if self._block_len or self._spec_k:
             raise ValueError(
                 "precompile() knows a token engine's programs only (no "
@@ -1654,20 +1699,13 @@ class DecodeEngine(object):
         if self.outstanding():
             raise RuntimeError("precompile() needs an idle engine")
 
-        def int32(*shape):
-            return jax.ShapeDtypeStruct(shape, jnp.int32)
-
+        int32 = _int32_shape
         # the largest bucket first: it is the longest to compile
         calls = [(self._prefill_fn, (
             self.params, self._cache, int32(self._tables.shape[1]),
             int32(b), int32(), int32(), self._key))
             for b in sorted(self.buckets, reverse=True)]
-        calls.append((self._decode_fn, (
-            self.params, self._cache, self._picked,
-            self._generation.pack_step_feed(
-                np.full(self.slots, -1, np.int32),
-                np.zeros(self.slots, np.int32),
-                np.zeros_like(self._tables)), self._key)))
+        calls.append(self._step_call())
         with concurrent.futures.ThreadPoolExecutor(len(calls)) as pool:
             list(pool.map(lambda c: c[0].lower(*c[1]).compile(), calls))
         return len(calls)
@@ -2991,6 +3029,13 @@ class DecodeEngine(object):
         handle._attr_spans.append(("prefill", t0, t1))
         handle._decode_t0 = t1
         self.counters.inc("prefills")
+        if not self._buffers_counted:
+            # once, behind the first prefill that went through (the
+            # parameters fit the model): the step it lowers is the one
+            # the next turn calls
+            self.counters.gauge("decode_call_buffers",
+                                self._step_call_buffers())
+            self._buffers_counted = True
         if self._spec_k:
             # mirror the tail into the DRAFT pool (PR 15): the draft
             # attends the same prefix through the same table row, so

@@ -322,6 +322,12 @@ METRIC_FAMILIES = {
         ("counter", "", "tokens of a step in flight that nobody got: "
                         "the request ended on EOS, was cancelled or "
                         "evicted after the dispatch"),
+    "tfos_serving_decode_call_buffers":
+        ("gauge", "", "buffers one call of the engine's step program "
+                      "hands the runtime plus buffers it takes back, "
+                      "read off the lowered program at the first "
+                      "admission (the host's cost of a dispatch goes by "
+                      "this count: the cache crosses as its pools only)"),
     "tfos_serving_attn_grid_steps":
         ("counter", "", "grid steps one call of the paged attention "
                         "kernel takes, summed over decode and block "

@@ -193,10 +193,10 @@ def _by_hand(model, cast, prompt, steps, total=48, bucket=None):
     toks[:n] = prompt
     logits, upd = dec.apply(
         {"params": cast,
-         "cache": generation._slot_view(cache, kv.tables[0], 0)},
+         "cache": generation._slot_view(dec, cache, kv.tables[0], 0)},
         jnp.asarray(toks)[None], last=jnp.asarray([n - 1]),
         mutable=["cache", "intermediates"])
-    cache = generation._merge_pools(cache, upd["cache"])
+    cache = upd["cache"]
     out, fed, held = [np.asarray(logits[0, 0])], [], []
     for i in range(steps):
         cursor = n + i
@@ -206,7 +206,7 @@ def _by_hand(model, cast, prompt, steps, total=48, bucket=None):
             kind.grow(0, cursor // BLOCK)
         held.append(len(kv.kinds[1].blocks[0]))
         stepped = generation._set_paged_leaves(
-            cache, jnp.asarray([cursor]), jnp.asarray(kv.tables))
+            dec, cache, jnp.asarray([cursor]), jnp.asarray(kv.tables))
         logits, upd = dec.apply({"params": cast, "cache": stepped},
                                 jnp.asarray([[token]]),
                                 mutable=["cache", "intermediates"])
@@ -595,7 +595,8 @@ def test_tables_of_two_kinds_ride_one_feed():
         decode=True, kv_block_size=BLOCK, kv_blocks=9, kv_window_blocks=5)
     cache = generation.init_cache(model, 2, 16)
     tables = jnp.arange(2 * 8).reshape(2, 8)
-    fed = generation._set_paged_leaves(cache, jnp.asarray([3, 9]), tables)
+    fed = generation._set_paged_leaves(model, cache, jnp.asarray([3, 9]),
+                                       tables)
     attn = fed["layer_0"]["attn"], fed["layer_3"]["attn"]
     assert np.asarray(attn[0]["window_table"]).tolist() \
         == np.asarray(tables[:, 4:]).tolist()
@@ -603,7 +604,7 @@ def test_tables_of_two_kinds_ride_one_feed():
         == np.asarray(tables[:, :4]).tolist()
     assert attn[0]["cached_key"].shape == (5, BLOCK, 16)
     assert attn[1]["cached_key"].shape == (9, BLOCK, 16)
-    by_table = generation.pool_leaves_by_table(cache)
+    by_table = generation.pool_leaves_by_table(model, cache)
     assert sorted((k, len(v)) for k, v in by_table.items()) \
         == [("block_table", 2), ("window_table", 6)]
     assert generation.answer_len(model, 2, 2) == 2 + 4 * 2 * 2

@@ -88,8 +88,7 @@ def _paged(model, slots, total_len, kv_block_size=8):
 
 
 def _pass_logits(paged, params, cache, tokens, idx, tables):
-    cache = generation._set_paged_leaves(
-        cache, jnp.asarray(idx, jnp.int32), jnp.asarray(tables, jnp.int32))
+    cache = generation._set_paged_leaves(paged, cache, idx, tables)
     logits, upd = paged.apply({"params": params, "cache": cache},
                               jnp.asarray(tokens), mutable=["cache"])
     return upd["cache"], np.asarray(logits)

@@ -361,7 +361,7 @@ def test_int8_top1_agreement_teacher_forced(lm):
         argmaxes = []
         for seq in seqs:
             c = generation._set_paged_leaves(
-                cache, jnp.zeros((1,), jnp.int32), table)
+                model, cache, jnp.zeros((1,), jnp.int32), table)
             logits, _ = model.apply(
                 {"params": params, "cache": c},
                 jnp.asarray([seq], jnp.int32), mutable=["cache"])

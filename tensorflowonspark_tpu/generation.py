@@ -313,6 +313,11 @@ def pack_step_feed(given, idx, tables):
 # 4.48 -> 4.54 with the biases and norms alone (4-5 us a slice read),
 # and the device's step is the gap once the call is under it.
 #
+# A model with a recurrent state (PR 38) adds its STATE leaves to what
+# crosses (:data:`_STATE_LEAVES`: a row a slot and layer), donated and
+# answered in place like the pools; which row a call's batch row is
+# comes from a slot leaf built inside the trace, as the cursors are.
+#
 # The jitted wrappers donate the pools, so the scheduler's steady-state
 # loop updates them in place instead of copying them. Every engine
 # program is the jit of a NAMED function: a trace's host spans then
@@ -332,6 +337,18 @@ TABLE_LEAVES = ("block_table", "window_table")
 #: (everything else is per-slot host-owned state: cursors and block
 #: tables cross no boundary)
 _POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
+
+#: flax cache leaves that are per-SLOT recurrent state (PR 38,
+#: models/falcon_h1.py: a Mamba-2 mixer's SSM state and its
+#: convolution's last inputs, a row a slot): they live on the device
+#: between calls and cross the boundary donated, beside the pools, but
+#: are addressed by slot and not through a table, and ship nowhere
+_STATE_LEAVES = ("ssm_state", "conv_state")
+
+#: flax cache leaves that say which state row each batch row of a call
+#: is (built inside the trace like a cursor: a step's rows are the
+#: slots, a prefill's one row the slot it fills)
+_SLOT_LEAVES = ("state_slot",)
 
 
 @functools.lru_cache(maxsize=32)
@@ -362,16 +379,18 @@ def _pools_of(cache):
             sub = _pools_of(sub)
             if sub:
                 out[name] = sub
-        elif name in _POOL_LEAVES:
+        elif name in _POOL_LEAVES or name in _STATE_LEAVES:
             out[name] = sub
     return out
 
 
-def _fed_cache(model, cache, cursor, tables):
+def _fed_cache(model, cache, cursor, tables, slot=None):
     """The flax ``cache`` collection of ``model`` for one call: the pool
-    leaves of ``cache`` (whatever else it holds is not read), every
-    cursor leaf ``cursor`` and every table leaf its kind's columns of
-    ``tables [B, kinds x width]``, each cast to the leaf's dtype."""
+    and state leaves of ``cache`` (whatever else it holds is not read),
+    every cursor leaf ``cursor``, every table leaf its kind's columns of
+    ``tables [B, kinds x width]`` and every slot leaf ``slot`` for each
+    row (None: row ``b`` is slot ``b``), each cast to the leaf's dtype.
+    A model without slot leaves traces nothing of ``slot``."""
     shapes = _cache_shapes(model)
     names = {_leaf_name(path) for path, _
              in jax.tree_util.tree_leaves_with_path(shapes)}
@@ -387,6 +406,10 @@ def _fed_cache(model, cache, cursor, tables):
                 out[name] = build(sub, cache[name])
             elif name in _CURSOR_LEAVES:
                 out[name] = cursor.astype(sub.dtype)
+            elif name in _SLOT_LEAVES:
+                rows = cursor.shape[0]
+                out[name] = (jnp.arange(rows) if slot is None else
+                             jnp.full((rows,), slot)).astype(sub.dtype)
             elif name in by_kind:
                 out[name] = by_kind[name].astype(sub.dtype)
             else:
@@ -417,23 +440,27 @@ def _set_paged_leaves(model, cache, idx, tables):
     stale K/V is unreachable once no table row names its blocks, and
     neighbors never see it. Eviction therefore cannot perturb
     concurrent sequences, which is why cancelled-neighbor outputs stay
-    bitwise-identical (tests/test_serving_lifecycle.py pins this)."""
+    bitwise-identical (tests/test_serving_lifecycle.py pins this).
+
+    A step's rows ARE the slots, so a state leaf's row of batch row
+    ``b`` is ``b`` (the slot leaves); a row at cursor 0 is idle and its
+    state is neither read nor written (ops/ssm_step.py)."""
     return _fed_cache(model, cache, jnp.asarray(idx, jnp.int32),
                       jnp.asarray(tables, jnp.int32))
 
 
-def _slot_view(model, cache, table_row, start):
+def _slot_view(model, cache, table_row, start, slot=0):
     """The batch-1 view of ONE slot on the shared pool: cursor leaves at
-    ``start``, block-table leaves the slot's row, pool leaves as they
-    are (they are batch-independent)."""
+    ``start``, block-table leaves the slot's row, slot leaves ``slot``,
+    pool and state leaves as they are (they are batch-independent)."""
     return _fed_cache(
         model, cache, jnp.full((1,), jnp.asarray(start, jnp.int32)),
-        jnp.asarray(table_row, jnp.int32)[None, :])
+        jnp.asarray(table_row, jnp.int32)[None, :], slot)
 
 
 def paged_prefill_into_slot(model, params, cache, table_row, tokens,
                             tail_len, start, temperature=0.0, top_k=None,
-                            top_p=None, rng=None):
+                            top_p=None, rng=None, slot=0):
     """Prefill a prompt TAIL into the pool blocks ``table_row`` maps.
 
     ``tokens [bucket]`` is the un-shared tail of the prompt padded to
@@ -453,10 +480,12 @@ def paged_prefill_into_slot(model, params, cache, table_row, tokens,
     ``max_new_tokens=1`` request costs one tiny-bucket forward). A
     model that ``takes_last`` runs its head on that one position and
     not on the bucket; one that sows its routed experts answers them
-    behind the token (:func:`with_routed`)."""
+    behind the token (:func:`with_routed`). ``slot`` is the state row a
+    model with a recurrent state writes whole (a prefill from position
+    0; models/falcon_h1.py); a model without one reads nothing of it."""
     tail_len = jnp.asarray(tail_len, jnp.int32)
     variables = {"params": params,
-                 "cache": _slot_view(model, cache, table_row, start)}
+                 "cache": _slot_view(model, cache, table_row, start, slot)}
     if getattr(model, "takes_last", False):
         cap, upd = model.apply(variables, tokens[None, :],
                                last=(tail_len - 1)[None],
@@ -520,12 +549,17 @@ def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
     the one array), so a step can be dispatched before the one before
     it has been read. A model with ``routed_layers`` answers the routed
     experts behind the tokens (:func:`with_routed`, as long as
-    :func:`answer_len` says), and the next step reads the first ``S``."""
+    :func:`answer_len` says), and the next step reads the first ``S``.
+
+    The prefill is told the SLOT it fills (a traced scalar: one
+    program for every slot), which a model with a recurrent state
+    (paging.StateKind) writes whole and every other model ignores."""
     def paged_prefill(params, cache, table_row, tokens, tail_len, start,
-                      key):
+                      key, slot):
         return paged_prefill_into_slot(
             model, params, cache, table_row, tokens, tail_len, start,
-            temperature=temperature, top_k=top_k, top_p=top_p, rng=key)
+            temperature=temperature, top_k=top_k, top_p=top_p, rng=key,
+            slot=slot)
 
     def paged_decode_step(params, cache, picked, feed, key):
         return _paged_decode_step(
@@ -688,16 +722,25 @@ def pool_leaves(cache):
             if _leaf_name(path) in _POOL_LEAVES]
 
 
+def state_leaves(cache):
+    """``[(path, leaf)]`` of a cache's per-slot state rows
+    (:data:`_STATE_LEAVES`), in tree order."""
+    return [(path, leaf) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(cache)
+            if _leaf_name(path) in _STATE_LEAVES]
+
+
 def pool_leaves_by_table(model, cache):
     """``{table leaf name: [pool leaves]}``: each kind's pools, a kind
     being named by the table leaf that ``model`` declares beside them
     in the same attention module (:data:`TABLE_LEAVES`; the pools
-    themselves carry no table)."""
+    themselves carry no table); a state kind's rows under the slot leaf
+    its mixer declares beside them (:data:`_SLOT_LEAVES`)."""
     table_of = {_path_key(path[:-1]): _leaf_name(path) for path, _
                 in jax.tree_util.tree_leaves_with_path(_cache_shapes(model))
-                if _leaf_name(path) in TABLE_LEAVES}
+                if _leaf_name(path) in TABLE_LEAVES + _SLOT_LEAVES}
     out = {}
-    for path, leaf in pool_leaves(cache):
+    for path, leaf in pool_leaves(cache) + state_leaves(cache):
         out.setdefault(table_of[_path_key(path[:-1])], []).append(leaf)
     return out
 

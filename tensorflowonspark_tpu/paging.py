@@ -524,9 +524,13 @@ class BlockPool(object):
 # blocks, a WINDOW layer only the blocks that reach back ``window``
 # positions from the newest one. Each kind has a pool of its own size
 # and a table of its own in every slot; what a slot holds, needs and
-# gives back is asked of the kinds together, below. Further kinds (a
-# recurrent state a slot, a latent row a token) are further classes
-# with these methods; nothing in the engine asks which kind it holds.
+# gives back is asked of the kinds together, below. A STATE kind (PR
+# 38, :class:`StateKind`) is the third: a recurrent layer's fixed-size
+# row a slot, no blocks, no table, the same methods answering 0 where a
+# block kind grows. Further kinds (a latent row a token) are further
+# classes with these methods; nothing in the engine asks which kind it
+# holds: what each counts and exports is its own
+# (:meth:`FullKind.count_step`, :meth:`FullKind.publish`).
 
 
 class FullKind(object):
@@ -538,6 +542,9 @@ class FullKind(object):
     #: the cache leaf that holds this kind's table on the device
     #: (generation.TABLE_LEAVES); its pools sit beside it
     table_leaf = "block_table"
+    #: what the paged kernel's grid takes in a step, counted under this
+    #: prefix (:meth:`count_step`)
+    grid_counter = "attn_"
 
     def __init__(self, pool, tables):
         self.pool, self.tables = pool, tables
@@ -618,6 +625,37 @@ class FullKind(object):
         return np.minimum(
             (cursors + span - 1) // self.pool.block_size + 1, self.width)
 
+    # -- what the kind counts and exports (the engine's counters) ------
+
+    def counter_names(self):
+        """Counters the kind keeps, exported as 0 from the start."""
+        return (self.grid_counter + "grid_steps",
+                self.grid_counter + "table_slots")
+
+    def count_step(self, counters, cursors, span):
+        """Where a step's feed is built: what ONE call of the paged
+        kernel takes (:meth:`grid_steps`) beside the table slots a grid
+        over every slot would."""
+        live = self.grid_steps(cursors, span)
+        counters.inc(self.grid_counter + "grid_steps", int(live.sum()))
+        counters.inc(self.grid_counter + "table_slots",
+                     live.size * self.width)
+
+    def count_turn(self, counters):
+        """At every token step, beside the full kind's ``kv_block_steps``
+        (the engine's): nothing for the full kind itself."""
+
+    def publish(self, counters):
+        """The kind's gauges (the full kind's are the engine's)."""
+
+    def model_fields(self):
+        """Fields of the served model this kind sizes (the full kind's
+        ``kv_blocks`` is the engine's)."""
+        return {}
+
+    def bytes_per_token(self):
+        return self.block_bytes // self.pool.block_size
+
 
 class WindowKind(FullKind):
     """The cache of layers whose query at ``i`` sees ``i - window <
@@ -627,10 +665,26 @@ class WindowKind(FullKind):
     (:meth:`trim`) in the turn the window leaves them."""
 
     name, table_leaf = "window", "window_table"
+    grid_counter = "attn_window_"
 
     def __init__(self, pool, tables, window):
         FullKind.__init__(self, pool, tables)
         self.window = int(window)
+
+    def counter_names(self):
+        return FullKind.counter_names(self) + (
+            "kv_window_block_steps", "kv_window_blocks_given_back")
+
+    def count_turn(self, counters):
+        counters.inc("kv_window_block_steps", self.in_use())
+
+    def publish(self, counters):
+        counters.gauge("kv_window_blocks_total", self.pool.num_blocks)
+        counters.gauge("kv_window_blocks_free", self.pool.allocatable())
+
+    def model_fields(self):
+        # +1 device row: the scratch block pad writes land in
+        return {"kv_window_blocks": self.pool.num_blocks + 1}
 
     def first_seen(self, cursor):
         return np.maximum(cursor - self.window + 1, 0) \
@@ -647,6 +701,77 @@ class WindowKind(FullKind):
         return last - np.minimum(self.first_seen(cursors), last) + 1
 
 
+class StateKind(object):
+    """The cache of recurrent layers (a Mamba-2 mixer's SSM state and
+    its convolution's last inputs, models/falcon_h1.py): ONE row a slot
+    in every such layer, whatever the sequence's length. No blocks, no
+    table: the device addresses a row by slot (the ``state_slot``
+    leaf), a prefill from position 0 writes a slot's whole row and a
+    decode step advances the rows of the live slots only, so a free
+    slot holds nothing that needs giving back and an admitted one
+    nothing that needs growing (every method that counts blocks answers
+    0). What it cannot do yet is named where it is refused
+    (serving.DecodeEngine._check_cache_kinds): a prefix hit would need
+    the state at the prefix's end, a rejected draft the state before
+    it."""
+
+    name = "state"
+    #: the cache leaf that addresses this kind's rows; its state leaves
+    #: sit beside it (generation.pool_leaves_by_table)
+    table_leaf = "state_slot"
+
+    def __init__(self, slots):
+        self.slots = int(slots)
+        self.block_bytes = 0    # a slot's row over every layer, set from
+        #                         the cache's own state leaves
+        self._writes = self._rolled = 0
+
+    def need(self, n_tokens):
+        return 0
+
+    def lacks(self, slot, upto):
+        return 0
+
+    def admit(self, slot, n_tokens):
+        """The slot is taken; its prefill (from position 0) writes the
+        row whole."""
+        self._writes += 1
+
+    def grow(self, slot, upto):
+        return 0
+
+    def trim(self, slot, cursor):
+        return 0
+
+    def release(self, slot):
+        """Nothing to give back: the row stays as it is until the next
+        prefill into the slot writes it."""
+
+    def counter_names(self):
+        return ("ssm_state_rows", "ssm_state_writes")
+
+    def count_step(self, counters, cursors, span):
+        """A live row (cursor above 0) is a row whose state the step
+        advances, once over all its layers; an idle row is not read."""
+        counters.inc("ssm_state_rows", int(np.count_nonzero(cursors)))
+
+    def count_turn(self, counters):
+        """Nothing at a token step beyond :meth:`count_step`."""
+
+    def publish(self, counters):
+        counters.gauge("ssm_state_bytes", self.block_bytes * self.slots)
+        if self._writes > self._rolled:
+            counters.inc("ssm_state_writes", self._writes - self._rolled)
+            self._rolled = self._writes
+
+    def model_fields(self):
+        return {"state_slots": self.slots}
+
+    def bytes_per_token(self):
+        """0: a row's bytes are a slot's, whatever its length."""
+        return 0
+
+
 class CacheKinds(object):
     """Every kind of cache an engine's slots hold, under one roof: one
     ``tables [slots, sum of widths]`` array (each kind's columns side
@@ -657,15 +782,20 @@ class CacheKinds(object):
     size. The window kind's is every slot's window
     (:meth:`WindowKind.most_a_slot`): a slot that is free holds none of
     it, so that pool never runs short and admission counts the full
-    kind alone."""
+    kind alone. A ``"state"`` kind (its value: the layers that keep
+    one) is a row a slot and takes no columns of ``tables``."""
 
     def __init__(self, slots, width, block_size, blocks, kinds=None,
                  kv_dtype="float32"):
         kinds = dict(kinds or {})
-        unknown = sorted(set(kinds) - {"window"})
+        unknown = sorted(set(kinds) - {"window", "state"})
         if unknown:
             raise ValueError("unknown cache kind(s) {}".format(unknown))
-        n = 1 + len(kinds)
+        if "state" in kinds and kv_dtype == "int8":
+            raise ValueError(
+                "a state kind keeps its rows at the model's dtype and "
+                "frames no block: no int8 pool beside it")
+        n = 1 + len(kinds) - ("state" in kinds)
         self.tables = np.zeros((slots, n * width), np.int32)
         self.full = FullKind(
             BlockPool(blocks, block_size, kv_dtype=kv_dtype),
@@ -677,6 +807,8 @@ class CacheKinds(object):
             self.kinds.append(WindowKind(
                 BlockPool(n_blocks, block_size, kv_dtype=kv_dtype),
                 self.tables[:, width:2 * width], kinds["window"]))
+        if "state" in kinds:
+            self.kinds.append(StateKind(slots))
 
     def __iter__(self):
         return iter(self.kinds)
@@ -691,7 +823,7 @@ class CacheKinds(object):
         [blocks, block_size, ...]]}``, generation.pool_leaves_by_table):
         whatever a token costs there, K and V of every head at the
         pool's dtype or an int8 pool's scales beside its codes, is in
-        their shapes."""
+        their shapes. A state kind's "block" is a slot's row."""
         for k in self.kinds:
             k.block_bytes = sum(
                 int(np.prod(leaf.shape[1:])) * np.dtype(leaf.dtype).itemsize
@@ -700,5 +832,4 @@ class CacheKinds(object):
     def bytes_per_token(self):
         """``{kind: bytes a cached token costs}`` over the kind's
         layers."""
-        return {k.name: k.block_bytes // k.pool.block_size
-                for k in self.kinds}
+        return {k.name: k.bytes_per_token() for k in self.kinds}

@@ -859,7 +859,8 @@ class DecodeEngine(object):
         if kinds:
             self._check_cache_kinds(
                 model, kinds, prefix_cache=prefix_cache,
-                speculate_k=speculate_k, kv_dtype=kv_dtype, tier=tier)
+                speculate_k=speculate_k, kv_dtype=kv_dtype, tier=tier,
+                block_len=self._block_len)
         if prefix_cache is None:
             prefix_cache = not kinds
         norm_top_k = None if top_k is None else int(top_k)
@@ -925,8 +926,7 @@ class DecodeEngine(object):
         clone_kw = dict(kv_block_size=self.kv_block_size,
                         kv_blocks=self.kv_blocks + 1)
         for kind in self._other_kinds:
-            clone_kw["kv_{}_blocks".format(kind.name)] = \
-                kind.pool.num_blocks + 1
+            clone_kw.update(kind.model_fields())
         if self._kv_quant:
             clone_kw["kv_dtype"] = "int8"
         try:
@@ -1036,12 +1036,9 @@ class DecodeEngine(object):
         # start: an engine that never runs ahead reads 0, not absent
         self.counters.inc("steps_dispatched_ahead", 0)
         self.counters.inc("tokens_dropped_in_flight", 0)
-        self.counters.inc("attn_grid_steps", 0)
-        self.counters.inc("attn_table_slots", 0)
-        for kind in self._other_kinds:
-            for name in ("kv_{}_block_steps", "kv_{}_blocks_given_back",
-                         "attn_{}_grid_steps", "attn_{}_table_slots"):
-                self.counters.inc(name.format(kind.name), 0)
+        for kind in self._kv:
+            for name in kind.counter_names():
+                self.counters.inc(name, 0)
         # host-authoritative block tables: row s mirrors
         # _slot_blocks[s] padded with scratch (0). A freed slot's
         # row resets to scratch AND its cursor to 0, so the idle
@@ -1127,11 +1124,33 @@ class DecodeEngine(object):
 
     @staticmethod
     def _check_cache_kinds(model, kinds, prefix_cache, speculate_k,
-                           kv_dtype, tier):
+                           kv_dtype, tier, block_len=0):
         """Refuse, with the reason, what an engine does not do yet for
         a model whose layers keep more than one kind of cache
-        (docs/serving.md, "Two kinds of cache")."""
+        (docs/serving.md, "Two kinds of cache" and "A recurrent state a
+        slot")."""
         why = None
+        if "state" in kinds:
+            if prefix_cache:
+                why = "a prefix hit would need the state at the prefix's " \
+                      "end, and no snapshot of it is kept: no prefix_cache"
+            elif speculate_k is not None:
+                why = "a rejected draft would need the state rolled " \
+                      "back: no speculate_k"
+            elif kv_dtype not in (None, "fp32", "float32"):
+                why = "its K/V pool and state rows are stored at the " \
+                      "model's dtype: no kv_dtype"
+            elif tier != "mixed":
+                why = "kvship frames K/V blocks and a state row is none: " \
+                      "no tier={!r}".format(tier)
+            elif block_len:
+                why = "every denoising pass would advance the state: no " \
+                      "stepping by blocks"
+            if why:
+                raise ValueError(
+                    "{} keeps a recurrent state a slot beside its paged "
+                    "K/V: {}".format(type(model).__name__, why))
+            return
         if prefix_cache:
             why = "a prefill attends its own K and V from position 0 " \
                   "and a window layer keeps no block to share: no " \
@@ -1703,7 +1722,7 @@ class DecodeEngine(object):
         # the largest bucket first: it is the longest to compile
         calls = [(self._prefill_fn, (
             self.params, self._cache, int32(self._tables.shape[1]),
-            int32(b), int32(), int32(), self._key))
+            int32(b), int32(), int32(), self._key, int32()))
             for b in sorted(self.buckets, reverse=True)]
         calls.append(self._step_call())
         with concurrent.futures.ThreadPoolExecutor(len(calls)) as pool:
@@ -1815,13 +1834,10 @@ class DecodeEngine(object):
         ``attn_grid_steps`` over ``attn_table_slots`` is the share of
         the tables that is walked (one call's; every layer's of a kind
         is the same, and a kind beside the full one counts under its
-        own name: ``attn_window_grid_steps``)."""
+        own name: ``attn_window_grid_steps``). A state kind counts the
+        rows whose state the step advances (``ssm_state_rows``)."""
         for kind in self._kv:
-            live = kind.grid_steps(cursors, span)
-            of = "attn_" if kind is self._kv.full \
-                else "attn_{}_".format(kind.name)
-            self.counters.inc(of + "grid_steps", int(live.sum()))
-            self.counters.inc(of + "table_slots", live.size * kind.width)
+            kind.count_step(self.counters, cursors, span)
 
     def _ewma(self, prev, sample):
         return sample if prev is None \
@@ -2231,8 +2247,7 @@ class DecodeEngine(object):
                 "kv_block_steps",
                 self._pool.num_blocks - self._pool.allocatable())
             for kind in self._other_kinds:
-                self.counters.inc("kv_{}_block_steps".format(kind.name),
-                                  kind.in_use())
+                kind.count_turn(self.counters)
         if older is None:
             return
         # the pace, read to read (dispatch to read after a pause):
@@ -2495,10 +2510,7 @@ class DecodeEngine(object):
         self.counters.gauge("kv_blocks_free", stats["free"])
         self.counters.gauge("kv_blocks_cached", stats["cached"])
         for kind in self._other_kinds:
-            self.counters.gauge("kv_{}_blocks_total".format(kind.name),
-                                kind.pool.num_blocks)
-            self.counters.gauge("kv_{}_blocks_free".format(kind.name),
-                                kind.pool.allocatable())
+            kind.publish(self.counters)
         # digest exposition (PR 16): how many chains the beat-carried
         # digest currently publishes, and whether the top-K bound cut
         # anything (the truncation-honesty flag, scrapeable)
@@ -3008,7 +3020,7 @@ class DecodeEngine(object):
                 self._cache, first = self._prefill_fn(
                     self.params, self._cache, jnp.asarray(row),
                     jnp.asarray(toks), jnp.int32(len(tail)),
-                    jnp.int32(start), self._next_key())
+                    jnp.int32(start), self._next_key(), jnp.int32(slot))
                 first, routed = self._generation.split_routed(
                     first, 1, bucket, self._top_k)
                 first = int(first[0])
@@ -3049,7 +3061,7 @@ class DecodeEngine(object):
                     self._draft_params, self._draft_cache,
                     jnp.asarray(row), jnp.asarray(toks),
                     jnp.int32(len(tail)), jnp.int32(start),
-                    self._next_key())
+                    self._next_key(), jnp.int32(slot))
         if self.prefix_cache:
             # publish every FULL block of the admitted sequence (now
             # holding valid K/V) under its token-chain key;

@@ -356,6 +356,18 @@ METRIC_FAMILIES = {
     "tfos_serving_attn_window_table_slots":
         ("counter", "", "tfos_serving_attn_table_slots for the window "
                         "layers' table"),
+    "tfos_serving_ssm_state_rows":
+        ("counter", "", "rows whose recurrent state a token step "
+                        "advanced, one a live row a step over all its "
+                        "layers, counted where the feed is built, for a "
+                        "model with a state kind (over "
+                        "tfos_serving_decode_steps: the rows the "
+                        "ssm_step kernel walks; an idle slot's row is "
+                        "neither read nor written)"),
+    "tfos_serving_ssm_state_writes":
+        ("counter", "", "prefills that wrote a slot's whole state row "
+                        "(from position 0: an admission or a preempted "
+                        "request's re-entry)"),
     "tfos_serving_expert_calls":
         ("counter", "", "expert-layer calls counted (layers x steps "
                         "and prefills), for a model with sparse experts "
@@ -405,6 +417,11 @@ METRIC_FAMILIES = {
                       "cache (a model with such layers only)"),
     "tfos_serving_kv_window_blocks_free":
         ("gauge", "", "blocks of that pool obtainable right now"),
+    "tfos_serving_ssm_state_bytes":
+        ("gauge", "", "bytes the state rows hold, every slot and layer "
+                      "(SSM state and the convolution's last inputs), "
+                      "read off the cache's own state leaves (a model "
+                      "with a state kind only)"),
     "tfos_serving_kv_blocks_cached":
         ("gauge", "", "refcount-0 blocks retained by the prefix cache "
                       "(evictable subset of kv_blocks_free)"),

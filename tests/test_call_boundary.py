@@ -168,7 +168,7 @@ def test_a_whole_cache_collection_is_read_for_its_pools(lm):
     tables = np.zeros((2, bps), np.int32)
     tables[0] = np.arange(1, bps + 1)
     args = (jnp.asarray(tables[0]), jnp.asarray(toks), jnp.int32(3),
-            jnp.int32(0), jax.random.PRNGKey(0))
+            jnp.int32(0), jax.random.PRNGKey(0), jnp.int32(0))
     feed = generation.pack_step_feed(
         np.array([-1, -1], np.int32), np.array([3, 0], np.int32), tables)
     got = []

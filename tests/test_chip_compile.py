@@ -250,7 +250,8 @@ def _lower_engine_program(program, model, params, cache, sds, slots, total):
             sds((slots, 2 + total // KV_BLOCK), jnp.int32), key)
     return prefill.lower(
         params, cache, sds((total // KV_BLOCK,), jnp.int32),
-        sds((128,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32), key)
+        sds((128,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32), key,
+        sds((), jnp.int32))
 
 
 @pytest.fixture
@@ -260,7 +261,7 @@ def kernel_on_cpu_backend(monkeypatch):
     paged_attention = pa.paged_attention
     monkeypatch.setattr(
         pa, "paged_attention",
-        lambda *a, impl=None, **kw: paged_attention(
+        lambda *a, impl=None, interpret=None, **kw: paged_attention(
             *a, impl="pallas", interpret=False, **kw))
 
 
@@ -574,3 +575,106 @@ def test_paged_attention_of_a_mellum_step_compiles_for_v5e(one_chip, window):
         sds((MELLUM["slots"], 1), jnp.int32))
     assert lowered.as_text().count('kernel_name = "paged_attention"') == 1
     assert _has_kernel(lowered.compile())
+
+
+#: Falcon-H1-34B's mixer and the cell's engine (PR 38): 32 SSM heads of
+#: 128 in 2 groups, d_state 256, d_conv 4, 64 slots
+FALCON = dict(hidden=5120, heads=20, kv_heads=4, head_dim=128,
+              mlp_hidden=21504, ssm_heads=32, ssm_head_dim=128,
+              ssm_groups=2, ssm_state=256, conv=4, slots=64, total=1536,
+              block=128)
+
+
+@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16])
+def test_ssm_step_at_falcon_widths_compiles_for_v5e(one_chip, state_dtype):
+    """The state's step kernel over 64 rows, the state donated: ONE
+    kernel call, the state and the convolution's inputs answered in
+    place (aliased), no copy of a state-sized array."""
+    ss = importlib.import_module("tensorflowonspark_tpu.ops.ssm_step")
+    f = FALCON
+    s, nh, p, n = f["slots"], f["ssm_heads"], f["ssm_head_dim"], \
+        f["ssm_state"]
+    width = nh * p + 2 * f["ssm_groups"] * n
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lowered = jax.jit(
+        lambda *a: ss.ssm_step(*a, impl="pallas", interpret=False),
+        donate_argnums=(0, 1)).lower(
+        sds((s, nh, p, n), state_dtype),
+        sds((s, f["conv"] - 1, width), jnp.bfloat16),
+        sds((s, nh, p), jnp.bfloat16), sds((s, nh), jnp.float32),
+        sds((nh,), jnp.float32), sds((s, f["ssm_groups"], n), jnp.bfloat16),
+        sds((s, f["ssm_groups"], n), jnp.bfloat16), sds((nh,), jnp.float32),
+        sds((s, width), jnp.bfloat16), sds((s,), jnp.int32),
+        sds((s,), jnp.bool_))
+    assert lowered.as_text().count('kernel_name = "ssm_step"') == 1
+    compiled = lowered.compile()
+    assert _has_kernel(compiled)
+    state_bytes = s * nh * p * n * jnp.dtype(state_dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 100
+
+
+def test_falcon_step_program_of_one_layer_compiles_for_v5e(
+        one_chip, kernel_on_cpu_backend, monkeypatch):
+    """The engine's token step of a one-layer Falcon-H1 at the published
+    widths and the cell's 64 slots: one paged-attention and one
+    ``ssm_step`` call, the pools and the state rows donated with no copy
+    of either."""
+    from benchmarks.reference import falcon_h1 as ref
+    from tensorflowonspark_tpu import generation
+    from tensorflowonspark_tpu.models import falcon_h1 as fh
+
+    ss = importlib.import_module("tensorflowonspark_tpu.ops.ssm_step")
+    step_op = ss.ssm_step
+    monkeypatch.setattr(ss, "ssm_step", lambda *a, impl=None, **kw: step_op(
+        *a, impl="pallas", interpret=False, **kw))
+    f = FALCON
+    model = dict(vocab=4096, hidden=f["hidden"], num_heads=f["heads"],
+                 num_kv_heads=f["kv_heads"], head_dim=f["head_dim"],
+                 num_layers=1, mlp_hidden=f["mlp_hidden"],
+                 ssm_heads=f["ssm_heads"], ssm_head_dim=f["ssm_head_dim"],
+                 ssm_groups=f["ssm_groups"], ssm_state=f["ssm_state"],
+                 conv_kernel=f["conv"], chunk=128, max_len=f["total"])
+    blocks = f["slots"] * f["total"] // f["block"]
+    dec = fh.FalconH1LM(decode=True, kv_block_size=f["block"],
+                        kv_blocks=blocks + 1, state_slots=f["slots"],
+                        **model)
+
+    def placed(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: ref.init_params(jax.random.PRNGKey(0), dict(
+            fh_defaults(), **model))))
+    pools = placed(jax.eval_shape(lambda: generation.init_pools(dec)))
+    _, step = generation.paged_step_fns(dec, 0.0, None, None)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = step.lower(
+        params, pools, sds((f["slots"],)),
+        sds((f["slots"], 2 + f["total"] // f["block"])),
+        sds((2,), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    state = f["slots"] * f["ssm_heads"] * f["ssm_head_dim"] \
+        * f["ssm_state"] * jnp.dtype(dec.dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 64 * 1024 * 1024
+
+
+def fh_defaults():
+    """The published multipliers, as the configuration's file has them."""
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "falcon-h1-34b.json")) as f:
+        return json.load(f)["model"]

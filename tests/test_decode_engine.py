@@ -445,7 +445,7 @@ def test_sampled_request_alone_draws_as_a_serial_loop_does(lm):
     tables[0] = np.arange(1, bps + 1)
     cache, first = prefill(params, cache, jnp.asarray(tables[0]),
                            jnp.asarray(toks), jnp.int32(len(prompt)),
-                           jnp.int32(0), sub)
+                           jnp.int32(0), sub, jnp.int32(0))
     want, idx = [int(first)], np.zeros(slots, np.int32)
     idx[0] = len(prompt)
     for _ in range(max_new - 1):

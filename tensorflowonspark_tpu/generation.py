@@ -272,7 +272,10 @@ def pack_step_feed(given, idx, tables):
     token ``given [S]`` a row starts from, -1 where the row feeds back
     the device's own pick; column 1 the cursors ``idx [S]``; after them
     the block ``tables [S, MB]``."""
-    return pack_block_feed(given[:, None], idx, tables)
+    import numpy as np
+
+    return np.concatenate([given[:, None], idx[:, None], tables],
+                          axis=1, dtype=np.int32)
 
 
 # -- paged-KV slot primitives (PR 8) -----------------------------------
@@ -578,13 +581,17 @@ def paged_step_fns(model, temperature=0.0, top_k=None, top_p=None):
 # step. A step runs the ``block_len`` positions of every slot's current
 # block against the paged cache - their K/V computed from the block's
 # current, partly masked state and written at the slot's cursor - and
-# answers, per position, the most probable token and its probability.
-# The HOST decides what that means (:func:`unmask`): it keeps the
-# cursor where it is while masks are left (so the next pass overwrites
-# the block's K/V) and moves it on by ``block_len`` after the pass that
-# ran the final tokens (the commit). Every slot is in its own phase, so
-# there is ONE step program of shape ``[slots, block_len]``; a prefill
-# writes the prompt's whole blocks and samples nothing.
+# unmasks, in the same program, by the rule (:func:`device_unmask`)
+# what the pass was confident of. Each slot's block lives on the device
+# between passes (:func:`init_block_state`), so the next pass's input
+# is the program's own output and a pass can be dispatched before the
+# one before it has been read. The host keeps the cursor where it is
+# while masks are left (so the next pass overwrites the block's K/V)
+# and moves it on by ``block_len`` for the pass after the one that ran
+# the final tokens (the commit), which also starts the row's next block
+# on the device. Every slot is in its own phase, so there is ONE step
+# program of shape ``[slots, block_len]``; a prefill writes the
+# prompt's whole blocks and samples nothing.
 
 
 def _expert_ids(intermediates):
@@ -628,6 +635,81 @@ _paged_block_prefill, _paged_block_step = paged_block_prefill, \
     paged_block_step
 
 
+def init_block_state(slots, block_len):
+    """Each slot's current block as the step program keeps it on the
+    device between passes: its ``tokens [S, B]``, which positions are
+    still ``masked [S, B]``, the pass of its block ``when [S, B]`` each
+    position was unmasked at (-1: not yet, or given) and the block's
+    denoising ``passes [S]`` so far. Every row starts as a fresh block:
+    all masked, pass 0."""
+    shape = (slots, block_len)
+    return {"tokens": jnp.zeros(shape, jnp.int32),
+            "masked": jnp.ones(shape, bool),
+            "when": jnp.full(shape, -1, jnp.int32),
+            "passes": jnp.zeros((slots,), jnp.int32)}
+
+
+def device_unmask(conf, masked, quota, threshold):
+    """:func:`unmask` inside a program, to the bit: the same comparisons
+    on the same float32 confidences. The stable ranking by confidence
+    is counted pairwise (a position's rank is the positions more
+    confident than it, and the equally confident ones before it), which
+    over a block of a few positions is a handful of comparisons."""
+    conf = jnp.where(masked, conf, -jnp.inf)
+    high = conf > threshold
+    pos = jnp.arange(conf.shape[-1])
+    mine, other = conf[..., :, None], conf[..., None, :]
+    rank = ((other > mine)
+            | ((other == mine) & (pos[None, :] < pos[:, None]))).sum(-1)
+    return jnp.where(high.sum(axis=-1, keepdims=True) >= quota, high,
+                     (rank < quota) & masked)
+
+
+def _restart_blocks(state, given, count):
+    """The rows the host (re)starts: where ``count >= 0`` a fresh block
+    whose first ``count`` positions are ``given`` (the prompt's last
+    ``len % block_len`` tokens, or those delivered before a preemption)
+    and the rest masked; every other row as the device left it."""
+    pos = jnp.arange(given.shape[-1])
+    over = (count >= 0)[:, None]
+    fresh = pos[None, :] < count[:, None]
+    return {"tokens": jnp.where(over, jnp.where(fresh, given, 0),
+                                state["tokens"]),
+            "masked": jnp.where(over, ~fresh, state["masked"]),
+            "when": jnp.where(over, -1, state["when"]),
+            "passes": jnp.where(over[:, 0], 0, state["passes"])}
+
+
+def _advance_blocks(model, state, best, conf, live):
+    """What a pass means for each row, in the program: positions inside
+    the request (``live [S]`` of them; those past its end stay masked
+    for good) that the rule picks take the pass's best token. A row
+    that entered the pass with no mask left inside ran its COMMIT: it
+    answers the block and restarts as a fresh one (after a commit the
+    next block has nothing given). Returns ``(state', rows [S, 2B + 3]
+    int32)``: per row the block as the pass left it, its ``when``, the
+    commit flag, the positions unmasked and the masks left inside."""
+    b = model.block_len
+    quota = max(1, b // model.denoise_steps)
+    inside = jnp.arange(b)[None, :] < live[:, None]
+    masked = state["masked"] & inside
+    commit = ~masked.any(axis=-1)
+    chosen = device_unmask(conf, masked, quota, model.confidence_threshold)
+    tokens = jnp.where(chosen, best, state["tokens"])
+    when = jnp.where(chosen, state["passes"][:, None], state["when"])
+    left = state["masked"] & ~chosen
+    rows = jnp.concatenate([
+        tokens, when, commit[:, None].astype(jnp.int32),
+        chosen.sum(axis=-1, keepdims=True, dtype=jnp.int32),
+        (left & inside).sum(axis=-1, keepdims=True, dtype=jnp.int32)],
+        axis=1)
+    done = commit[:, None]
+    return {"tokens": jnp.where(done, 0, tokens),
+            "masked": done | left,
+            "when": jnp.where(done, -1, when),
+            "passes": jnp.where(commit, 0, state["passes"] + 1)}, rows
+
+
 @functools.lru_cache(maxsize=32)
 def paged_block_fns(model):
     """(jitted block prefill, jitted block step) for one block-stepping
@@ -635,51 +717,64 @@ def paged_block_fns(model):
     compile-count contract (one step program, one prefill per bucket),
     the same naming of programs and the same form of the cache.
 
-    The step runs between every two host decisions with the device
-    waiting on both sides, and every array that crosses is a round
-    trip of its own. So it takes ONE array (:func:`pack_block_feed`)
-    and answers ONE (:func:`unpack_block_step`): ``step(params, cache,
-    feed [S, B + 1 + MB] int32) -> (cache', answers int32)``."""
+    The step is ``step(params, cache, state, feed [S, B + 3 + MB]
+    int32) -> (cache', state', answer int32)``: ``state`` is every
+    slot's block (:func:`init_block_state`), donated and answered like
+    the pools, so that a pass runs from where the one before it left
+    the blocks, dispatched before that one has been read; ``feed`` the
+    host's part in ONE array (:func:`pack_block_feed`) and ``answer``
+    what the host needs of the pass in ONE (:func:`unpack_block_step`):
+    every array that crosses is a transfer of its own."""
     def paged_block_prefill(params, cache, table_row, tokens, start):
         return _paged_block_prefill(model, params, cache, table_row,
                                     tokens, start)
 
-    def paged_block_step(params, cache, feed):
+    def paged_block_step(params, cache, state, feed):
         b = model.block_len
+        state = _restart_blocks(state, feed[:, :b], feed[:, b])
+        tokens = jnp.where(state["masked"], model.mask_token_id,
+                           state["tokens"])
         cache, (best, conf, routed) = _paged_block_step(
-            model, params, cache, feed[:, :b], feed[:, b], feed[:, b + 1:])
-        # the confidences bit for bit among the int32
-        return cache, jnp.concatenate([
-            best.ravel(),
-            jax.lax.bitcast_convert_type(conf, jnp.int32).ravel(),
-            routed.astype(jnp.int32).ravel()])
+            model, params, cache, tokens, feed[:, b + 1], feed[:, b + 3:])
+        state, rows = _advance_blocks(model, state, best, conf,
+                                      feed[:, b + 2])
+        return cache, state, jnp.concatenate(
+            [rows.ravel(), routed.astype(jnp.int32).ravel()])
 
     return (jax.jit(paged_block_prefill, donate_argnums=(1,)),
-            jax.jit(paged_block_step, donate_argnums=(1,)))
+            jax.jit(paged_block_step, donate_argnums=(1, 2)))
 
 
-def pack_block_feed(tokens, idx, tables):
-    """The jitted block step's one argument (host side, numpy): each
-    slot's block ``tokens [S, B]``, its cursor ``idx [S]`` and its
-    table row ``tables [S, MB]`` side by side, int32."""
+def pack_block_feed(given, count, idx, live, tables):
+    """The jitted block step's one argument (host side, numpy), per
+    slot: the ``given [S, B]`` tokens and their ``count [S]`` of a block
+    the host (re)starts (-1: the row goes on from the device's own
+    block), the cursor ``idx [S]``, the positions of the block inside
+    the request ``live [S]`` and the table row ``tables [S, MB]``, side
+    by side, int32."""
     import numpy as np
 
-    return np.concatenate([tokens, idx[:, None], tables],
-                          axis=1, dtype=np.int32)
+    return np.concatenate([given, count[:, None], idx[:, None],
+                           live[:, None], tables], axis=1, dtype=np.int32)
 
 
 def unpack_block_step(packed, slots, block_len, top_k):
-    """``(best [S, B] int32, confidence [S, B] float32, expert_ids
-    [layers, S * B, top_k] int32)`` out of the one array the jitted
-    block step of :func:`paged_block_fns` answers (host side, numpy;
-    views, nothing is copied)."""
+    """What the jitted block step of :func:`paged_block_fns` answers
+    (host side, numpy; views, nothing is copied), per slot: the block's
+    ``tokens [S, B]`` and ``when [S, B]`` as the pass left them (a
+    commit's are the block's final ones), ``commit [S]``, ``unmasked
+    [S]`` positions and masks ``left [S]`` inside the request; and the
+    ``expert_ids [layers, S * B, top_k]`` each position was routed
+    to."""
     import numpy as np
 
     packed = np.asarray(packed)
-    n = slots * block_len
-    return (packed[:n].reshape(slots, block_len),
-            packed[n:2 * n].view(np.float32).reshape(slots, block_len),
-            packed[2 * n:].reshape(-1, n, top_k))
+    b, n = block_len, slots * (2 * block_len + 3)
+    rows = packed[:n].reshape(slots, 2 * b + 3)
+    return {"tokens": rows[:, :b], "when": rows[:, b:2 * b],
+            "commit": rows[:, 2 * b].astype(bool),
+            "unmasked": rows[:, 2 * b + 1], "left": rows[:, 2 * b + 2],
+            "expert_ids": packed[n:].reshape(-1, slots * b, top_k)}
 
 
 def unmask(conf, masked, quota, threshold):
@@ -689,7 +784,10 @@ def unmask(conf, masked, quota, threshold):
     (``low_confidence_dynamic``; a threshold of 1 or more is the static
     schedule of ``quota`` a pass). Ties go to the earlier position.
     Boolean, ``masked``'s shape: one block ``[B]``, or the blocks of
-    many rows ``[..., B]`` at once, each by itself."""
+    many rows ``[..., B]`` at once, each by itself. The engine's step
+    program applies the same rule itself (:func:`device_unmask`); this
+    is what the cacheless generation (models/sdar_moe.py) runs and what
+    that one is held to."""
     import numpy as np
 
     conf = np.where(masked, conf, -np.inf)

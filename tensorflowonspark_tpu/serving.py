@@ -1019,23 +1019,27 @@ class DecodeEngine(object):
         self._slot_req = [None] * self.slots
         self._idx = np.zeros(self.slots, np.int32)
         self._last = np.zeros(self.slots, np.int32)
-        # the token step in flight (dispatched, its tokens not read
-        # yet): the device's answer ``picked [S]``, which is also the
-        # next step's input, per slot the request it was dispatched for
-        # (None: the row was idle) and when. A token engine keeps at
-        # most one; block-stepping and speculative engines none, ever.
-        # (behind the tokens, the routed experts of a model that sows
-        # them: generation.with_routed)
+        # the step in flight (dispatched, its answer not read yet): the
+        # device's answer (a token step's ``picked [S]``, which is also
+        # the next step's input; a block pass's blocks), per slot the
+        # request it was dispatched for (None: the row was idle) and
+        # when. A token or block-stepping engine keeps at most one, a
+        # speculative one none, ever. (behind the tokens, the routed
+        # experts of a model that sows them: generation.with_routed)
         self._picked = jnp.zeros(
             (generation.answer_len(model, self.slots, self.slots),),
             jnp.int32)
         self._top_k = int(getattr(model, "experts_per_tok", 0))
-        self._flight = None   # or (picked, [request or None] * S, t0)
+        # or (answer, [request or None] * S, t0), and a block pass's
+        # given and inside positions per slot and the prefills before it
+        self._flight = None
         self._read_at = 0.0   # when a step's tokens were last read
         # how often it engages and what it cost, exported from the
         # start: an engine that never runs ahead reads 0, not absent
         self.counters.inc("steps_dispatched_ahead", 0)
         self.counters.inc("tokens_dropped_in_flight", 0)
+        if self._block_len:
+            self.counters.inc("block_rows_dropped_in_flight", 0)
         for kind in self._kv:
             for name in kind.counter_names():
                 self.counters.inc(name, 0)
@@ -1054,19 +1058,19 @@ class DecodeEngine(object):
         # boundary crossings and completion advance it
         self._slot_registered = [0] * self.slots
         if self._block_len:
-            # each slot's current block, the host's: its tokens, which
-            # positions are still masked, at which pass of the block
-            # each was unmasked (for the handle), how many leading
-            # positions were given (prompt, or delivered before a
-            # preemption), how many are inside the request, and the
-            # number of denoising passes the block has had
-            blk = (self.slots, self._block_len)
-            self._blk_tok = np.zeros(blk, np.int32)
-            self._blk_masked = np.ones(blk, bool)
-            self._blk_when = np.full(blk, -1, np.int32)
+            # each slot's current block lives on the device, the step
+            # program's (generation.init_block_state). The host's part:
+            # how many leading positions of the block at the cursor were
+            # given (prompt, or delivered before a preemption), the given
+            # tokens of a block it (re)starts at the next dispatch, a
+            # commit learnt before its dispatch (the cursor moves on
+            # behind it), and the experts of prefills not read yet
+            self._blk_state = generation.init_block_state(
+                self.slots, self._block_len)
             self._blk_given = np.zeros(self.slots, np.int32)
-            self._blk_live = np.zeros(self.slots, np.int32)
-            self._blk_pass = np.zeros(self.slots, np.int32)
+            self._blk_over = [None] * self.slots
+            self._blk_due = np.zeros(self.slots, bool)
+            self._routed_due = []
         # the cache on the device: the POOLS only (cursors and tables
         # are this thread's numpy and reach a program inside its feed)
         self._cache = generation.init_pools(model)
@@ -1673,8 +1677,8 @@ class DecodeEngine(object):
                 self._key)
         if self._block_len:
             return self._decode_fn, (
-                self.params, self._cache,
-                int32(s, self._block_len + 1 + width))
+                self.params, self._cache, self._blk_state,
+                int32(s, self._block_len + 3 + width))
         return self._decode_fn, (self.params, self._cache, self._picked,
                                  int32(s, 2 + width), self._key)
 
@@ -1777,18 +1781,6 @@ class DecodeEngine(object):
         return not (self._stopping or self._queue or self._kv_jobs
                     or self._active_slots())
 
-    def _step_feed(self, jnp):
-        """The per-step inputs of a block step or a speculative round:
-        what ``step_upload`` times (a token step: :meth:`_token_feed`)."""
-        if self._block_len:
-            # one array, handed over as numpy: the call transfers
-            # it itself, with no ``jnp.asarray`` round trip before it
-            self._count_attn_grid(self._idx, self._block_len)
-            return [self._generation.pack_block_feed(
-                self._block_feed(), self._idx, self._tables)]
-        return [jnp.asarray(self._last), jnp.asarray(self._idx),
-                jnp.asarray(self._tables), self._next_key()]
-
     def _in_flight(self, slot):
         """Whether the step in flight computes a token of the request
         now in ``slot`` (a slot freed and admitted again since the
@@ -1800,9 +1792,13 @@ class DecodeEngine(object):
     def _owes_step(self, slot):
         """Whether the request in ``slot`` needs a row in the next
         step: not when the tokens it holds and the one in flight make
-        up its ``max_new_tokens`` (finish by length is known before
-        the read)."""
+        up its ``max_new_tokens``, nor when its last block's commit is
+        in flight, which has moved its cursor past the request's end
+        (finish by length is known before the read)."""
         handle = self._slot_req[slot]
+        if self._block_len:
+            return self._idx[slot] < len(handle.prompt) \
+                + handle.max_new_tokens
         return len(handle._tokens) + self._in_flight(slot) \
             < handle.max_new_tokens
 
@@ -2134,34 +2130,23 @@ class DecodeEngine(object):
                 # scopes an only=<replica> injection to THIS engine of
                 # an in-process fleet)
                 chaos.on_decode_step(steps, self.replica_id)
-                if not (self._spec_k or self._block_len):
-                    # a token step's next input is the device's own
-                    # output, so one step stays in flight while the
-                    # host reads and schedules; where the next input
-                    # is a decision the host makes from the answers
-                    # (below) the turn reads before it schedules
+                if not self._spec_k:
+                    # the next input is the device's own output (a token
+                    # step's pick; a block pass's blocks, unmasked in
+                    # the program), so one step stays in flight while
+                    # the host reads and schedules; where it is a
+                    # decision the host makes from the answers (a
+                    # speculative round's acceptance, below) the turn
+                    # reads before it schedules
                     rows = [s for s in active if self._owes_step(s)]
-                    self._token_turn(rows, steps)
+                    if self._block_len:
+                        self._block_turn(rows, steps)
+                    else:
+                        self._token_turn(rows, steps)
                     steps += bool(rows)
                     continue
                 t0 = time.monotonic()
-                if self._spec_k:
-                    drafts, targets = self._spec_round(jnp)
-                else:
-                    # upload, the jitted call until it returns (the
-                    # host path of a dispatch: the device works on
-                    # after it), the blocking read of the answers
-                    with self.timers.timed("decode_step"):
-                        with self.timers.timed("step_upload"):
-                            feed = self._step_feed(jnp)
-                        with self.timers.timed("step_dispatch"):
-                            self._cache, toks = self._decode_fn(
-                                self.params, self._cache, *feed)
-                        with self.timers.timed("step_sync"):
-                            # the per-step host sync (tokens,
-                            # confidences and the routed experts in
-                            # one array)
-                            toks = np.asarray(toks)
+                drafts, targets = self._spec_round(jnp)
                 t1 = time.monotonic()
                 self._step_ewma = self._ewma(self._step_ewma, t1 - t0)
                 self._hist_step.observe(t1 - t0)
@@ -2178,11 +2163,7 @@ class DecodeEngine(object):
                     "kv_block_steps",
                     self._pool.num_blocks - self._pool.allocatable())
                 with self.timers.timed("host_schedule"):
-                    if self._spec_k:
-                        delivered = self._spec_deliver(active, drafts,
-                                                       targets)
-                    else:
-                        delivered = self._block_advance(active, toks)
+                    delivered = self._spec_deliver(active, drafts, targets)
                     self.counters.inc("tokens", delivered)
                     # decode_tokens excludes prefill-emitted firsts, so
                     # rate("decode_tokens", "decode_steps") is true
@@ -2286,9 +2267,13 @@ class DecodeEngine(object):
     def _retire(self):
         """Read and deliver the step in flight, if there is one, with
         nothing dispatched after it: for whatever needs a slot's tokens
-        whole (a preemption's re-prefill, a KV job, stop, parking)."""
+        or block whole (a preemption's re-prefill, a KV job, stop,
+        parking)."""
         if self._flight is not None:
-            self._token_turn((), None)
+            if self._block_len:
+                self._block_turn((), None)
+            else:
+                self._token_turn((), None)
 
     def _fail_outstanding(self, err):
         """Fail every queued and in-flight handle (scheduler thread
@@ -2335,7 +2320,8 @@ class DecodeEngine(object):
         targets [S, k])`` host arrays."""
         with self.timers.timed("spec_round"):
             with self.timers.timed("step_upload"):
-                feed = self._step_feed(jnp)
+                feed = [jnp.asarray(self._last), jnp.asarray(self._idx),
+                        jnp.asarray(self._tables), self._next_key()]
             with self.timers.timed("step_dispatch"):
                 self._cache, self._draft_cache, drafts, targets = \
                     self._round_fn(
@@ -2395,87 +2381,153 @@ class DecodeEngine(object):
     # The host half of generation by diffusion over blocks
     # (generation.py, "block-stepping primitives"). Each slot is in its
     # own phase: denoising pass k of its block, or the commit. A pass is
-    # the same program at the same cursor; only after the pass that ran
-    # a block's final tokens does the cursor move on.
+    # the same program at the same cursor; only for the pass after the
+    # one that ran a block's final tokens does the cursor move on. The
+    # blocks and the unmasking rule are the program's, so a turn runs as
+    # a token engine's does: dispatch pass n+1, then read pass n.
 
-    def _block_feed(self):
-        """``[slots, block_len]`` tokens of the next pass: each slot's
-        block as it stands, MASK where a position is still masked (a
-        boolean per position, never a comparison with the MASK id)."""
-        return np.where(self._blk_masked, self._model.mask_token_id,
-                        self._blk_tok).astype(np.int32)
+    def _block_turn(self, rows, step):
+        """One turn of a block-stepping engine: upload and dispatch a
+        pass for the slots ``rows`` (none: nothing owes one), THEN read
+        the pass dispatched a turn ago and deliver its commits
+        (:meth:`_block_read`), so that the read, the deliveries and the
+        next turn's scheduling run while the device computes.
+        ``step_sync`` is the wait for the OLDER pass."""
+        older, t0 = self._flight, time.monotonic()
+        with self.timers.timed("decode_step"):
+            if rows:
+                with self.timers.timed("step_upload"):
+                    feed, given, live = self._pass_feed(rows)
+                with self.timers.timed("step_dispatch"):
+                    self._cache, self._blk_state, answer = self._decode_fn(
+                        self.params, self._cache, self._blk_state, feed)
+                    answer.copy_to_host_async()
+                for s in rows:
+                    if self._blk_due[s]:
+                        # a commit learnt before its dispatch: the pass
+                        # after it runs the next block
+                        self._next_block(s)
+            self._flight = None
+            if rows:
+                owners = [None] * self.slots
+                for s in rows:
+                    owners[s] = self._slot_req[s]
+                self._flight = (answer, owners, t0, given, live,
+                                self._routed_due)
+                self._routed_due = []
+            if older is not None:
+                with self.timers.timed("step_sync"):
+                    answers = self._generation.unpack_block_step(
+                        older[0], self.slots, self._block_len, self._top_k)
+        t1 = time.monotonic()
+        # engine-row span (tid 0): this turn's share of the loop
+        self.flight.span("decode_step", t0, t1, active=len(rows),
+                         step=step)
+        if rows:
+            self.counters.inc("decode_steps")
+            self.counters.inc("steps_dispatched_ahead",
+                              int(older is not None))
+            # blocks held by in-flight sequences, summed over the
+            # steps: kv_block_steps / decode_steps is the mean
+            # occupancy of the pool the steps saw
+            self.counters.inc(
+                "kv_block_steps",
+                self._pool.num_blocks - self._pool.allocatable())
+        if older is None:
+            return
+        # the pace, read to read (dispatch to read after a pause)
+        pace = t1 - max(self._read_at, older[2])
+        self._read_at = t1
+        self._step_ewma = self._ewma(self._step_ewma, pace)
+        self._hist_step.observe(pace)
+        with self.timers.timed("host_schedule"):
+            self._block_read(older, answers)
 
-    def _fresh_block(self, slot, handle):
-        """Start the block at the slot's cursor: positions the sequence
-        already holds (the prompt's last ``len % block_len`` tokens, or
-        tokens delivered before a preemption) are given, the others
-        masked; positions past the end of the request stay masked for
-        good and are never unmasked."""
-        seq = handle.prompt + handle._tokens
-        start = int(self._idx[slot])
-        given = len(seq) - start
-        self._blk_given[slot] = given
-        self._blk_live[slot] = min(
-            self._block_len,
-            len(handle.prompt) + handle.max_new_tokens - start)
-        self._blk_tok[slot] = 0
-        self._blk_tok[slot, :given] = seq[start:]
-        self._blk_masked[slot] = np.arange(self._block_len) >= given
-        self._blk_when[slot] = -1
-        self._blk_pass[slot] = 0
+    def _pass_feed(self, rows):
+        """The host's part of a block pass for the slots ``rows``
+        (generation.pack_block_feed): a row admitted or resumed since
+        the last dispatch restarts from its given tokens, every other
+        row of ``rows`` goes on from the device's own block; the others
+        run idle at cursor 0 over the scratch table with no position
+        inside a request, an active slot whose last commit is in flight
+        among them. Returns the feed and, per slot, the given positions
+        and the positions inside the request of the block it runs."""
+        b = self._block_len
+        take = np.zeros(self.slots, bool)
+        take[rows] = True
+        given = np.zeros((self.slots, b), np.int32)
+        count = np.full(self.slots, -1, np.int32)
+        end = np.zeros(self.slots, np.int32)
+        for s in rows:
+            handle = self._slot_req[s]
+            end[s] = len(handle.prompt) + handle.max_new_tokens
+            if self._blk_over[s] is not None:
+                count[s] = len(self._blk_over[s])
+                given[s, :count[s]] = self._blk_over[s]
+                self._blk_over[s] = None
+        cursors = np.where(take, self._idx, 0)
+        live = np.clip(end - cursors, 0, b)
+        self._count_attn_grid(cursors, b)
+        feed = self._generation.pack_block_feed(
+            given, count, cursors, live,
+            np.where(take[:, None], self._tables, 0))
+        return feed, np.where(take, self._blk_given, 0), live
 
-    def _block_advance(self, active, answers):
-        """What the pass just run (``answers``: the jitted block step's
-        one array) means for each active slot. Where
-        masks were left, unmask by the rule (generation.unmask) and
-        stay; where none was, that pass was the COMMIT: its K/V is
-        final, so move the cursor on, deliver the block in one
-        delivery and start the next. Returns the tokens delivered.
-        The denoising rows are advanced all at once (array operations
-        over ``[active, block_len]``): this runs between every two
-        passes with the device waiting, and only a commit needs a
-        row's turn of its own."""
-        model = self._model
-        best, conf, expert_ids = self._generation.unpack_block_step(
-            answers, self.slots, self._block_len, model.experts_per_tok)
-        quota = max(1, self._block_len // model.denoise_steps)
-        act = np.asarray(active)
-        self.counters.inc("row_passes", len(act))
-        # positions inside their request; those past its end stay
-        # masked for good and are never unmasked
-        inside = np.arange(self._block_len) < self._blk_live[act, None]
-        of_requests = np.zeros(self._blk_masked.shape, bool)
-        of_requests[act] = inside
-        self._count_expert_rows(expert_ids, of_requests.reshape(-1))
-        masked = self._blk_masked[act] & inside
-        denoising = masked.any(axis=1)
-        # a row in its commit has no mask left: nothing is chosen there
-        chosen = self._generation.unmask(
-            conf[act], masked, quota, model.confidence_threshold)
-        self._blk_tok[act] = np.where(chosen, best[act], self._blk_tok[act])
-        self._blk_when[act] = np.where(chosen, self._blk_pass[act, None],
-                                       self._blk_when[act])
-        self._blk_masked[act] &= ~chosen
-        self._blk_pass[act] += denoising
-        self.counters.inc("tokens_unmasked", int(chosen.sum()))
+    def _next_block(self, slot):
+        """Move the slot's cursor on to its next block, which the
+        device starts with nothing given."""
+        self._idx[slot] += self._block_len
+        self._blk_given[slot] = 0
+        self._blk_due[slot] = False
+
+    def _block_read(self, older, answers):
+        """What the pass ``older`` (its flight) answered: the counts of
+        the rows that belonged to a request at the dispatch, each
+        commit's block delivered in one delivery, and for a row left
+        with no mask that its next pass is the commit, so its cursor
+        moves on for the pass after that one (now, if that pass is in
+        flight; else at its dispatch). A row whose request was
+        cancelled or evicted since the dispatch is dropped."""
+        owners, given, live, prefills = older[1], older[3], older[4], older[5]
+        owned = np.array([h is not None for h in owners])
+        commit = answers["commit"] & owned
+        self.counters.inc("row_passes", int(owned.sum()))
+        self.counters.inc("commit_row_passes", int(commit.sum()))
+        self.counters.inc("tokens_unmasked",
+                          int(answers["unmasked"][owned].sum()))
+        # positions inside a request: an idle slot's rows (live 0) and
+        # positions past a request's end are counted nowhere
+        inside = np.arange(self._block_len)[None, :] < live[:, None]
+        self._count_expert_rows(answers["expert_ids"], inside.reshape(-1))
+        for routed, rows in prefills:
+            # dispatched before this pass: read without a wait
+            self._count_expert_rows(np.asarray(routed), rows)
         delivered = 0
-        commits = act[~denoising].tolist()
-        for s in commits:
-            with self.timers.timed("block_commit"):
-                handle = self._slot_req[s]
-                given, live = int(self._blk_given[s]), int(self._blk_live[s])
-                tokens = self._blk_tok[s, given:live].tolist()
-                self._idx[s] += self._block_len
-                self._deliver(
-                    s, tokens[-1],
-                    block=(tokens, self._blk_when[s, given:live].tolist()))
-                if self._slot_req[s] is handle:
-                    self._fresh_block(s, handle)
-                delivered += len(tokens)
-        self.counters.inc("commit_row_passes", len(commits))
+        for s, handle in enumerate(owners):
+            if handle is None:
+                continue
+            if self._slot_req[s] is not handle:
+                # cancelled or evicted since the dispatch: the row's
+                # pass is nobody's
+                self.counters.inc("block_rows_dropped_in_flight")
+            elif commit[s]:
+                with self.timers.timed("block_commit"):
+                    a, z = int(given[s]), int(live[s])
+                    tokens = answers["tokens"][s, a:z].tolist()
+                    self._deliver(s, tokens[-1], block=(
+                        tokens, answers["when"][s, a:z].tolist()))
+                    delivered += len(tokens)
+            elif not answers["left"][s]:
+                if self._in_flight(s):
+                    self._next_block(s)
+                else:
+                    self._blk_due[s] = True
+        self.counters.inc("tokens", delivered)
+        self.counters.inc("decode_tokens", delivered)
         self._tokens_round_ewma = self._ewma(
-            self._tokens_round_ewma, delivered / len(active))
-        return delivered
+            self._tokens_round_ewma, delivered / max(1, int(owned.sum())))
+        # occupancy AFTER deliveries: see _loop
+        self.counters.gauge("slot_occupancy", len(self._active_slots()))
 
     def _count_expert_rows(self, expert_ids, live):
         """The experts' load in one call, from the program's own output
@@ -3031,8 +3083,11 @@ class DecodeEngine(object):
                 self._cache, routed = self._prefill_fn(
                     self.params, self._cache, jnp.asarray(row),
                     jnp.asarray(toks), jnp.int32(start))
-                self._count_expert_rows(np.asarray(routed),
-                                        np.arange(bucket) < len(tail))
+                # counted at the read of the pass after it: an
+                # admission does not wait for its prefill
+                routed.copy_to_host_async()
+                self._routed_due.append(
+                    (routed, np.arange(bucket) < len(tail)))
         t1 = time.monotonic()
         self._prefill_ewma = self._ewma(self._prefill_ewma, t1 - t0)
         self.flight.span("prefill", t0, t1, trace=handle.trace,
@@ -3082,7 +3137,11 @@ class DecodeEngine(object):
         self._publish_kv_gauges()
         self._idx[slot] = fill
         if self._block_len:
-            self._fresh_block(slot, handle)
+            # the tokens left over start the slot's block: the device
+            # restarts the row from them at the next dispatch
+            self._blk_over[slot] = full[fill:]
+            self._blk_given[slot] = n - fill
+            self._blk_due[slot] = False
             return
         self._last[slot] = first
         self._deliver(slot, first)

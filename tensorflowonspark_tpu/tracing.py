@@ -316,12 +316,17 @@ METRIC_FAMILIES = {
         ("counter", "", "decode steps dispatched while the step before "
                         "was still unread (over "
                         "tfos_serving_decode_steps: how often a token "
-                        "engine keeps a step in flight; 0 where the "
-                        "host decides the next input)"),
+                        "or block-stepping engine keeps a step in "
+                        "flight; 0 where the host decides the next "
+                        "input, a speculative round)"),
     "tfos_serving_tokens_dropped_in_flight":
         ("counter", "", "tokens of a step in flight that nobody got: "
                         "the request ended on EOS, was cancelled or "
                         "evicted after the dispatch"),
+    "tfos_serving_block_rows_dropped_in_flight":
+        ("counter", "", "rows of a block pass in flight that nobody "
+                        "got: the request was cancelled or evicted "
+                        "after the dispatch (block-stepping engines)"),
     "tfos_serving_decode_call_buffers":
         ("gauge", "", "buffers one call of the engine's step program "
                       "hands the runtime plus buffers it takes back, "

@@ -229,14 +229,19 @@ def test_speculative_round_takes_both_models_pools(lm):
         assert eng.submit([5, 3, 9], 9).result(300) == got
 
 
-def test_block_step_call_takes_pools_and_one_feed():
+def test_block_step_call_takes_pools_blocks_and_one_feed():
+    """The parameters, 2 pools a layer, the 4 leaves of the slots'
+    blocks (generation.init_block_state) and the feed in; the pools,
+    the blocks and the answer out: 2 x 3 + 4 + 1 + 2 x 3 + 4 + 1 = 22
+    beside the parameters."""
     model = _sdar(layers=3)
     params = _params(model)
     with serving.DecodeEngine(model.clone(decode=True), params, slots=2,
                               total_len=32, kv_block_size=8) as eng:
         leaves = len(jax.tree.leaves(params))
+        assert len(jax.tree.leaves(eng._blk_state)) == 4
         assert eng.compile_stats()["decode_call_buffers"] \
-            == leaves + 2 * 3 + 1 + 2 * 3 + 1
+            == leaves + 2 * 3 + 4 + 1 + 2 * 3 + 4 + 1 == leaves + 22
 
 
 def test_two_kinds_of_cache_cross_as_their_pools():

@@ -17,6 +17,7 @@ and more).
 """
 
 import importlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -131,18 +132,32 @@ def test_passes_through_the_paged_cache_match_the_reference(prompt_len,
             want = ref.logits(params, tokens, TINY,
                               rows=range(block * b, block * b + b))
             assert np.abs(got[slot] - np.asarray(want)).max() < F32_TOL
-    # and the jitted step answers the argmax and its probability
-    feed = np.zeros((2, b), np.int32)
-    cache, answers = step(
-        cast, cache, generation.pack_block_feed(feed, idx, tables))
-    best, conf, ids = generation.unpack_block_step(
-        answers, 2, b, TINY["experts_per_tok"])
+    # and the jitted step unmasks in the program: the slot restarted as
+    # a fresh block (nothing given: all MASK), the other row idle
+    count, live = np.array([-1, 0], np.int32), np.array([0, b], np.int32)
+    cache, state, answer = step(
+        cast, cache, generation.init_block_state(2, b),
+        generation.pack_block_feed(np.zeros((2, b), np.int32), count, idx,
+                                   live, tables))
+    got = generation.unpack_block_step(answer, 2, b, TINY["experts_per_tok"])
+    feed = np.full((2, b), TINY["mask_token_id"], np.int32)
     _, logits = _pass_logits(paged, cast, cache, feed, idx, tables)
-    assert np.array_equal(np.asarray(best), logits.argmax(-1))
-    np.testing.assert_allclose(
-        np.asarray(conf), jax.nn.softmax(logits, -1).max(-1), rtol=1e-5)
+    # one position a pass (nothing near 0.9 on random weights): the
+    # most confident, its best token, at pass 0
+    first = int(jax.nn.softmax(logits[slot], -1).max(-1).argmax())
+    assert got["tokens"][slot].tolist() == [
+        int(logits[slot, first].argmax()) if i == first else 0
+        for i in range(b)]
+    assert got["when"][slot].tolist() == [0 if i == first else -1
+                                          for i in range(b)]
+    assert (got["commit"].tolist(), got["unmasked"].tolist(),
+            got["left"].tolist()) == ([True, False], [0, 1], [0, b - 1])
+    # the state the next pass goes on from: the idle row a fresh block
+    assert np.asarray(state["masked"]).tolist() == [
+        [True] * b, [i != first for i in range(b)]]
+    assert np.asarray(state["passes"]).tolist() == [0, 1]
     # the experts each of the 2 x b positions was routed to, a layer
-    ids = np.asarray(ids)
+    ids = got["expert_ids"]
     assert ids.shape == (TINY["num_layers"], 2 * b,
                          TINY["experts_per_tok"])
     assert ids.min() >= 0 and ids.max() < TINY["num_experts"]
@@ -316,6 +331,276 @@ def test_unmask_rule_quota_threshold_and_ties():
     for quota, tau in [(1, 0.9), (1, 0.96), (2, 1.0), (4, 1.0)]:
         assert ref.unmask_rule(np.log(conf), masked, quota, tau).tolist() \
             == generation.unmask(conf, masked, quota, tau).tolist()
+
+
+def _rule_case(case, rows=96, b=4, seed=0):
+    """Confidences ``[rows, b]`` float32, which positions are masked and
+    how many of each row's block lie inside its request."""
+    rng = np.random.RandomState(seed)
+    conf = rng.rand(rows, b).astype(np.float32)
+    masked = rng.rand(rows, b) < 0.7
+    live = np.full(rows, b)
+    if case == "random":
+        # and on either side of each threshold, a float32 ulp away
+        edges = np.float32([0.9, 0.96, 1.0])
+        near = np.concatenate([edges, np.nextafter(edges, np.float32(0)),
+                               np.nextafter(edges, np.float32(2))])
+        pick = rng.rand(rows, b) < 0.3
+        conf = np.where(pick, rng.choice(near, size=(rows, b)), conf)
+    elif case == "ties":
+        conf = rng.choice(np.float32([0.25, 0.5, 0.93, 0.97]), size=(rows, b))
+        conf[::3] = np.float32(0.5)
+    elif case == "phases":
+        # a row with 4, 3, 2, 1 masks and none (its commit), each often
+        left = np.arange(rows) % (b + 1)
+        order = np.argsort(rng.rand(rows, b), axis=1)
+        masked = order < left[:, None]
+    elif case == "past_end":
+        live = rng.randint(0, b + 1, size=rows)
+    return conf.astype(np.float32), masked, live
+
+
+def _host_pass(state, best, conf, live, quota, threshold):
+    """What a pass does to each row's block, stated in numpy with
+    :func:`generation.unmask`: the positions chosen, the block's tokens
+    and ``when``, the masks left, the pass count, the denoising rows."""
+    b = conf.shape[1]
+    inside = np.arange(b)[None, :] < live[:, None]
+    masked = state["masked"] & inside
+    denoising = masked.any(axis=1)
+    chosen = generation.unmask(conf, masked, quota, threshold)
+    tokens = np.where(chosen, best, state["tokens"])
+    when = np.where(chosen, state["passes"][:, None], state["when"])
+    left = state["masked"] & ~chosen
+    return tokens, when, left, state["passes"] + denoising, chosen, \
+        denoising
+
+
+@pytest.mark.parametrize("threshold", [0.9, 0.96, 1.0])
+@pytest.mark.parametrize("quota", [1, 2, 4])
+@pytest.mark.parametrize("case", ["random", "ties", "phases", "past_end"])
+def test_the_programs_rule_is_unmask_to_the_bit(case, quota, threshold):
+    """The step program's rule (``device_unmask``) picks what
+    ``generation.unmask`` picks, on the same float32 confidences, and a
+    pass leaves each row's block as the host's turn left it: a commit
+    (no mask inside) answers the block whole and restarts it fresh;
+    positions past a request's end are never unmasked."""
+    conf, masked, live = _rule_case(case)
+    rows, b = conf.shape
+    inside = np.arange(b)[None, :] < live[:, None]
+    want = generation.unmask(conf, masked & inside, quota, threshold)
+    got = jax.jit(generation.device_unmask, static_argnums=(2, 3))(
+        conf, masked & inside, quota, threshold)
+    assert np.asarray(got).tolist() == want.tolist()
+    rng = np.random.RandomState(1)
+    state = {"tokens": rng.randint(0, 96, size=(rows, b)).astype(np.int32),
+             "masked": masked,
+             "when": np.where(masked, -1, rng.randint(0, 3, size=(rows, b))
+                              ).astype(np.int32),
+             "passes": rng.randint(0, 4, size=rows).astype(np.int32)}
+    best = rng.randint(0, 96, size=(rows, b)).astype(np.int32)
+    model = _model(denoise_steps=b // quota, confidence_threshold=threshold)
+    new, out = jax.jit(lambda *a: generation._advance_blocks(model, *a))(
+        state, best, conf, live.astype(np.int32))
+    tokens, when, left, passes, chosen, denoising = _host_pass(
+        state, best, conf, live, quota, threshold)
+    commit = ~denoising
+    out = np.asarray(out)
+    assert out[:, :b].tolist() == tokens.tolist()
+    assert out[:, b:2 * b].tolist() == when.tolist()
+    assert out[:, 2 * b].tolist() == commit.astype(int).tolist()
+    assert out[:, 2 * b + 1].tolist() == chosen.sum(1).tolist()
+    assert out[:, 2 * b + 2].tolist() == (left & inside).sum(1).tolist()
+    assert not (chosen & ~inside).any()
+    fresh = commit[:, None]
+    assert np.asarray(new["masked"]).tolist() == (fresh | left).tolist()
+    assert np.asarray(new["tokens"]).tolist() == \
+        np.where(fresh, 0, tokens).tolist()
+    assert np.asarray(new["when"]).tolist() == \
+        np.where(fresh, -1, when).tolist()
+    assert np.asarray(new["passes"]).tolist() == \
+        np.where(commit, 0, passes).tolist()
+
+
+def _blocks_delivered(handles):
+    return sum(len(h.deliveries) for h in handles)
+
+
+def _first_block(handle, timeout=120.0):
+    """Wait for the request's first delivery (an abandoned ``stream()``
+    would cancel it)."""
+    deadline = time.monotonic() + timeout
+    while not handle.generated:
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+
+
+def test_an_admission_while_a_pass_is_in_flight_restarts_its_row():
+    """Requests admitted while another's pass is in flight: each new
+    row is restarted on the device from its given tokens (the feed's
+    override) and the running row goes on from the device's block;
+    every request equals itself alone."""
+    _, cast = _weights(4)
+    model = _model()
+    reqs = _requests()
+    reqs = [(reqs[0][0], 40)] + reqs[1:3]
+    alone = [sdar_moe.generate(model, cast, p, n) for p, n in reqs]
+    with _engine(model, cast) as eng:
+        admit, in_flight = eng._admit, []
+
+        def admitted(slot, handle):
+            in_flight.append(eng._flight is not None)
+            admit(slot, handle)
+
+        eng._admit = admitted
+        first = eng.submit(*reqs[0])
+        _first_block(first)
+        handles = [first] + [eng.submit(p, n) for p, n in reqs[1:]]
+        got = [h.result(timeout=120) for h in handles]
+        counts = eng.counters.snapshot()["counts"]
+    assert in_flight == [False, True, True]
+    assert got == [seq for seq, _ in alone]
+    assert [h.unmask_passes for h in handles] == [p for _, p in alone]
+    assert counts["commit_row_passes"] == _blocks_delivered(handles)
+    assert counts["block_rows_dropped_in_flight"] == 0
+
+
+def test_a_cancel_mid_block_drops_its_row_in_flight():
+    """A request cancelled after its first block: its slot is freed at
+    the next turn's top while its next pass is in flight, and that
+    pass's row is read and dropped, counted once; the other request
+    equals itself alone and the cancelled one served a prefix of its
+    own."""
+    _, cast = _weights(4)
+    model = _model()
+    reqs = _requests()
+    reqs = [(reqs[0][0], 40), reqs[1]]
+    alone = [sdar_moe.generate(model, cast, p, n) for p, n in reqs]
+    with _engine(model, cast) as eng:
+        gone, kept = [eng.submit(p, n) for p, n in reqs]
+        _first_block(gone)
+        gone.cancel()
+        assert kept.result(timeout=120) == alone[1][0]
+        with pytest.raises(Exception):
+            gone.result(timeout=120)
+        counts = eng.counters.snapshot()["counts"]
+    assert counts["block_rows_dropped_in_flight"] == 1
+    served = gone.generated
+    assert 0 < len(served) < 40
+    assert reqs[0][0] + served == alone[0][0][:len(reqs[0][0]) + len(served)]
+    assert kept.unmask_passes == alone[1][1]
+
+
+@pytest.mark.parametrize("prompt_len,max_new", [(9, 11), (8, 8), (3, 5),
+                                                (12, 1)])
+def test_no_pass_is_dispatched_after_a_requests_last_commit(prompt_len,
+                                                            max_new):
+    """Alone in the engine, a request runs every pass of every block and
+    nothing more: its last commit in flight, it owes no pass (finish by
+    length is known before the read). Every pass but the first is
+    dispatched with the one before it unread."""
+    _, cast = _weights(4)
+    model = _model()
+    prompt = np.random.RandomState(prompt_len).randint(
+        0, 96, size=prompt_len).tolist()
+    seq, passes = sdar_moe.generate(model, cast, prompt, max_new)
+    with _engine(model, cast) as eng:
+        handle = eng.submit(prompt, max_new)
+        assert handle.result(timeout=120) == seq
+        counts = eng.counters.snapshot()["counts"]
+    want, at = 0, 0
+    for n in handle.deliveries:
+        want, at = want + max(passes[at:at + n]) + 2, at + n
+    assert counts["decode_steps"] == counts["row_passes"] == want
+    assert counts["steps_dispatched_ahead"] == want - 1
+    assert counts["commit_row_passes"] == len(handle.deliveries)
+
+
+def test_a_preemption_reads_the_pass_in_flight_first():
+    """Under exhaustion the victim's slot is freed only after the pass
+    in flight has been read and delivered (``_retire``): the re-prefill
+    takes the blocks delivered whole, the rows that go on continue from
+    the device's blocks, and every request equals itself alone."""
+    _, cast = _weights(4)
+    model = _model()
+    rng = np.random.RandomState(0)
+    reqs = [(rng.randint(0, 96, size=p).tolist(), n)
+            for p, n in [(9, 22), (12, 19), (5, 26)]]
+    alone = [sdar_moe.generate(model, cast, p, n) for p, n in reqs]
+    with _engine(model, cast, kv_blocks=8) as eng:
+        preempt, before, after = eng._preempt, [], []
+
+        def preempted(slot):
+            before.append(eng._flight is not None)
+            preempt(slot)
+            after.append(eng._flight is None)
+
+        eng._preempt = preempted
+        handles = [eng.submit(p, n) for p, n in reqs]
+        got = [h.result(timeout=120) for h in handles]
+        counts = eng.counters.snapshot()["counts"]
+    assert counts["preemptions"] >= 1 and any(before) and all(after)
+    assert got == [seq for seq, _ in alone]
+    assert [h.unmask_passes for h in handles] == [p for _, p in alone]
+    assert counts["steps_dispatched_ahead"] > 0.5 * counts["decode_steps"]
+
+
+def _step_with(model, fault):
+    """The engine's block step (generation.paged_block_fns) with a fault
+    planted in the program: ``stale_commit`` feeds a commit what the
+    last denoising pass fed (the position it unmasked still MASK), so
+    the block's K/V stay as that pass wrote them; ``turned_rule`` turns
+    the rule round (the least confident masked position goes first)."""
+    b = model.block_len
+
+    def paged_block_step(params, cache, state, feed):
+        state = generation._restart_blocks(state, feed[:, :b], feed[:, b])
+        tokens = jnp.where(state["masked"], model.mask_token_id,
+                           state["tokens"])
+        if fault == "stale_commit":
+            commit = ~state["masked"].any(axis=1)
+            last = state["when"] == (state["passes"] - 1)[:, None]
+            tokens = jnp.where(commit[:, None] & last, model.mask_token_id,
+                               tokens)
+        cache, (best, conf, routed) = generation._paged_block_step(
+            model, params, cache, tokens, feed[:, b + 1], feed[:, b + 3:])
+        rule = model
+        if fault == "turned_rule":
+            rule, conf = model.clone(confidence_threshold=2.0), 1.0 - conf
+        state, rows = generation._advance_blocks(rule, state, best, conf,
+                                                 feed[:, b + 2])
+        return cache, state, jnp.concatenate(
+            [rows.ravel(), routed.astype(jnp.int32).ravel()])
+
+    return jax.jit(paged_block_step, donate_argnums=(1, 2))
+
+
+@pytest.mark.parametrize("fault,failed", [
+    ("stale_commit", {"served_gap_max", "order_gap_mean"}),
+    ("turned_rule", {"order_gap_mean"})])
+def test_a_fault_planted_in_the_step_program_is_not_correct(fault, failed):
+    """The benchmark's check at the cell's tiny size (its runner and
+    limits) catches a fault where the rule and the blocks now live, in
+    the step program: a commit pass that runs a stale block shows in the
+    served tokens or their order, the rule turned round in the order
+    alone (every token is still the model's best at its position)."""
+    import shutil
+
+    from benchmarks.runners import serve_blockdiff
+    from benchmarks.tests.test_sdar_moe import tiny_ctx
+
+    def tamper(engine):
+        engine._decode_fn = _step_with(engine._model, fault)
+
+    ctx = tiny_ctx()
+    try:
+        result = serve_blockdiff.run(ctx, tamper=tamper)
+    finally:
+        shutil.rmtree(ctx["work_dir"], ignore_errors=True)
+    assert not result["correct"]
+    over = {k for k, (v, limit) in result["checks"].items()
+            if v is None or not v <= limit}
+    assert over and over <= failed, result["checks"]
 
 
 @pytest.mark.parametrize("kw,why", [

@@ -1089,6 +1089,9 @@ class DecodeEngine(object):
             self._draft_cache = generation.init_pools(self._draft_model)
         self._buffers_counted = False  # the loop's: _admit, once
         self._publish_kv_gauges()
+        # the process's collections stall this loop too: stages gc and
+        # gc_full of the engine's timers until stop()
+        tracing.watch_gc(self.timers)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="tfos-decode-engine")
         self._thread.start()
@@ -1749,6 +1752,7 @@ class DecodeEngine(object):
             self._stopping = True
             self._cv.notify_all()
         self._thread.join(timeout=30)
+        tracing.unwatch_gc(self.timers)
         if self._thread.is_alive():
             logger.warning(
                 "decode engine scheduler still inside a device call "
@@ -2049,134 +2053,138 @@ class DecodeEngine(object):
         steps = 0
         try:
             while True:
-                if not self._active_slots():
-                    # every request of the step in flight is gone
-                    # (cancelled, evicted): read it off before parking
-                    self._retire()
-                with self._cv:
-                    if self._idle_locked():
-                        # idle for want of work: its own stage, so
-                        # nobody reads a quiet engine as scheduler cost
-                        with self.timers.timed("park"):
-                            while self._idle_locked():
-                                self._cv.wait()
-                    if self._stopping:
-                        # a last token in flight completes its request
+                # one pass: a span of its own, so that what the pass
+                # does between its stages (the lock, the queue's
+                # pruning, gauges, the step's bookkeeping) is named
+                with self.timers.timed("loop"):
+                    if not self._active_slots():
+                        # every request of the step in flight is gone
+                        # (cancelled, evicted): read it off before parking
                         self._retire()
-                        self._fail_outstanding(
-                            RuntimeError("engine stopped"))
-                        return
-                    # KV ship/splice jobs drain under the lock, run
-                    # outside it (export gathers device rows to host,
-                    # import scatters — both too slow for _cv). Taking
-                    # them on the scheduler thread is the whole safety
-                    # story: no admission or decode step interleaves
-                    # with pool surgery.
-                    kv_jobs = list(self._kv_jobs)
-                    self._kv_jobs.clear()
-                    self._prune_queue_locked(time.monotonic())
-                    # QoS admission (PR 18): weighted-fair pick order
-                    # replaces the FIFO head scan; the stage timer
-                    # proves the scheduler stays off the hot path
-                    with self.timers.timed("qos_plan"):
-                        admits, victims = self._plan_admission_locked()
-                    self.counters.gauge("queue_depth", len(self._queue))
-                # class preemption OUTSIDE the lock (_preempt
-                # re-acquires _cv to requeue its victim — _cv is
-                # non-reentrant): the slot and blocks it frees admit
-                # the waiting stronger-class head on the very next
-                # scan — one decode step of latency, the same boundary
-                # every other scheduling decision lands on
-                for s in victims:
-                    if self._slot_req[s] is not None:
-                        with self.timers.timed("preempt"):
-                            self._preempt(s)
-                if kv_jobs:
-                    self._retire()
-                for job in kv_jobs:
-                    with self.timers.timed("kv_job"):
-                        self._run_kv_job(job)
-                # prefill OUTSIDE the lock: submit() must never block on
-                # device work
-                for s, handle in admits:
-                    with self.timers.timed("admit"):
-                        self._admit(s, handle)
-                # step-boundary eviction: cancelled / past-deadline
-                # requests free their slots BEFORE the step computes
-                # for them, so the next admission scan can reuse them
-                with self.timers.timed("evict"):
-                    self._evict_expired(time.monotonic())
-                if self._other_kinds:
-                    # what a window has left goes back to its pool in
-                    # this very turn, a stage of its own, BEFORE the
-                    # turn's growth: where a block is left and the next
-                    # begun in one turn (window = 1 mod block size) a
-                    # slot never holds more than its window's blocks,
-                    # which is all its pool has for it
-                    with self.timers.timed("trim_blocks"):
-                        self._trim_active_blocks()
-                # lazy block growth (and, under exhaustion,
-                # youngest-first preemption) for every slot whose
-                # NEXT write crosses a block boundary
-                with self.timers.timed("grow_blocks"):
-                    self._grow_active_blocks()
-                active = self._active_slots()
-                self.counters.gauge("slot_occupancy", len(active))
-                if not active:
-                    continue
-                # serving chaos sites: stall_decode_for / a scheduler
-                # kill lands here, between steps — the same boundary
-                # every other scheduling decision uses (replica_id
-                # scopes an only=<replica> injection to THIS engine of
-                # an in-process fleet)
-                chaos.on_decode_step(steps, self.replica_id)
-                if not self._spec_k:
-                    # the next input is the device's own output (a token
-                    # step's pick; a block pass's blocks, unmasked in
-                    # the program), so one step stays in flight while
-                    # the host reads and schedules; where it is a
-                    # decision the host makes from the answers (a
-                    # speculative round's acceptance, below) the turn
-                    # reads before it schedules
-                    rows = [s for s in active if self._owes_step(s)]
-                    if self._block_len:
-                        self._block_turn(rows, steps)
-                    else:
-                        self._token_turn(rows, steps)
-                    steps += bool(rows)
-                    continue
-                t0 = time.monotonic()
-                drafts, targets = self._spec_round(jnp)
-                t1 = time.monotonic()
-                self._step_ewma = self._ewma(self._step_ewma, t1 - t0)
-                self._hist_step.observe(t1 - t0)
-                # engine-row span (tid 0): the step every request's
-                # tokens in this round came from
-                self.flight.span("decode_step", t0, t1,
-                                 active=len(active), step=steps)
-                steps += 1
-                self.counters.inc("decode_steps")
-                # blocks held by in-flight sequences, summed over
-                # the steps: kv_block_steps / decode_steps is the
-                # mean occupancy of the pool the steps saw
-                self.counters.inc(
-                    "kv_block_steps",
-                    self._pool.num_blocks - self._pool.allocatable())
-                with self.timers.timed("host_schedule"):
-                    delivered = self._spec_deliver(active, drafts, targets)
-                    self.counters.inc("tokens", delivered)
-                    # decode_tokens excludes prefill-emitted firsts, so
-                    # rate("decode_tokens", "decode_steps") is true
-                    # decode occupancy (bounded by slots; under
-                    # speculation, tokens per ROUND — the acceptance
-                    # win read straight off the counters)
-                    self.counters.inc("decode_tokens", delivered)
-                    # re-publish occupancy AFTER deliveries: when the
-                    # last slot frees on a completion the loop parks in
-                    # cv.wait, and a gauge frozen at the pre-step value
-                    # would read "occupied" on an idle engine forever
-                    self.counters.gauge("slot_occupancy",
-                                        len(self._active_slots()))
+                    with self._cv:
+                        if self._idle_locked():
+                            # idle for want of work: its own stage, so
+                            # nobody reads a quiet engine as scheduler cost
+                            with self.timers.timed("park"):
+                                while self._idle_locked():
+                                    self._cv.wait()
+                        if self._stopping:
+                            # a last token in flight completes its request
+                            self._retire()
+                            self._fail_outstanding(
+                                RuntimeError("engine stopped"))
+                            return
+                        # KV ship/splice jobs drain under the lock, run
+                        # outside it (export gathers device rows to host,
+                        # import scatters — both too slow for _cv). Taking
+                        # them on the scheduler thread is the whole safety
+                        # story: no admission or decode step interleaves
+                        # with pool surgery.
+                        kv_jobs = list(self._kv_jobs)
+                        self._kv_jobs.clear()
+                        self._prune_queue_locked(time.monotonic())
+                        # QoS admission: weighted-fair pick order
+                        # replaces the FIFO head scan; the stage timer
+                        # proves the scheduler stays off the hot path
+                        with self.timers.timed("qos_plan"):
+                            admits, victims = self._plan_admission_locked()
+                        self.counters.gauge("queue_depth", len(self._queue))
+                    # class preemption OUTSIDE the lock (_preempt
+                    # re-acquires _cv to requeue its victim — _cv is
+                    # non-reentrant): the slot and blocks it frees admit
+                    # the waiting stronger-class head on the very next
+                    # scan — one decode step of latency, the same boundary
+                    # every other scheduling decision lands on
+                    for s in victims:
+                        if self._slot_req[s] is not None:
+                            with self.timers.timed("preempt"):
+                                self._preempt(s)
+                    if kv_jobs:
+                        self._retire()
+                    for job in kv_jobs:
+                        with self.timers.timed("kv_job"):
+                            self._run_kv_job(job)
+                    # prefill OUTSIDE the lock: submit() must never block on
+                    # device work
+                    for s, handle in admits:
+                        with self.timers.timed("admit"):
+                            self._admit(s, handle)
+                    # step-boundary eviction: cancelled / past-deadline
+                    # requests free their slots BEFORE the step computes
+                    # for them, so the next admission scan can reuse them
+                    with self.timers.timed("evict"):
+                        self._evict_expired(time.monotonic())
+                    if self._other_kinds:
+                        # what a window has left goes back to its pool in
+                        # this very turn, a stage of its own, BEFORE the
+                        # turn's growth: where a block is left and the next
+                        # begun in one turn (window = 1 mod block size) a
+                        # slot never holds more than its window's blocks,
+                        # which is all its pool has for it
+                        with self.timers.timed("trim_blocks"):
+                            self._trim_active_blocks()
+                    # lazy block growth (and, under exhaustion,
+                    # youngest-first preemption) for every slot whose
+                    # NEXT write crosses a block boundary
+                    with self.timers.timed("grow_blocks"):
+                        self._grow_active_blocks()
+                    active = self._active_slots()
+                    self.counters.gauge("slot_occupancy", len(active))
+                    if not active:
+                        continue
+                    # serving chaos sites: stall_decode_for / a scheduler
+                    # kill lands here, between steps — the same boundary
+                    # every other scheduling decision uses (replica_id
+                    # scopes an only=<replica> injection to THIS engine of
+                    # an in-process fleet)
+                    chaos.on_decode_step(steps, self.replica_id)
+                    if not self._spec_k:
+                        # the next input is the device's own output (a token
+                        # step's pick; a block pass's blocks, unmasked in
+                        # the program), so one step stays in flight while
+                        # the host reads and schedules; where it is a
+                        # decision the host makes from the answers (a
+                        # speculative round's acceptance, below) the turn
+                        # reads before it schedules
+                        rows = [s for s in active if self._owes_step(s)]
+                        if self._block_len:
+                            self._block_turn(rows, steps)
+                        else:
+                            self._token_turn(rows, steps)
+                        steps += bool(rows)
+                        continue
+                    t0 = time.monotonic()
+                    drafts, targets = self._spec_round(jnp)
+                    t1 = time.monotonic()
+                    self._step_ewma = self._ewma(self._step_ewma, t1 - t0)
+                    self._hist_step.observe(t1 - t0)
+                    # engine-row span (tid 0): the step every request's
+                    # tokens in this round came from
+                    self.flight.span("decode_step", t0, t1,
+                                     active=len(active), step=steps)
+                    steps += 1
+                    self.counters.inc("decode_steps")
+                    # blocks held by in-flight sequences, summed over
+                    # the steps: kv_block_steps / decode_steps is the
+                    # mean occupancy of the pool the steps saw
+                    self.counters.inc(
+                        "kv_block_steps",
+                        self._pool.num_blocks - self._pool.allocatable())
+                    with self.timers.timed("host_schedule"):
+                        delivered = self._spec_deliver(active, drafts, targets)
+                        self.counters.inc("tokens", delivered)
+                        # decode_tokens excludes prefill-emitted firsts, so
+                        # rate("decode_tokens", "decode_steps") is true
+                        # decode occupancy (bounded by slots; under
+                        # speculation, tokens per ROUND — the acceptance
+                        # win read straight off the counters)
+                        self.counters.inc("decode_tokens", delivered)
+                        # re-publish occupancy AFTER deliveries: when the
+                        # last slot frees on a completion the loop parks in
+                        # cv.wait, and a gauge frozen at the pre-step value
+                        # would read "occupied" on an idle engine forever
+                        self.counters.gauge("slot_occupancy",
+                                            len(self._active_slots()))
         except BaseException as e:  # noqa: BLE001 - fail every client
             logger.exception("decode engine loop died")
             self._flight = None  # a dead loop's answers are not read

@@ -26,6 +26,10 @@ code; its own plumbing is unobservable. Here the framework exposes:
   ``<plane>:<stage>`` in a captured profiler trace, on the device
   trace's clock, so a device idle gap is named by the stage the host
   was in.
+- :func:`watch_gc` — the process's garbage collections as stages of
+  the timers that subscribe (``gc``, ``gc_full``) and as host spans
+  ``runtime:gc``: a collection stops every thread, so the idle gap it
+  leaves on the device is named by it.
 - :class:`Counters` — named monotonic counters + gauges for scheduler
   loops: serving.DecodeEngine exports queue depth, slot occupancy,
   tokens-per-step, and the request-lifecycle tallies (``shed`` /
@@ -68,6 +72,7 @@ The unified observability plane (PR 5) lives here too:
 """
 
 import collections
+import gc
 import itertools
 import logging
 import math
@@ -109,7 +114,10 @@ class StageTimers(object):
     ``decode_step`` holds ``step_upload`` + ``step_dispatch`` +
     ``step_sync``; its ``admit`` holds ``prefix_lookup``,
     ``block_alloc`` and ``prefill``). Sum siblings, never a parent
-    with its children.
+    with its children. Timers subscribed by :func:`watch_gc` also
+    get the process's collections as ``gc`` and ``gc_full``, added
+    from whichever thread collects: those overlap every other stage
+    and are never summed with one.
     """
 
     __slots__ = ("_t", "_n", "_plane")
@@ -148,8 +156,9 @@ class StageTimers(object):
     def per_ms(self):
         """{stage: mean milliseconds per sample} — the human-readable
         breakdown bench.py and profile_fed.py print."""
+        # over a copy: a collection may add its stage mid-iteration
         return {k: round(v * 1000.0 / max(self._n.get(k, 1), 1), 3)
-                for k, v in self._t.items()}
+                for k, v in self.snapshot().items()}
 
 
 class Counters(object):
@@ -1598,6 +1607,91 @@ class _StageSpan(object):
         self._timers.add(self._stage, time.monotonic() - self._t0)
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
+
+
+# -- the process's garbage collections -----------------------------------
+#
+# A collection runs on whichever thread allocated past the threshold and
+# holds the GIL throughout, so every other thread of the process waits
+# for it: a full one over a serving process's 1.8 million objects is
+# 0.64-0.75 s (PERF.md). One entry in ``gc.callbacks``, there while
+# anything subscribes, times each collection into every subscribed
+# StageTimers and, under a capture, opens a host span around it.
+
+#: {id(timers): [timers, subscriptions]}; written under _GC_LOCK, read
+#: by the callback without it (it may run on a thread holding the lock)
+_GC_TIMERS = {}
+_GC_LOCK = threading.Lock()
+#: the collection in progress: (annotation or None, t0), else None
+_gc_open = None
+_gc_failed = False
+
+
+def watch_gc(timers):
+    """Add every garbage collection of the process to ``timers`` from
+    now on: stage ``gc`` (generations 0 and 1) or ``gc_full``
+    (generation 2), by :meth:`StageTimers.add`, and — in a process
+    that has imported jax, while a capture runs — a host span
+    ``runtime:gc`` on the collecting thread with the generation as
+    metadata. Those stages overlap every other (a collection stops
+    whatever stage each thread is in), so they are never summed with
+    siblings. Each of several subscribers sees each collection: two
+    engines in one process are both stalled by it and both count it.
+    The same timers subscribed twice count a collection once and stay
+    subscribed until :func:`unwatch_gc` has been called as often."""
+    with _GC_LOCK:
+        entry = _GC_TIMERS.setdefault(id(timers), [timers, 0])
+        entry[1] += 1
+        if _gc_callback not in gc.callbacks:
+            gc.callbacks.append(_gc_callback)
+
+
+def unwatch_gc(timers):
+    """Undo one :func:`watch_gc` of ``timers``; the last one out takes
+    the callback out of ``gc.callbacks``."""
+    with _GC_LOCK:
+        entry = _GC_TIMERS.get(id(timers))
+        if entry is None:
+            return
+        entry[1] -= 1
+        if not entry[1]:
+            del _GC_TIMERS[id(timers)]
+        if not _GC_TIMERS and _gc_callback in gc.callbacks:
+            gc.callbacks.remove(_gc_callback)
+
+
+def _gc_callback(phase, info):
+    """``gc.callbacks`` entry: opens at ``start``, closes and adds at
+    ``stop``, on the collecting thread. Never raises (a failure is
+    logged once) and ignores a phase out of turn, so a collection can
+    neither be counted twice nor break the allocation that set it
+    off."""
+    global _gc_open, _gc_failed
+    try:
+        if phase == "start":
+            if _gc_open is not None:
+                return
+            annotation = None
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            if profiler is not None:
+                annotation = profiler.TraceAnnotation(
+                    "runtime:gc", generation=info["generation"])
+                annotation.__enter__()
+            _gc_open = (annotation, time.monotonic())
+        elif _gc_open is not None:
+            annotation, t0 = _gc_open
+            seconds = time.monotonic() - t0
+            _gc_open = None
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            stage = "gc_full" if info["generation"] == 2 else "gc"
+            for timers, _ in list(_GC_TIMERS.values()):
+                timers.add(stage, seconds)
+    except Exception:  # noqa: BLE001 - a collection must not fail
+        _gc_open = None
+        if not _gc_failed:
+            _gc_failed = True
+            logger.exception("garbage-collection watch failed")
 
 
 #: port of this process's already-started jax profiler server, if any

@@ -6,12 +6,16 @@ and — under a ``jax.profiler`` capture — a host span named
 ``<plane>:<stage>`` on the device trace's clock. Pinned here: the span
 is written by the profiler itself and only while it captures, a
 process without jax is not made to import it, the decode scheduler's
-loop is split where the work happens (and its parts add up), and the
+loop is split where the work happens (and its parts add up), each of
+its passes is a stage and a span of its own, a garbage collection is
+a ``runtime:gc`` span and a stage of every subscribed timers, and the
 whole thing costs next to nothing when nobody captures. The names of
 the jitted programs and of the Pallas calls are checked where the
 TPU's lowering is at hand: ``tests/test_chip_compile.py``.
 """
 
+import contextlib
+import gc
 import glob
 import os
 import statistics
@@ -147,6 +151,145 @@ def test_timed_costs_next_to_nothing_with_no_capture():
         samples.append(time.perf_counter_ns() - t0)
     assert statistics.median(samples) < 25000  # ns; measured about 1,000
     assert timers.counts() == {"x": 10000}
+
+
+# -- the process's garbage collections -----------------------------------
+
+
+@contextlib.contextmanager
+def _only_explicit_collections():
+    """No collection but the ones the test makes: a full one the
+    interpreter starts on its own would add a sample of its own."""
+    gc.disable()
+    try:
+        yield
+    finally:
+        gc.enable()
+
+
+@contextlib.contextmanager
+def _watching(*timers):
+    for t in timers:
+        tracing.watch_gc(t)
+    try:
+        yield
+    finally:
+        for t in timers:
+            tracing.unwatch_gc(t)
+
+
+def test_a_collection_is_a_host_span_and_a_stage_of_each_subscriber(
+        tmp_path):
+    one, two, bare = (tracing.StageTimers("engine"),
+                      tracing.StageTimers("feed"), tracing.StageTimers())
+    around = tracing.StageTimers("engine")
+    with _watching(one, two, bare), _only_explicit_collections():
+        with tracing.trace(str(tmp_path)):
+            with around.timed("around"):
+                gc.collect()
+    events = _host_events(str(tmp_path))
+    (span,), (outer,) = events["runtime:gc"], events["engine:around"]
+    # on the collecting thread's line, inside what that thread was doing
+    assert span[0] == outer[0]
+    assert outer[1] <= span[1] and span[2] <= outer[2]
+    seconds = (span[2] - span[1]) * 1e-9
+    for t in (one, two, bare):
+        # one full collection, one sample, whatever the timers' plane
+        assert t.counts() == {"gc_full": 1}
+        assert abs(t.snapshot()["gc_full"] - seconds) < 1e-3
+    # a sample by add(): no span of a subscriber's plane
+    assert not {"engine:gc_full", "feed:gc_full"} & set(events)
+
+
+def test_a_young_collection_is_stage_gc():
+    timers = tracing.StageTimers("engine")
+    with _watching(timers), _only_explicit_collections():
+        gc.collect(0)
+        gc.collect(1)
+    assert timers.counts() == {"gc": 2}
+
+
+def test_gc_callbacks_are_as_they_were_once_the_last_subscriber_goes(lm):
+    dec, params = lm
+    before = list(gc.callbacks)
+    a, b = tracing.StageTimers("engine"), tracing.StageTimers("engine")
+    with _watching(a, b, a):
+        assert gc.callbacks.count(tracing._gc_callback) == 1
+        with _only_explicit_collections():
+            gc.collect()
+        # the same timers subscribed twice count a collection once
+        assert a.counts() == b.counts() == {"gc_full": 1}
+    assert gc.callbacks == before
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                              kv_blocks=12) as one:
+        with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                                  kv_blocks=12) as two:
+            _serve(one, n=1)
+            with _only_explicit_collections():
+                full = [e.timers.counts().get("gc_full", 0)
+                        for e in (one, two)]
+                gc.collect()
+            # both engines are stalled by it, and both count it
+            assert [e.timers.counts()["gc_full"] for e in (one, two)] \
+                == [n + 1 for n in full]
+        assert tracing._gc_callback in gc.callbacks
+    assert gc.callbacks == before
+    if not tracing._GC_TIMERS:
+        assert tracing._gc_callback not in gc.callbacks
+
+
+def test_a_process_without_jax_times_a_collection_and_does_not_import_it():
+    code = (
+        "import gc, sys\n"
+        "from tensorflowonspark_tpu import tracing\n"
+        "t = tracing.StageTimers('engine')\n"
+        "tracing.watch_gc(t)\n"
+        "gc.disable()\n"
+        "gc.collect()\n"
+        "assert t.counts() == {'gc_full': 1}, t.counts()\n"
+        "assert t.snapshot()['gc_full'] > 0.0\n"
+        "tracing.unwatch_gc(t)\n"
+        "assert tracing._gc_callback not in gc.callbacks\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "print('ok')\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True,
+        text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
+def test_a_failing_watch_logs_once_and_never_raises(caplog, monkeypatch):
+    monkeypatch.setattr(tracing, "_gc_failed", False)
+
+    class Broken(object):
+        def add(self, stage, seconds):
+            raise RuntimeError("broken timers")
+
+    broken = Broken()
+    with _watching(broken), _only_explicit_collections():
+        with caplog.at_level("ERROR", logger=tracing.__name__):
+            gc.collect()
+            gc.collect()
+    assert [r.getMessage() for r in caplog.records] \
+        == ["garbage-collection watch failed"]
+
+
+def test_a_collection_costs_next_to_nothing_with_no_capture():
+    timers = tracing.StageTimers("engine")
+    start, stop = {"generation": 0}, {"generation": 0, "collected": 0,
+                                      "uncollectable": 0}
+    samples = []
+    with _watching(timers), _only_explicit_collections():
+        for _ in range(10000):
+            t0 = time.perf_counter_ns()
+            tracing._gc_callback("start", start)
+            tracing._gc_callback("stop", stop)
+            samples.append(time.perf_counter_ns() - t0)
+    # ns for the pair, annotation included; measured about 4,000 here
+    assert statistics.median(samples) < 25000
+    assert timers.counts() == {"gc": 10000}
 
 
 # -- the feed plane's stages ---------------------------------------------
@@ -358,3 +501,56 @@ def test_park_is_idle_for_want_of_work_not_scheduler_cost(lm):
     busy = sum(sec.get(k, 0.0) for k in (
         "qos_plan", "evict", "grow_blocks", "host_schedule"))
     assert busy < 0.25
+
+
+# -- one pass of the scheduler loop --------------------------------------
+
+#: the stages a pass of ``DecodeEngine._loop`` opens directly; every
+#: other stage of the scheduler's thread lies inside one of these
+PASS_STAGES = ("park", "qos_plan", "preempt", "kv_job", "admit", "evict",
+               "trim_blocks", "grow_blocks", "decode_step", "spec_round",
+               "host_schedule")
+
+
+@pytest.mark.parametrize("speculate_k", [None, 3])
+def test_a_pass_is_a_stage_that_holds_its_stages(lm, speculate_k):
+    dec, params = lm
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                              kv_blocks=16, speculate_k=speculate_k) as eng:
+        _serve(eng)
+        time.sleep(0.3)  # a park between requests
+        _serve(eng, n=1)
+    sec, n = eng.timers.snapshot(), eng.timers.counts()
+    # a pass plans admission once, but for the last: it finds the
+    # engine stopping and returns
+    assert n["loop"] == n["qos_plan"] + 1
+    inside = sum(sec.get(k, 0.0) for k in PASS_STAGES)
+    assert 0.0 < inside <= sec["loop"]
+    assert sec["park"] >= 0.25
+
+
+def test_each_pass_is_a_span_around_the_stages_it_opens(lm, tmp_path):
+    dec, params = lm
+    with serving.DecodeEngine(dec, params, slots=2, kv_block_size=8,
+                              kv_blocks=12) as eng:
+        _serve(eng, n=1)  # compiled before the capture
+        with tracing.trace(str(tmp_path)):
+            _serve(eng, n=2)
+    events = _host_events(str(tmp_path))
+    passes = sorted(events["engine:loop"], key=lambda e: e[1])
+    (thread,) = {e[0] for e in passes}
+    # one after another on the scheduler's thread, never one in another
+    assert all(a[2] <= b[1] for a, b in zip(passes, passes[1:]))
+    stages = [e for name, evs in events.items()
+              if name.startswith("engine:") and name != "engine:loop"
+              for e in evs]
+    assert {e[0] for e in stages} == {thread}
+    # the pass under way when the capture began (parked) and the one
+    # under way when it ended have no span: a span is written where the
+    # capture sees it open and close; every stage between them lies in
+    # the pass that opened it
+    between = [e for e in stages
+               if passes[0][1] <= e[1] and e[2] <= passes[-1][2]]
+    assert len(between) > len(passes) > 2
+    for _, a, b in between:
+        assert any(p0 <= a and b <= p1 for _, p0, p1 in passes)

@@ -139,7 +139,13 @@ def band_attention(q, k, v, window=None, chunk=512):
 class KindAttention(nn.Module):
     """Grouped-query attention of one layer kind: ``window`` 0 is a full
     layer, else the positions a query sees; ``inv_freq``/``rope_factor``
-    are the kind's RoPE (None: plain, at ``rope_theta``)."""
+    are the kind's RoPE (None: plain, at ``rope_theta``). ``rotary_dim``
+    turns the first ``rotary_dim`` dims of a head and passes the rest
+    through (``inv_freq`` then has ``rotary_dim / 2`` entries and
+    ``rope_factor`` scales the turned dims only); ``gate_heads`` gives
+    each head's output a gate, ``sigmoid(x W_gate)`` in float32 with
+    ``W_gate [hidden, num_heads]``, before ``Wo`` (models/laguna.py).
+    Both off leave the kind as it was."""
 
     num_heads: int
     num_kv_heads: int
@@ -154,12 +160,15 @@ class KindAttention(nn.Module):
     decode: bool = False
     kv_block_size: int = 0
     kv_blocks: int = 0
+    rotary_dim: int = 0
+    gate_heads: bool = False
 
     @nn.compact
     def __call__(self, x):
         b, s, h = x.shape
         n, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
         window = self.window or None
+        r = self.rotary_dim
 
         def project(name, heads):
             w = _matrix(self, name, (h, heads * d), self.dtype)
@@ -167,6 +176,12 @@ class KindAttention(nn.Module):
                 .astype(self.dtype).reshape(b, s, heads, d)
 
         def turn(t, pos):
+            if r and r < d:
+                return jnp.concatenate([turn_all(t[..., :r], pos),
+                                        t[..., r:]], axis=-1)
+            return turn_all(t, pos)
+
+        def turn_all(t, pos):
             return rope(t, pos, self.rope_theta, inv_freq=self.inv_freq,
                         factor=self.rope_factor)
 
@@ -194,6 +209,12 @@ class KindAttention(nn.Module):
                 pool.write(k, v, pos)
                 ctx = band_attention(turn(q, pos), k, v, window,
                                      self.prefill_chunk)
+        if self.gate_heads:
+            w_gate = _matrix(self, "w_gate", (h, n), self.dtype)
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, w_gate, preferred_element_type=jnp.float32))
+            ctx = (ctx.astype(jnp.float32) * gate[..., None]) \
+                .astype(self.dtype)
         return jnp.dot(ctx.reshape(b, s, n * d), wo,
                        preferred_element_type=jnp.float32).astype(self.dtype)
 

@@ -157,12 +157,36 @@ class BlockAttention(nn.Module):
                        preferred_element_type=jnp.float32).astype(self.dtype)
 
 
+class GatedMlp(nn.Module):
+    """A dense gated feed-forward, ``down(silu(x Wg) * (x Wu))`` of
+    width ``width``, products accumulating in float32: a dense layer
+    among sparse ones, or a shared expert (models/laguna.py)."""
+
+    width: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        h, f32 = x.shape[-1], jnp.float32
+        gate = jnp.dot(x, _matrix(self, "gate", (h, self.width), self.dtype),
+                       preferred_element_type=f32)
+        up = jnp.dot(x, _matrix(self, "up", (h, self.width), self.dtype),
+                     preferred_element_type=f32)
+        act = (jax.nn.silu(gate) * up).astype(self.dtype)
+        return jnp.dot(act, _matrix(self, "down", (self.width, h),
+                                    self.dtype),
+                       preferred_element_type=f32).astype(self.dtype)
+
+
 class SparseExperts(nn.Module):
     """The held experts' part of the sparse feed-forward; sows the
     experts the router sent each position to (``intermediates``,
     ``expert_ids`` ``[positions, experts_per_tok]``) for whoever counts
     the load: only the caller knows which positions belong to a
-    request."""
+    request. ``routed_scale`` multiplies the normalised top-k weights;
+    ``shared_hidden`` adds a shared expert every position visits, a
+    :class:`GatedMlp` of that width, as plain products outside the
+    grouped kernel (so ``expert_gmm`` holds the routed work alone)."""
 
     num_experts: int
     experts_per_tok: int
@@ -170,6 +194,8 @@ class SparseExperts(nn.Module):
     dtype: jnp.dtype
     first_expert: int = 0
     expert_count: int = 0
+    routed_scale: float = 1.0
+    shared_hidden: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -182,9 +208,14 @@ class SparseExperts(nn.Module):
         down = _matrix(self, "down", (held, f, h), self.dtype)
         y, experts = expert_gmm.expert_layer(
             x.reshape(b * s, h), router, gate, up, down,
-            self.experts_per_tok, first=self.first_expert)
+            self.experts_per_tok, first=self.first_expert,
+            scale=self.routed_scale)
         self.sow("intermediates", "expert_ids", experts)
-        return y.reshape(b, s, h)
+        y = y.reshape(b, s, h)
+        if self.shared_hidden:
+            y = y + GatedMlp(self.shared_hidden, self.dtype,
+                             name="shared")(x)
+        return y
 
 
 class SdarMoeLayer(nn.Module):

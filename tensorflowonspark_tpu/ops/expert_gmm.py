@@ -171,17 +171,20 @@ def expert_gmm(lhs, rhs, layout, tm, impl=None, interpret=None):
                      "'pallas' or 'ragged'".format(impl))
 
 
-def expert_layer(h, router, gate, up, down, top_k, first=0):
+def expert_layer(h, router, gate, up, down, top_k, first=0, scale=1.0):
     """The held experts' part of a sparse gated-MLP layer.
 
     ``h [T, H]``; ``router [H, E]`` over ALL experts; ``gate``/``up``
     ``[held, H, F]`` and ``down [held, F, H]`` the weights of experts
     ``first .. first + held - 1``. Returns ``(y [T, H] in h's dtype,
     experts [T, top_k] int32)``: ``y[t] = sum over t's top_k experts
-    that are held of gate_weight * down(silu(gate(h[t])) * up(h[t]))``,
-    and the experts (of all ``E``) the router sent each position to."""
+    that are held of scale * gate_weight * down(silu(gate(h[t])) *
+    up(h[t]))``, and the experts (of all ``E``) the router sent each
+    position to."""
     t, held = h.shape[0], gate.shape[0]
     experts, gates = route(h, router, top_k)
+    if scale != 1.0:
+        gates = gates * scale
     tm = row_tile(t * top_k, held)
     layout = plan(experts, router.shape[1], first, held, tm)
     x = h[layout["row_token"]]

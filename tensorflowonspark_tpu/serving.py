@@ -2546,12 +2546,23 @@ class DecodeEngine(object):
         multiplied like any other and are counted nowhere. Per layer
         the fullest expert's rows and the mean, summed
         (``expert_rows_max`` over ``expert_rows_mean`` is the peak
-        load), and what the grouped product had to touch."""
+        load), and what the grouped product had to touch. Those count
+        the experts the model HOLDS (``first_expert`` on, ``expert_count``
+        of them; all when 0): a row routed to an absent expert is
+        another chip's work and counts toward none here, and
+        ``expert_rows_routed`` counts every live row's assignments,
+        held or not."""
         ids = expert_ids[:, live]
-        layers, experts = ids.shape[0], self._model.num_experts
-        rows = np.bincount(
-            (ids + experts * np.arange(layers)[:, None, None]).ravel(),
-            minlength=layers * experts).reshape(layers, experts)
+        layers = ids.shape[0]
+        first = int(getattr(self._model, "first_expert", 0))
+        held = int(getattr(self._model, "expert_count", 0)) \
+            or self._model.num_experts
+        local = ids + (held * np.arange(layers)[:, None, None] - first)
+        if held != self._model.num_experts:
+            local = local[(ids >= first) & (ids < first + held)]
+        rows = np.bincount(local.ravel(), minlength=layers * held) \
+            .reshape(layers, held)
+        self.counters.inc("expert_rows_routed", int(ids.size))
         self.counters.inc("expert_calls", layers)
         self.counters.inc("expert_rows_max", int(rows.max(axis=1).sum()))
         self.counters.inc("expert_rows_mean",

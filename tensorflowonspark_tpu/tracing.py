@@ -388,17 +388,23 @@ METRIC_FAMILIES = {
                         "(block steps, token steps and prefills alike)"),
     "tfos_serving_expert_rows":
         ("counter", "", "rows (positions x experts a token) the router "
-                        "sent to experts in those calls, over the "
-                        "positions that belong to a request"),
+                        "sent to experts the model holds in those "
+                        "calls, over the positions that belong to a "
+                        "request"),
+    "tfos_serving_expert_rows_routed":
+        ("counter", "", "every assignment of those positions, held or "
+                        "not: tfos_serving_expert_rows over it is the "
+                        "share of the routed work this chip computes"),
     "tfos_serving_expert_rows_max":
-        ("counter", "", "per call the fullest expert's rows, summed "
-                        "(over tfos_serving_expert_rows_mean: the peak "
-                        "load)"),
+        ("counter", "", "per call the fullest held expert's rows, "
+                        "summed (over tfos_serving_expert_rows_mean: "
+                        "the peak load)"),
     "tfos_serving_expert_rows_mean":
-        ("counter", "", "per call the mean rows an expert, summed"),
+        ("counter", "", "per call the mean rows a held expert, summed"),
     "tfos_serving_experts_touched":
-        ("counter", "", "per call the experts with at least one row, "
-                        "summed: what the grouped product had to read"),
+        ("counter", "", "per call the held experts with at least one "
+                        "row, summed: what the grouped product had to "
+                        "read"),
     "tfos_serving_admit_scans_blocked_slots":
         ("counter", "", "admission scans that left a queued request "
                         "waiting because no slot was free"),

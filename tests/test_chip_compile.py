@@ -678,3 +678,67 @@ def fh_defaults():
             os.path.abspath(__file__))), "benchmarks", "configs",
             "falcon-h1-34b.json")) as f:
         return json.load(f)["model"]
+
+
+# -- Laguna-S-2.1's kernels at its published widths --------------------
+
+#: hidden 3072, 48 (full) / 72 (sliding) query and 8 K/V heads of 128,
+#: 128 of 256 experts of 1024 held with 10 a position, a window of 512;
+#: the engine's step is 32 slots over 10,240-token tables in 128-token
+#: blocks (benchmarks/configs/laguna-s-2.1)
+LAGUNA = dict(hidden=3072, full_heads=48, sliding_heads=72, kv_heads=8,
+              head_dim=128, held=128, moe_hidden=1024, top_k=10, slots=32,
+              total=10240, block=128, window=512)
+
+
+@pytest.mark.parametrize("heads,window", [(72, LAGUNA["window"]),
+                                          (48, None)],
+                         ids=["window_layer_group_9", "full_layer_group_6"])
+def test_paged_attention_of_a_laguna_step_compiles_for_v5e(one_chip, heads,
+                                                           window):
+    """The decode step's call of either layer kind: 32 rows of one
+    position, a group of 9 query heads a K/V head in a window layer and
+    of 6 in a full one, over the one pool width of 8 heads of 128."""
+    mb = LAGUNA["total"] // LAGUNA["block"]
+    blocks = LAGUNA["slots"] * (mb if window is None else 5) + 1
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((blocks, LAGUNA["block"],
+                LAGUNA["kv_heads"] * LAGUNA["head_dim"]), jnp.bfloat16)
+    lowered = jax.jit(
+        lambda *a: pa.paged_attention(*a, impl="pallas", interpret=False,
+                                      window=window)
+    ).lower(
+        sds((LAGUNA["slots"], 1, heads, LAGUNA["head_dim"]), jnp.bfloat16),
+        pool, pool, sds((LAGUNA["slots"], mb), jnp.int32),
+        sds((LAGUNA["slots"], 1), jnp.int32))
+    assert lowered.as_text().count('kernel_name = "paged_attention"') == 1
+    assert _has_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("kdim,ndim", [(3072, 1024), (1024, 3072)],
+                         ids=["step_up", "step_down"])
+def test_expert_gmm_at_laguna_widths_compiles_for_v5e(one_chip, kdim, ndim):
+    """The step's 32 positions into and out of the 128 held experts of
+    3072 x 1024: a weight matrix of 6.3 MB, double-buffered in VMEM
+    beside a tile of rows."""
+    from tensorflowonspark_tpu.ops import expert_gmm
+
+    held, m = LAGUNA["held"], LAGUNA["slots"] * LAGUNA["top_k"]
+    tm = expert_gmm.row_tile(m, held)
+    rows = -(-(m + held * (tm - 1)) // tm) * tm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def gmm(lhs, rhs, tile_expert, live):
+        return expert_gmm.expert_gmm(
+            lhs, rhs, {"tile_expert": tile_expert, "live": live}, tm,
+            impl="pallas", interpret=False)
+
+    compiled = _compile(gmm, sds((rows, kdim), jnp.bfloat16),
+                        sds((held, kdim, ndim), jnp.bfloat16),
+                        sds((rows // tm,), jnp.int32), sds((), jnp.int32))
+    assert _has_kernel(compiled)
